@@ -142,8 +142,8 @@ def _layout_report(si: SegmentedIndex) -> dict:
             # both layouts, so this IS the bytes/query ratio) — the
             # array-total ratio above additionally counts rare-term
             # blocks no frequent-term query reads
-            block = int(seg.index.block_tfs.shape[1])
-            per_packed = int(seg.index.packed.shape[1]) * 4 + block * 2 + 12
+            block = int(seg.index.block)
+            per_packed = int(seg.index.words_per_block) * 4 + block * 2 + 12
             per_hor = block * 8 + 8
             rec["block_bytes_vs_hor"] = round(per_packed / per_hor, 3)
         elif seg.layout == "banded":
@@ -152,9 +152,9 @@ def _layout_report(si: SegmentedIndex) -> dict:
             # its ratio can fall well below the monolithic-packed
             # floor; the HOR tail streams HOR blocks by construction
             ix = seg.index
-            block = int(ix.packed.block_tfs.shape[1])
+            block = int(ix.packed.block)
             per_hor = block * 8 + 8
-            per_packed = int(ix.packed.packed.shape[1]) * 4 + block * 2 + 12
+            per_packed = int(ix.packed.words_per_block) * 4 + block * 2 + 12
             rec["band_cut"] = int(seg.band_cut)
             rec["bands"] = {
                 "packed": {
@@ -367,6 +367,8 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--save-table", default=None, metavar="PATH",
                     help="write the winning tuning table as JSON")
     args = ap.parse_args(argv)
+    from repro.kernels.runtime import enable_compile_cache
+    enable_compile_cache()
     tiers = sorted(TIERS) if args.tier == "all" else [args.tier]
     autotune_results = {}
     if not args.no_probe and not args.no_autotune:

@@ -1,101 +1,67 @@
 """Doc- vs term-partitioned retrieval: the distribution crossover.
 
-Runs both shard_map engines on an 8-device host mesh (subprocess, since
-XLA device count must be set before jax init) and reports per-query
-latency plus the ANALYTIC per-query wire bytes at production scale —
-the quantity that decides the sharding choice at 1000+ nodes:
+Runs both shard_map engines on an 8-device mesh over ``jax.devices()``
+in this process (on CPU, launch with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``; fewer devices
+is an error) and reports per-query latency plus the ANALYTIC per-query
+wire bytes at production scale — the quantity that decides the
+sharding choice at 1000+ nodes:
 
   doc-partitioned : wire/query ~ shards * k * 8 B      (top-k merge)
   term-partitioned: wire/query ~ D * 4 B               ([D] psum)
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
+import time
 
 from benchmarks.common import emit, is_smoke
 
-# corpus/query sizing is injected so --smoke reaches the subprocess too
-SCRIPT = r"""
-import time
-import jax, jax.numpy as jnp, numpy as np
-from repro.text import corpus
-from repro.core import build
-from repro.distributed import retrieval
-
-mesh = jax.make_mesh((8,), ("data",))
-tc = corpus.generate(corpus.CorpusSpec(num_docs={docs}, vocab={vocab},
-                                       avg_distinct={avg}, seed=4))
-host = build.bulk_build(tc)
-qh = corpus.sample_query_terms(host.df, host.term_hashes, {queries}, 3,
-                               num_docs=host.num_docs, seed=5)
-
-for name, builder, mk in [
-        ("doc", retrieval.build_doc_sharded,
-         retrieval.make_doc_sharded_scorer),
-        ("term", retrieval.build_term_sharded,
-         retrieval.make_term_sharded_scorer),
-        # fused engines per layout: the term-sharded tier now runs the
-        # compressed layout end to end (per-shard re-compression +
-        # in-VMEM decode), so the crossover is measured per layout too
-        ("term_fused_hor", retrieval.build_term_sharded_blocked,
-         retrieval.make_term_sharded_fused_scorer),
-        ("term_fused_packed", retrieval.build_term_sharded_packed,
-         retrieval.make_term_sharded_fused_scorer)]:
-    ix = builder(host, 8)
-    scorer = mk(ix, mesh, "data", k=10)
-    scorer(jnp.asarray(qh[0]))          # warm
-    t0 = time.perf_counter()
-    for q in qh:
-        out = scorer(jnp.asarray(q))
-        jax.tree.map(lambda x: x.block_until_ready(), out)
-    us = (time.perf_counter() - t0) / len(qh) * 1e6
-    print(f"RESULT {name} {us:.1f}")
-"""
+SHARDS = 8
 
 
 def main() -> None:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import build
+    from repro.distributed import retrieval
+    from repro.text import corpus
+
+    if len(jax.devices()) < SHARDS:
+        raise RuntimeError(
+            f"partitioned: needs {SHARDS} devices, {len(jax.devices())} "
+            "present (on CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={SHARDS})")
+    mesh = jax.make_mesh((SHARDS,), ("data",),
+                         devices=jax.devices()[:SHARDS])
     sizing = (dict(docs=1_500, vocab=600, avg=25, queries=8) if is_smoke()
               else dict(docs=8000, vocab=2000, avg=60, queries=32))
-    script = SCRIPT
-    for key, val in sizing.items():   # not .format(): SCRIPT has f-strings
-        script = script.replace("{%s}" % key, str(val))
-    try:
-        out = subprocess.run([sys.executable, "-c", script],
-                             env=env, capture_output=True, text=True,
-                             timeout=520)
-        stdout, stderr = out.stdout, out.stderr
-    except subprocess.TimeoutExpired as e:
-        # salvage whatever engines finished (the interpret-mode fused
-        # rows at full bench size can outlast the budget on slow hosts)
-        stdout = (e.stdout or b"").decode() if isinstance(
-            e.stdout, bytes) else (e.stdout or "")
-        err = (e.stderr or b"").decode() if isinstance(
-            e.stderr, bytes) else (e.stderr or "")
-        stderr = "subprocess timeout: " + err
-    expected = ["doc", "term", "term_fused_hor", "term_fused_packed"]
-    finished = []
-    for line in stdout.splitlines():
-        if line.startswith("RESULT"):
-            _, name, us = line.split()
-            finished.append(name)
-            emit(f"partitioned/{name}_sharded_8dev", float(us), "per_query")
-    # a timeout salvage that silently drops engines reads as "all
-    # measured" — name every dropped shard config explicitly
-    dropped = [n for n in expected if n not in finished]
-    for name in dropped:
-        emit(f"partitioned/{name}_sharded_8dev/DROPPED", 0.0,
-             "timed_out_before_measurement")
-    if dropped:
-        print(f"# partitioned: dropped {len(dropped)}/{len(expected)} "
-              f"engine configs: {','.join(dropped)}", file=sys.stderr)
-    if not finished:
-        emit("partitioned/FAILED", 0.0, stderr[-200:].replace("\n", " "))
+    tc = corpus.generate(corpus.CorpusSpec(
+        num_docs=sizing["docs"], vocab=sizing["vocab"],
+        avg_distinct=sizing["avg"], seed=4))
+    host = build.bulk_build(tc)
+    qh = corpus.sample_query_terms(host.df, host.term_hashes,
+                                   sizing["queries"], 3,
+                                   num_docs=host.num_docs, seed=5)
+    for name, builder, mk in [
+            ("doc", retrieval.build_doc_sharded,
+             retrieval.make_doc_sharded_scorer),
+            ("term", retrieval.build_term_sharded,
+             retrieval.make_term_sharded_scorer),
+            # fused engines per layout: the term-sharded tier runs the
+            # compressed layout end to end (per-shard re-compression +
+            # in-VMEM decode), so the crossover is measured per layout
+            ("term_fused_hor", retrieval.build_term_sharded_blocked,
+             retrieval.make_term_sharded_fused_scorer),
+            ("term_fused_packed", retrieval.build_term_sharded_packed,
+             retrieval.make_term_sharded_fused_scorer)]:
+        scorer = mk(builder(host, SHARDS), mesh, "data", k=10)
+        scorer(jnp.asarray(qh[0]))          # warm
+        t0 = time.perf_counter()
+        for q in qh:
+            jax.block_until_ready(scorer(jnp.asarray(q)))
+        us = (time.perf_counter() - t0) / len(qh) * 1e6
+        emit(f"partitioned/{name}_sharded_{SHARDS}dev", us, "per_query")
 
     # analytic production-scale wire (1M docs, 256 shards, k=10)
     shards, k, docs = 256, 10, 1_004_721

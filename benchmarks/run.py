@@ -11,10 +11,12 @@
   serving  QueryServer offered-QPS sweep: request latency p50/p99,
            achieved QPS, cache hit rate, maintenance-thread lifecycle;
            plus the MeshServer offered-QPS x shard-count sweep (shed
-           rate, handoff pause) — one subprocess per shard count
+           rate, handoff pause), in this process over ``jax.devices()``
 
 ``--smoke`` runs every suite on a CI-sized corpus (plumbing check, not
-representative numbers).
+representative numbers).  ``partitioned`` and the mesh sweep need 8
+devices: on CPU launch with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
 from __future__ import annotations
 
@@ -26,6 +28,8 @@ import traceback
 def main() -> None:
     from benchmarks import churn, common, expansion, partitioned, \
         roofline, serving, table5_size, table6_index, table7_query
+    from repro.kernels.runtime import enable_compile_cache
+    enable_compile_cache()
     suites = [("table5", table5_size.main), ("table6", table6_index.main),
               ("table7", table7_query.main), ("expansion", expansion.main),
               ("partitioned", partitioned.main),

@@ -7,8 +7,8 @@ advances the epoch so the cache invalidation path is exercised, and the
 query stream draws from a finite pool so repeats produce cache hits.
 
 The mesh sweep repeats the drive against a ``MeshServer`` per shard
-count — each shard count in its own subprocess, since the XLA host
-device count must be set before jax initialises — with admission
+count, all in this process over ``jax.devices()`` (on CPU, launch with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``), with admission
 control and deadline shedding armed, ingest churn forcing epoch
 handoffs mid-drive, and per-tenant cache traffic.
 
@@ -32,10 +32,6 @@ tests/test_serve.py (the daily full-suite job).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -133,146 +129,118 @@ def run_sweep(rates, n_requests, *, pool_size=64, ingest_every=64,
 
 # -- mesh sweep ------------------------------------------------------------
 #
-# One subprocess per shard count (XLA host device count is fixed at jax
-# init); sizing/rates injected via .replace() like partitioned.py — the
-# child regenerates the deterministic corpus rather than importing
-# benchmarks, so only src/ needs to be on its path.  Each rate's summary
-# comes back as one parseable ``MESHROW <json>`` line; the parent
-# salvages partial output on timeout and names every dropped config.
-MESH_SCRIPT = r"""
-import dataclasses, json, time
-import jax, numpy as np
-from repro.text import corpus
-from repro.core import build, compaction
-from repro.core.live_index import SegmentedIndex
-from repro.serve import MeshConfig, MeshServer
+# Every shard count runs in THIS process over the first S of
+# ``jax.devices()``: on a chip machine a child process could not open
+# the devices the parent already holds.  CPU callers get host devices
+# by setting ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+# before launch; a shard count above the devices present is an error.
 
-N_SHARDS = {shards}
-mesh = jax.make_mesh((N_SHARDS,), ("shards",))
-tc = corpus.generate(corpus.CorpusSpec(num_docs={docs}, vocab={vocab},
-                                       avg_distinct={avg}, seed=42))
-host = build.bulk_build(tc)
 
-# ingest all but a holdback slice (streamed during the drive), sealing
-# per step so the doc topology has segment runs to shard
-n = tc.num_docs
-first = int(n * 0.75)
-si = SegmentedIndex(term_hashes=tc.term_hashes, delta_doc_capacity=128,
-                    delta_posting_capacity=128 * 64,
-                    policy=compaction.TieredPolicy(size_ratio=8.0,
-                                                   min_run=4))
-step = max(first // 8, 1)
-for a in range(0, first, step):
-    b = min(a + step, first)
-    si.add_batch(dataclasses.replace(tc, doc_term_ids=tc.doc_term_ids[a:b],
-                                     doc_counts=tc.doc_counts[a:b],
-                                     num_docs=b - a))
-    si.seal()
+def _mesh_drive(n_shards: int, rates, n_requests: int, spec) -> list:
+    """One MeshServer over ``n_shards`` devices, driven at each offered
+    rate; returns one summary dict per rate."""
+    import dataclasses
 
-cfg = MeshConfig(batch_size=8, n_terms_budget=8, k=10, trace_sample=1,
-                 n_shards=N_SHARDS, max_queue=64, deadline_us=500_000.0,
-                 auto_handoff=True, handoff_min_interval_s=0.02,
-                 seal_fill=0.5, maintenance_interval_s=0.002)
-ms = MeshServer(si, cfg, mesh=mesh)
-ms.warmup()
-pool = corpus.sample_query_terms(host.df, host.term_hashes, 64, 3,
-                                 num_docs=host.num_docs, seed=11)
-rng = np.random.default_rng(11)
-holdback = list(range(first, n, max((n - first) // 16, 1)))
+    import jax
 
-ms.start()
-try:
-    for rate in {rates}:
-        shed0 = ms.shed_counts()
-        hand0 = ms.registry.histogram("mesh_handoff_pause_us").snapshot()
-        ms.metrics.reset()
-        ms.cache.reset_counters()
-        ms.stages.reset()
-        gap = 1.0 / rate
-        tickets = []
-        next_ingest = 24
-        for i in range({requests}):
-            tickets.append(ms.submit(pool[rng.integers(64)],
-                                     tenant="t%d" % (i % 4)))
-            if i == next_ingest and holdback:
-                a = holdback.pop(0)
-                b = min(a + 16, n)
-                ms.add_batch(dataclasses.replace(
-                    tc, doc_term_ids=tc.doc_term_ids[a:b],
-                    doc_counts=tc.doc_counts[a:b], num_docs=b - a))
-                next_ingest += 24
-            time.sleep(gap)
-        for t in tickets:
-            t.result(timeout=120.0)
-        s = ms.metrics.summary()
-        shed1 = ms.shed_counts()
-        hand1 = ms.registry.histogram("mesh_handoff_pause_us").snapshot()
-        shed = {k: shed1[k] - shed0[k] for k in shed1}
-        offered = s["requests"] + shed["total"]
-        row = {"offered_qps": rate, "n_shards": N_SHARDS,
-               "offered": offered, "served": s["requests"],
-               "p50_us": s["p50_us"], "p99_us": s["p99_us"],
-               "achieved_qps": s["qps"], "shed": shed,
-               "shed_rate": shed["total"] / offered if offered else 0.0,
-               "handoffs": hand1["count"] - hand0["count"],
-               "handoff_pause_p50_us": hand1.get("p50", 0.0),
-               "handoff_pause_p99_us": hand1.get("p99", 0.0),
-               "cache_hit_rate": s["cache_hit_rate"],
-               "batch_fill": s["batch_fill"],
-               "epochs_served": s["epochs_served"],
-               "stages": ms.stage_summary()}
-        print("MESHROW " + json.dumps(row), flush=True)
-finally:
-    ms.stop()
-print("MESHDONE", flush=True)
-"""
+    from repro.core import compaction
+    from repro.serve import MeshConfig, MeshServer
+
+    mesh = jax.make_mesh((n_shards,), ("shards",),
+                         devices=jax.devices()[:n_shards])
+    tc = corpus.generate(spec)
+    host = build.bulk_build(tc)
+    # ingest all but a holdback slice (streamed during the drive),
+    # sealing per step so the doc topology has segment runs to shard
+    n = tc.num_docs
+    first = int(n * 0.75)
+    si = SegmentedIndex(term_hashes=tc.term_hashes, delta_doc_capacity=128,
+                        delta_posting_capacity=128 * 64,
+                        policy=compaction.TieredPolicy(size_ratio=8.0,
+                                                       min_run=4))
+
+    def part(a, b):
+        return dataclasses.replace(tc, doc_term_ids=tc.doc_term_ids[a:b],
+                                   doc_counts=tc.doc_counts[a:b],
+                                   num_docs=b - a)
+
+    step = max(first // 8, 1)
+    for a in range(0, first, step):
+        si.add_batch(part(a, min(a + step, first)))
+        si.seal()
+    cfg = MeshConfig(batch_size=8, n_terms_budget=8, k=10, trace_sample=1,
+                     n_shards=n_shards, max_queue=64,
+                     deadline_us=500_000.0, auto_handoff=True,
+                     handoff_min_interval_s=0.02, seal_fill=0.5,
+                     maintenance_interval_s=0.002)
+    ms = MeshServer(si, cfg, mesh=mesh)
+    ms.warmup()
+    pool = corpus.sample_query_terms(host.df, host.term_hashes, 64, 3,
+                                     num_docs=host.num_docs, seed=11)
+    rng = np.random.default_rng(11)
+    holdback = list(range(first, n, max((n - first) // 16, 1)))
+    rows = []
+    ms.start()
+    try:
+        for rate in rates:
+            shed0 = ms.shed_counts()
+            hand0 = ms.registry.histogram("mesh_handoff_pause_us").snapshot()
+            ms.metrics.reset()
+            ms.cache.reset_counters()
+            ms.stages.reset()
+            gap = 1.0 / rate
+            tickets = []
+            next_ingest = 24
+            for i in range(n_requests):
+                tickets.append(ms.submit(pool[rng.integers(64)],
+                                         tenant="t%d" % (i % 4)))
+                if i == next_ingest and holdback:
+                    a = holdback.pop(0)
+                    ms.add_batch(part(a, min(a + 16, n)))
+                    next_ingest += 24
+                time.sleep(gap)
+            for t in tickets:
+                t.result(timeout=120.0)
+            m = ms.metrics.summary()
+            shed1 = ms.shed_counts()
+            hand1 = ms.registry.histogram("mesh_handoff_pause_us").snapshot()
+            shed = {k: shed1[k] - shed0[k] for k in shed1}
+            offered = m["requests"] + shed["total"]
+            rows.append({
+                "offered_qps": rate, "n_shards": n_shards,
+                "offered": offered, "served": m["requests"],
+                "p50_us": m["p50_us"], "p99_us": m["p99_us"],
+                "achieved_qps": m["qps"], "shed": shed,
+                "shed_rate": shed["total"] / offered if offered else 0.0,
+                "handoffs": hand1["count"] - hand0["count"],
+                "handoff_pause_p50_us": hand1.get("p50", 0.0),
+                "handoff_pause_p99_us": hand1.get("p99", 0.0),
+                "cache_hit_rate": m["cache_hit_rate"],
+                "batch_fill": m["batch_fill"],
+                "epochs_served": m["epochs_served"],
+                "stages": ms.stage_summary()})
+    finally:
+        ms.stop()
+    return rows
 
 
 def run_mesh_sweep(shard_counts, rates, n_requests):
-    """Offered-QPS x shard-count sweep over the MeshServer, one
-    subprocess per shard count.  Returns ``(rows, dropped)``: per-rate
-    summary dicts (MESHROW payloads) and the explicitly-named configs a
-    timeout or crash left unmeasured."""
+    """Offered-QPS x shard-count sweep over the MeshServer, in this
+    process.  Returns the per-rate summary dicts; raises when a shard
+    count exceeds the devices present."""
+    import jax
+
+    have = len(jax.devices())
+    if max(shard_counts) > have:
+        raise RuntimeError(
+            f"serving: mesh sweep wants {max(shard_counts)} devices, "
+            f"{have} present (on CPU set XLA_FLAGS="
+            "--xla_force_host_platform_device_count=N before launch)")
     spec = common.SMOKE_SPEC if common.is_smoke() else common.BENCH_SPEC
-    sizing = dict(docs=spec.num_docs, vocab=spec.vocab,
-                  avg=spec.avg_distinct)
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    rows, dropped = [], []
+    rows = []
     for n_shards in shard_counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={n_shards}"
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        script = MESH_SCRIPT
-        for key, val in dict(sizing, shards=n_shards, rates=list(rates),
-                             requests=n_requests).items():
-            script = script.replace("{%s}" % key, str(val))
-        try:
-            out = subprocess.run([sys.executable, "-c", script],
-                                 env=env, capture_output=True, text=True,
-                                 timeout=520)
-            stdout, stderr = out.stdout, out.stderr
-        except subprocess.TimeoutExpired as e:
-            # salvage the rates that finished before the budget ran out
-            stdout = (e.stdout or b"").decode() if isinstance(
-                e.stdout, bytes) else (e.stdout or "")
-            err = (e.stderr or b"").decode() if isinstance(
-                e.stderr, bytes) else (e.stderr or "")
-            stderr = "subprocess timeout: " + err
-        finished = []
-        for line in stdout.splitlines():
-            if line.startswith("MESHROW "):
-                row = json.loads(line[len("MESHROW "):])
-                rows.append(row)
-                finished.append(row["offered_qps"])
-        # a salvage that silently drops configs reads as "all measured"
-        for rate in rates:
-            if rate not in finished:
-                dropped.append({"n_shards": n_shards, "offered_qps": rate})
-        if not finished:
-            emit_tail = stderr[-200:].replace("\n", " ")
-            common.emit(f"serving/mesh_s{n_shards}/FAILED", 0.0, emit_tail)
-    return rows, dropped
+        rows.extend(_mesh_drive(n_shards, rates, n_requests, spec))
+    return rows
 
 
 def _mesh_fragment(row: dict) -> str:
@@ -327,26 +295,18 @@ def main() -> None:
         artifact.append({k: v for k, v in s.items() if k != "samples_us"})
 
     # sharded closed-loop sweep: offered QPS x shard count
-    # full-mode sizing stays modest: each shard count is one subprocess
-    # on a 520s budget, and interpret-mode scoring at the bench corpus
-    # is ~10s/batch — the DROPPED salvage names anything that overruns
     mesh_shards = [1, 2] if smoke else [1, 2, 4]
     mesh_rates = [100, 400] if smoke else [50, 200, 800]
     mesh_requests = 64 if smoke else 128
-    mesh_rows, mesh_dropped = run_mesh_sweep(mesh_shards, mesh_rates,
-                                             mesh_requests)
+    mesh_rows = run_mesh_sweep(mesh_shards, mesh_rates, mesh_requests)
     for row in mesh_rows:
         common.emit(
             f"serving/mesh_s{row['n_shards']}_qps_{row['offered_qps']}",
             row["p50_us"], _mesh_fragment(row))
-    for d in mesh_dropped:
-        common.emit(
-            f"serving/mesh_s{d['n_shards']}_qps_{d['offered_qps']}/DROPPED",
-            0.0, "timed_out_before_measurement")
     common.write_bench(
         "serving",
         results={"sweep": artifact,
-                 "mesh": {"rows": mesh_rows, "dropped": mesh_dropped}},
+                 "mesh": {"rows": mesh_rows}},
         config={"rates": rates, "n_requests": n_requests,
                 "mesh": {"shard_counts": mesh_shards,
                          "rates": mesh_rates,
@@ -355,5 +315,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.kernels.runtime import enable_compile_cache
+    enable_compile_cache()
     common.set_smoke()
     main()

@@ -6,20 +6,24 @@ through isolated result-cache partitions — every response pinned to
 one epoch and bit-identical to a single-host QueryServer over the
 same view.
 
-    PYTHONPATH=src python examples/mesh_serve.py
+    PYTHONPATH=src python examples/mesh_serve.py          # 4 chips
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=src python examples/mesh_serve.py      # 4 CPU devices
 """
-import os
+import sys
 
-# the XLA host device count must be set before jax initialises — this
-# is what gives the mesh 4 "shards" on a CPU-only machine
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+import jax
+import numpy as np
 
-import numpy as np           # noqa: E402
+from repro.core import build, compaction
+from repro.core.live_index import SegmentedIndex
+from repro.serve import MeshConfig, MeshServer
+from repro.text import corpus
 
-from repro.core import build, compaction                    # noqa: E402
-from repro.core.live_index import SegmentedIndex            # noqa: E402
-from repro.serve import MeshConfig, MeshServer              # noqa: E402
-from repro.text import corpus                               # noqa: E402
+if len(jax.devices()) < 4:
+    sys.exit(f"mesh_serve: 4 shards need 4 devices, {len(jax.devices())} "
+             "present (on CPU set XLA_FLAGS="
+             "--xla_force_host_platform_device_count=4)")
 
 spec = corpus.CorpusSpec(num_docs=2000, vocab=1000, avg_distinct=30, seed=5)
 tc = corpus.generate(spec)

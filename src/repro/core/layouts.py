@@ -48,6 +48,7 @@ Array = jax.Array
 
 BLOCK = 128  # posting block size: one VPU lane-width / VMEM-friendly tile
 ROUTE_TILE = 512  # doc-tile width the scoring kernels route against
+LANES = 128  # a device row is moved by DMA in whole 128-lane widths
 
 
 def _block_tile_routing(block_min: np.ndarray, block_max: np.ndarray,
@@ -700,7 +701,7 @@ def pad_packed_to_class(ix: "PackedCsrIndex", nb_pad: int, w_pad: int,
     lane dim), so it quantizes like the other statics.
     """
     w, nb = ix.num_terms, int(ix.packed.shape[0])
-    wpb = int(ix.packed.shape[1])
+    wpb = int(ix.words_per_block)
     if nb_pad < nb or w_pad < w or words_per_block < wpb:
         raise ValueError(f"size class ({nb_pad}, {w_pad}, {words_per_block})"
                          f" below actual ({nb}, {w}, {wpb})")
@@ -721,8 +722,10 @@ def pad_packed_to_class(ix: "PackedCsrIndex", nb_pad: int, w_pad: int,
         block_bits=jnp.pad(ix.block_bits, (0, dn), constant_values=1),
         block_base=jnp.pad(ix.block_base, (0, dn)),
         block_count=jnp.pad(ix.block_count, (0, dn)),
-        packed=jnp.pad(ix.packed, ((0, dn), (0, words_per_block - wpb))),
-        block_tfs=jnp.pad(ix.block_tfs, ((0, dn), (0, 0))),
+        packed=jnp.pad(ix.packed, (
+            (0, dn), (0, lane_width(words_per_block) - ix.packed.shape[1]))),
+        tf_pairs=jnp.pad(ix.tf_pairs, (
+            (0, -(-nb_pad // 2) - ix.tf_pairs.shape[0]), (0, 0))),
         block_min=jnp.pad(ix.block_min, (0, dn)),
         block_max=jnp.pad(ix.block_max, (0, dn), constant_values=-1),
         tile_first=jnp.pad(ix.tile_first, (0, dn)),
@@ -745,10 +748,20 @@ class PackedCsrIndex:
 
     The paper (§3.1) notes DBMSs cannot apply the number encodings that
     make inverted files small.  On TPU we can: each block of 128 doc-id
-    deltas is packed at a per-block bit width into int32 words; a Pallas
-    kernel (kernels/packed_postings.py) unpacks blocks in VMEM.  First
-    entry of each block stores the absolute doc id's delta from
+    deltas is packed at a per-block bit width into int32 words; the
+    fused kernels (kernels/fused_decode_score.py) unpack blocks in VMEM.
+    First entry of each block stores the absolute doc id's delta from
     ``block_base``.
+
+    Rows are stored in the form the kernels DMA, so no call copies the
+    index: a block's words fill the first ``words_per_block`` lanes of a
+    ``lane_width(words_per_block)``-lane row (a TPU lays an array's
+    minor dim out in 128-lane tiles anyway), and the f16 tfs of blocks
+    ``2r`` and ``2r + 1`` share u32 row ``r`` of ``tf_pairs`` (low and
+    high half of each lane): f16 bytes in 32-bit rows, since one DMA
+    cannot move a single 16-bit row.  ``posting_bytes`` counts the
+    format (``words_per_block`` words and 2 bytes of tf per posting
+    slot); ``nbytes`` counts the stored arrays.
     """
     _static_fields = ("max_posting_len", "words_per_block", "block",
                       "route_tile", "route_pairs_max", "route_span_max")
@@ -758,8 +771,8 @@ class PackedCsrIndex:
     block_bits: Array     # i32[NB]     bit width of this block
     block_base: Array     # i32[NB]     absolute doc id before first entry
     block_count: Array    # i32[NB]     valid postings in this block
-    packed: Array         # u32[NB, words_per_block]  (worst-case width)
-    block_tfs: Array      # f16[NB, BLOCK]
+    packed: Array         # u32[NB, lane_width(words_per_block)]
+    tf_pairs: Array       # u32[ceil(NB/2), BLOCK]  f16 tf bits, 2 blocks
     docs: DocTable
     max_posting_len: int
     words_per_block: int
@@ -818,7 +831,7 @@ class PackedCsrIndex:
         docs = self.block_base[b] + jnp.cumsum(deltas, dtype=jnp.int32)
         valid = jnp.arange(self.block, dtype=jnp.int32) < self.block_count[b]
         docs = jnp.where(valid, docs, -1)
-        tfs = jnp.where(valid, self.block_tfs[b].astype(jnp.float32), 0.0)
+        tfs = jnp.where(valid, unpair_tfs(self.tf_pairs, b), 0.0)
         return docs, tfs, valid
 
     def gather_postings(self, term_ids: Array, cap: int
@@ -847,15 +860,42 @@ class PackedCsrIndex:
         return sum(int(x.nbytes) for x in
                    (self.sorted_hash, self.df, self.block_offsets,
                     self.block_bits, self.block_base, self.block_count,
-                    self.packed, self.block_tfs)) + self.docs.nbytes()
+                    self.packed, self.tf_pairs)) + self.docs.nbytes()
 
     def posting_bytes(self) -> int:
+        nb = int(self.packed.shape[0])
         return int(self.block_offsets.nbytes + self.block_bits.nbytes +
                    self.block_base.nbytes + self.block_count.nbytes +
-                   self.packed.nbytes + self.block_tfs.nbytes)
+                   nb * (4 * self.words_per_block + 2 * self.block))
 
 
 _register(PackedCsrIndex)
+
+
+def lane_width(words_per_block: int) -> int:
+    """Stored lane width of a packed word row: the word count rounded
+    up to whole 128-lane rows, the unit one DMA moves."""
+    return -(-max(int(words_per_block), 1) // LANES) * LANES
+
+
+def pair_tf_rows(tfs: np.ndarray) -> np.ndarray:
+    """f16 tfs ``[NB, B]`` -> u32 ``[ceil(NB/2), B]`` rows: lane ``l`` of
+    row ``r`` holds block ``2r``'s tf bits in its low half and block
+    ``2r + 1``'s in its high half."""
+    t = np.asarray(tfs, np.float16).view(np.uint16)
+    if t.shape[0] % 2:
+        t = np.concatenate([t, np.zeros((1, t.shape[1]), np.uint16)])
+    return t[0::2].astype(np.uint32) | (t[1::2].astype(np.uint32) << 16)
+
+
+def unpair_tfs(tf_pairs: Array, b: Array) -> Array:
+    """f32 tfs ``[..., B]`` of blocks ``b`` (any int shape) from
+    ``pair_tf_rows`` rows: the half ``b & 1`` of row ``b >> 1``."""
+    rows = tf_pairs[b >> 1]
+    shift = ((b & 1) * 16).astype(jnp.uint32)[..., None]
+    half = ((rows >> shift) & jnp.uint32(0xFFFF)).astype(jnp.uint16)
+    return jax.lax.bitcast_convert_type(half, jnp.float16).astype(
+        jnp.float32)
 
 
 def _pack_block_np(deltas: np.ndarray, bits: int, block: int = BLOCK
@@ -875,47 +915,58 @@ def _pack_block_np(deltas: np.ndarray, bits: int, block: int = BLOCK
 def build_packed_csr(h: PostingsHost, max_bits: int = 32,
                      block: int = BLOCK,
                      route_tile: int = ROUTE_TILE) -> PackedCsrIndex:
+    """Delta+bit-pack every term's posting blocks, vectorized over all
+    postings (the words are those ``_pack_block_np`` writes per block):
+    a block's first delta is taken against the previous block's last doc
+    id (``-1`` at term start), its width is the bit length of its
+    largest delta clipped to [1, max_bits], and lane ``l`` occupies bits
+    ``[l*width, (l+1)*width)`` of the block's little-endian word
+    stream."""
     order = np.argsort(h.term_hashes, kind="stable")
-    lengths = np.diff(h.offsets)[order]
+    lengths = np.diff(h.offsets)[order].astype(np.int64)
     nblocks = np.maximum(-(-lengths // block), (lengths > 0).astype(np.int64))
     block_offsets = np.zeros(h.num_terms + 1, dtype=np.int64)
     np.cumsum(nblocks, out=block_offsets[1:])
     NB = int(block_offsets[-1])
-    bits_arr = np.zeros(NB, dtype=np.int32)
-    base_arr = np.zeros(NB, dtype=np.int32)
-    count_arr = np.zeros(NB, dtype=np.int32)
-    min_arr = np.zeros(NB, dtype=np.int32)
-    max_arr = np.full(NB, -1, dtype=np.int32)
+    # every posting in sorted-term order: its term, its rank in the term
+    term_of = np.repeat(np.arange(h.num_terms, dtype=np.int64), lengths)
+    new_start = np.cumsum(lengths) - lengths
+    within = np.arange(term_of.shape[0], dtype=np.int64) - new_start[term_of]
+    src = h.offsets[:-1][order].astype(np.int64)[term_of] + within
+    docs = h.doc_ids[src].astype(np.int64)
+    blk = block_offsets[:-1][term_of] + within // block
+    lane = within % block
+    prev = np.empty_like(docs)
+    prev[1:] = docs[:-1]
+    prev[within == 0] = -1                  # a term's first delta: vs -1
+    deltas = docs - prev
+    bstart = np.flatnonzero(lane == 0)      # blocks are contiguous runs
+    bend = np.append(bstart[1:], docs.shape[0])[:NB] - 1
+    bmax = (np.maximum.reduceat(deltas, bstart) if NB
+            else np.zeros(0, np.int64))
+    # exact bit_length via the frexp exponent (x = m * 2**e, 0.5<=m<1)
+    _, exp = np.frexp(np.maximum(bmax, 1).astype(np.float64))
+    width = np.clip(exp.astype(np.int64), 1, max_bits)
+    bits_arr = width.astype(np.int32)
+    base_arr = prev[bstart].astype(np.int32)
+    count_arr = (bend - bstart + 1).astype(np.int32)
+    min_arr = docs[bstart].astype(np.int32)
+    max_arr = docs[bend].astype(np.int32)
     tf_arr = np.zeros((NB, block), dtype=np.float16)
-    blocks_packed = []
-    for newpos, old in enumerate(order):
-        s, e = int(h.offsets[old]), int(h.offsets[old + 1])
-        docs = h.doc_ids[s:e].astype(np.int64)
-        tfs = h.tfs[s:e]
-        b0 = int(block_offsets[newpos])
-        for k in range(int(nblocks[newpos])):
-            lo, hi = k * block, min((k + 1) * block, len(docs))
-            blk = docs[lo:hi]
-            base = int(docs[lo - 1]) if lo > 0 else -1 if len(blk) else -1
-            prev = base if lo > 0 else -1
-            deltas = np.diff(np.concatenate([[prev], blk])).astype(np.int64)
-            width = max(1, int(deltas.max()).bit_length()) if len(deltas) else 1
-            width = min(width, max_bits)
-            padded = np.zeros(block, dtype=np.int64)
-            padded[:len(deltas)] = deltas
-            blocks_packed.append(_pack_block_np(padded, width, block))
-            bidx = b0 + k
-            bits_arr[bidx] = width
-            base_arr[bidx] = prev
-            count_arr[bidx] = len(blk)
-            if len(blk):
-                min_arr[bidx] = int(blk[0])
-                max_arr[bidx] = int(blk[-1])
-            tf_arr[bidx, :len(blk)] = tfs[lo:hi]
-    words_per_block = max((len(b) for b in blocks_packed), default=1)
-    packed = np.zeros((NB, words_per_block), dtype=np.uint32)
-    for i, b in enumerate(blocks_packed):
-        packed[i, :len(b)] = b
+    tf_arr[blk, lane] = h.tfs[src]
+    words_blk = (block * width + 31) // 32
+    words_per_block = int(words_blk.max()) if NB else 1
+    lanes = lane_width(words_per_block)
+    bitpos = lane * width[blk]
+    wi, off = bitpos >> 5, (bitpos & 31).astype(np.uint64)
+    dv = deltas.astype(np.uint64)
+    flat = np.zeros(NB * lanes, dtype=np.uint64)
+    at = blk * lanes + wi
+    np.bitwise_or.at(flat, at, (dv << off) & np.uint64(0xFFFFFFFF))
+    spill = np.where(off > 0, dv >> (np.uint64(32) - off), np.uint64(0))
+    keep = (spill != 0) & (wi + 1 < words_blk[blk])
+    np.bitwise_or.at(flat, at[keep] + 1, spill[keep])
+    packed = flat.reshape(NB, lanes).astype(np.uint32)
     tfirst, tcount = _block_tile_routing(min_arr, max_arr, h.num_docs,
                                          route_tile)
     return PackedCsrIndex(
@@ -924,7 +975,7 @@ def build_packed_csr(h: PostingsHost, max_bits: int = 32,
         block_offsets=jnp.asarray(block_offsets.astype(np.int32)),
         block_bits=jnp.asarray(bits_arr), block_base=jnp.asarray(base_arr),
         block_count=jnp.asarray(count_arr), packed=jnp.asarray(packed),
-        block_tfs=jnp.asarray(tf_arr),
+        tf_pairs=jnp.asarray(pair_tf_rows(tf_arr)),
         docs=DocTable(norm=jnp.asarray(h.norm), rank=jnp.asarray(h.rank)),
         max_posting_len=h.max_posting_len,
         words_per_block=words_per_block,

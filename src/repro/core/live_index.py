@@ -119,20 +119,31 @@ Array = jax.Array
 # ---------------------------------------------------------------------------
 
 
-@jax.jit
-def _query_weights(df: Array, d_live: Array):
-    """Global idf weights + query norms, same op sequence as the oracle.
+def query_weights(df: np.ndarray, live_docs: int):
+    """Global idf weights + query norms of dedup'd query rows, on the host.
 
-    df i32[B, T] LIVE global document frequencies per (dedup'd) slot,
-    d_live f32 scalar live doc count.  Bit-identical to
-    ``query.idf`` + the oracle's qnorm reduction, so every segment
-    scores with exactly the weights a from-scratch rebuild would use.
-    """
-    safe = jnp.maximum(df, 1)
-    idf = jnp.where(df > 0, jnp.log1p(d_live / safe.astype(jnp.float32)),
-                    0.0)
-    qnorm = jnp.sqrt(jnp.maximum(jnp.sum(idf * idf, axis=1), 1e-12))
-    return idf, qnorm
+    df i32[..., T] LIVE global document frequencies per (dedup'd) slot.
+    Same formula as ``query.idf`` + the oracle's qnorm reduction, in
+    float32 numpy: every server (single host, every shard of a mesh)
+    takes its weights from here, so a query scores with the same bits
+    whatever batch shape or program it rides in.  Returns
+    (idf f32[..., T], qnorm f32[...])."""
+    df = np.asarray(df)
+    d_live = np.float32(live_docs)
+    safe = np.maximum(df, 1).astype(np.float32)
+    idf = np.where(df > 0, np.log1p(d_live / safe),
+                   np.float32(0)).astype(np.float32)
+    return idf, query_norms(idf)
+
+
+def query_norms(idf: np.ndarray) -> np.ndarray:
+    """f32[...] norms of idf rows f32[..., T], summed slot by slot on
+    the host (numpy's own reduction order depends on the shape)."""
+    idf = np.asarray(idf, np.float32)
+    sq = np.zeros(idf.shape[:-1], np.float32)
+    for t in range(idf.shape[-1]):
+        sq = sq + idf[..., t] * idf[..., t]
+    return np.sqrt(np.maximum(sq, np.float32(1e-12))).astype(np.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("k_tile", "tile", "rank_blend"))
@@ -200,7 +211,6 @@ def scorer_cache_sizes() -> dict:
     measurable form of the recompile-avoidance contract."""
     sizes = dict(ops.segment_scorer_cache_sizes())
     sizes.update({
-        "query_weights": _query_weights._cache_size(),
         "delta_candidates": _delta_candidates._cache_size(),
         "delta_conjunctive": _delta_conjunctive._cache_size(),
     })
@@ -422,9 +432,8 @@ class LiveView:
                           0).astype(np.int32)
         else:
             df = np.zeros(qh.shape, np.int32)
-        idf_w, qnorm = _query_weights(
-            jnp.asarray(df), jnp.asarray(np.float32(self.live_docs)))
-        return qh, tids, idf_w, qnorm
+        idf_w, qnorm = query_weights(df, self.live_docs)
+        return qh, tids, jnp.asarray(idf_w), jnp.asarray(qnorm)
 
     def topk(self, query_hashes, k: int, *, cap: int | None = None,
              rank_blend: float = 0.0, engine: str = "pallas",
@@ -445,9 +454,9 @@ class LiveView:
         Kernel geometry resolves PER SEGMENT from the active tuning
         table (``tune`` overrides it for every segment): each sealed
         segment's (backend, size_class, layout) picks its own tile
-        width / reducer / unroll, so a view mixing a 4k-doc segment and
-        a 512k-doc segment runs each at its tuned shape.  The delta
-        always scores at the default tile (its buffers are
+        width / reducer / candidate count, so a view mixing a 4k-doc
+        segment and a 512k-doc segment runs each at its tuned shape.
+        The delta always scores at the default tile (its buffers are
         capacity-padded, not size-classed) with ``k_tile`` clamped to
         that tile — exactness only needs ``k_tile >= min(k, tile)`` per
         SOURCE, and the host merge accepts ragged widths."""
@@ -466,14 +475,14 @@ class LiveView:
             cfg = (tune if tune is not None else autotune.lookup(
                 backend, int(seg.index.docs.num_docs), seg.layout))
             seg_kt = cfg.resolve_k_tile(k)
+            c = int(cap) if cap is not None else seg.index.max_posting_len
             if seg.layout == "banded":
-                mp_p, mp_h = ops.banded_pairs_budgets(
-                    seg.index, cfg.tile, cfg.pairs_per_step)
+                mp_p, mp_h, cap_p, cap_h = ops.banded_pairs_budgets(
+                    seg.index, *qh.shape, c, cfg.tile)
                 mp = mp_p + mp_h
             else:
-                mp = ops.padded_pairs_budget(seg.index, cfg.tile,
-                                             cfg.pairs_per_step)
-            c = int(cap) if cap is not None else seg.index.max_posting_len
+                mp = ops.default_max_pairs(seg.index, *qh.shape, c,
+                                           cfg.tile)
             b = jnp.asarray(np.int32(seg.doc_base))
             span = None
             if trace is not None:
@@ -481,9 +490,7 @@ class LiveView:
                     "segment", parent="score", doc_base=int(seg.doc_base),
                     size_class=int(seg.size_class), layout=seg.layout,
                     tile=int(cfg.tile), k_tile=int(seg_kt),
-                    reducer=cfg.reducer,
-                    pairs_per_step=int(cfg.pairs_per_step),
-                    max_pairs=int(mp),
+                    reducer=cfg.reducer, max_pairs=int(mp),
                     candidate_bytes=size_model.candidate_bytes_per_query(
                         int(seg.index.docs.num_docs), int(cfg.tile),
                         int(seg_kt)),
@@ -493,32 +500,29 @@ class LiveView:
                        if seg.layout == "banded" else {}))
             if engine == "jnp":
                 v, g, o = ops.jnp_segment_topk(
-                    seg.index, qh_dev, idf_w, b, k_tile=k_tile, cap=c,
-                    rank_blend=rank_blend)
+                    seg.index, qh_dev, idf_w, qnorm, b, k_tile=k_tile,
+                    cap=c, rank_blend=rank_blend)
             elif seg.layout == "banded":
                 # one fused dense launch per band, partials summed in
                 # the engine; both "candidates" and "dense" modes route
                 # here (a per-band candidate top-k cannot merge — scores
                 # are additive over terms, not max-mergeable)
                 v, g, o = ops.fused_segment_banded_topk(
-                    seg.index, qh_dev, idf_w, b, k_tile=seg_kt,
-                    cap_packed=min(c, max(
-                        seg.index.packed.max_posting_len, 1)),
-                    cap_hor=min(c, max(seg.index.hor.max_posting_len, 1)),
+                    seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt,
+                    cap_packed=cap_p, cap_hor=cap_h,
                     max_pairs_packed=mp_p, max_pairs_hor=mp_h,
                     rank_blend=rank_blend, tile=cfg.tile,
                     backend=backend, q_pad=cfg.q_pad)
             elif mode == "dense":
                 v, g, o = ops.fused_segment_dense_topk(
-                    seg.index, qh_dev, idf_w, b, k_tile=seg_kt, cap=c,
+                    seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt, cap=c,
                     max_pairs=mp, rank_blend=rank_blend, tile=cfg.tile,
                     backend=backend, q_pad=cfg.q_pad)
             else:
                 v, g, o = ops.fused_segment_topk(
-                    seg.index, qh_dev, idf_w, b, k_tile=seg_kt, cap=c,
+                    seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt, cap=c,
                     max_pairs=mp, rank_blend=rank_blend, tile=cfg.tile,
-                    backend=backend, q_pad=cfg.q_pad, reducer=cfg.reducer,
-                    pairs_per_step=cfg.pairs_per_step)
+                    backend=backend, q_pad=cfg.q_pad, reducer=cfg.reducer)
             # keep device arrays until every segment is dispatched —
             # transferring here would serialize the per-segment launches
             vals.append(v)

@@ -63,10 +63,18 @@ def final_scores(scores: Array, norm: Array, rank: Array, qnorm: Array,
     (``fused_decode_score._final_from_acc``), so candidate values are
     bit-identical to this dense reference.
     """
+    return scoring_tail(scores, norm[None, :], rank[None, :],
+                        qnorm[:, None], rank_blend)
+
+
+def scoring_tail(scores: Array, norm: Array, rank: Array, qnorm: Array,
+                 rank_blend: float) -> Array:
+    """``final_scores`` on pre-broadcast operands: norm/rank ``[1, D]``
+    rows, qnorm a ``[B, 1]`` column — the form a kernel holds in VMEM."""
     live = norm > 0
-    cosine = scores / (jnp.maximum(norm, 1e-12)[None, :] * qnorm[:, None])
-    final = cosine + rank_blend * rank[None, :]
-    return jnp.where(live[None, :] & (scores > 0), final, -jnp.inf)
+    cosine = scores / (jnp.maximum(norm, 1e-12) * qnorm)
+    final = cosine + rank_blend * rank
+    return jnp.where(live & (scores > 0), final, -jnp.inf)
 
 
 def accumulate_scores(doc_ids: Array, weights: Array, valid: Array,
@@ -75,11 +83,17 @@ def accumulate_scores(doc_ids: Array, weights: Array, valid: Array,
 
     doc_ids/weights/valid: [T, cap].  Invalid postings are routed to a
     trash row (index num_docs).  Returns f32[num_docs].
+
+    One scatter per term row, in row order: a row's doc ids are
+    distinct, so each doc's sum is taken in row order on every backend
+    (a single scatter over all rows leaves the order of a doc's
+    duplicate updates to the compiler).
     """
-    flat_docs = jnp.where(valid, doc_ids, num_docs).reshape(-1)
-    flat_w = jnp.where(valid, weights, 0.0).reshape(-1)
+    docs = jnp.where(valid, doc_ids, num_docs)
+    w = jnp.where(valid, weights, 0.0)
     acc = jnp.zeros((num_docs + 1,), jnp.float32)
-    acc = acc.at[flat_docs].add(flat_w, mode="drop")
+    for t in range(docs.shape[0]):
+        acc = acc.at[docs[t]].add(w[t], mode="drop")
     return acc[:num_docs]
 
 
@@ -182,7 +196,7 @@ def fused_score_queries(index: Any, query_hashes: Array, k: int, cap: int,
             index, term_ids, idf_t, cap, k, rank_blend=rank_blend,
             max_pairs=max_pairs, backend=backend, tile=tune.tile,
             k_tile=tune.resolve_k_tile(k), q_pad=tune.q_pad,
-            reducer=tune.reducer, pairs_per_step=tune.pairs_per_step)
+            reducer=tune.reducer)
         ops.warn_on_overflow(overflow, "fused engine")
         top_scores, top_docs = merge_topk_candidates(cand_v, cand_i, k)
     else:

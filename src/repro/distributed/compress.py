@@ -17,7 +17,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.shmap import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -84,7 +83,7 @@ def make_compressed_grad_fn(loss_fn, mesh: Mesh, axis: str):
         return loss, new_g, new_e
 
     def wrapped(params, batch, err):
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(grads_with_feedback),
             mesh=mesh,
             in_specs=(P(), P(axis), P()),

@@ -17,7 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.shmap import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -57,7 +56,7 @@ def splitk_decode_attention(mesh: Mesh, axis: str):
         local = seq // n
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(), P(None, None, axis, None),
                       P(None, None, axis, None), P()),
             out_specs=P(), check_vma=False)

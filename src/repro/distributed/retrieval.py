@@ -50,7 +50,6 @@ from repro.core import layouts, segments
 from repro.core.layouts import PostingsHost
 from repro.core.query import dedup_query_hashes, idf as idf_fn
 from repro.distributed.topk import local_topk_merge
-from repro.distributed.shmap import shard_map
 
 Array = jax.Array
 
@@ -155,7 +154,7 @@ def make_doc_sharded_scorer(index: DocShardedIndex, mesh: Mesh, axis: str,
                 "doc_ids", "tfs", "norm", "doc_base")}
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(sharded, P()), out_specs=(P(), P()), check_vma=False)
     def score(ix, qh):
         sq = {n: v[0] for n, v in ix.items()}    # drop shard dim
@@ -265,7 +264,7 @@ def make_term_sharded_scorer(index: TermShardedIndex, mesh: Mesh, axis: str,
     sharded["norm"] = P()
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(sharded, P()), out_specs=(P(), P()), check_vma=False)
     def score(ix, qh):
         sq = {n: (v[0] if n != "norm" else v) for n, v in ix.items()}
@@ -423,8 +422,9 @@ class PackedDocShardedIndex:
     HOR-only gap of the bulk doc-sharded path).
 
     Each shard re-compresses its document slice: LOCAL doc-id deltas
-    bit-packed at per-block minimal widths, f16 tfs, the per-block
-    (bits, base, count) decode scalars, and routing recomputed against
+    bit-packed at per-block minimal widths, f16 tfs in u32 pair rows
+    (``layouts.pair_tf_rows``), the per-block (bits, base, count)
+    decode scalars, and routing recomputed against
     the PADDED local doc space so every shard's kernel sees the same
     tile grid.  Cross-shard padding blocks carry ``bits=1, count=0`` —
     they decode to nothing, the same inert-padding trick the packed
@@ -433,8 +433,8 @@ class PackedDocShardedIndex:
     sorted_hash: np.ndarray    # u32[S, W]
     df_global: np.ndarray      # i32[S, W]
     block_offsets: np.ndarray  # i32[S, W+1]
-    packed: np.ndarray         # u32[S, NBmax, WPB]  LOCAL-doc deltas
-    block_tfs: np.ndarray      # f16[S, NBmax, BLOCK]
+    packed: np.ndarray         # u32[S, NBmax, lanes]  LOCAL-doc deltas
+    tf_pairs: np.ndarray       # u32[S, ceil(NBmax/2), BLOCK]
     block_bits: np.ndarray     # i32[S, NBmax]  (1 on padding blocks)
     block_base: np.ndarray     # i32[S, NBmax]
     block_count: np.ndarray    # i32[S, NBmax]  (0 on padding blocks)
@@ -474,8 +474,8 @@ def build_doc_sharded_packed(host: PostingsHost, n_shards: int,
     nbmax = max(int(ix.packed.shape[0]) for ix in shards)
     wpb = max(ix.words_per_block for ix in shards)
     S = n_shards
-    pk = np.zeros((S, nbmax, wpb), dtype=np.uint32)
-    bt = np.zeros((S, nbmax, block), dtype=np.float16)
+    pk = np.zeros((S, nbmax, layouts.lane_width(wpb)), dtype=np.uint32)
+    tp = np.zeros((S, -(-nbmax // 2), block), dtype=np.uint32)
     bits_a = np.ones((S, nbmax), dtype=np.int32)   # padding decodes inert
     base_a = np.zeros((S, nbmax), dtype=np.int32)
     cnt_a = np.zeros((S, nbmax), dtype=np.int32)
@@ -485,8 +485,8 @@ def build_doc_sharded_packed(host: PostingsHost, n_shards: int,
     norm_a = np.zeros((S, dmax), dtype=np.float32)
     for s, ix in enumerate(shards):
         nb = int(ix.packed.shape[0])
-        pk[s, :nb, :ix.words_per_block] = np.asarray(ix.packed)
-        bt[s, :nb] = np.asarray(ix.block_tfs)
+        pk[s, :nb, :ix.packed.shape[1]] = np.asarray(ix.packed)
+        tp[s, :ix.tf_pairs.shape[0]] = np.asarray(ix.tf_pairs)
         bits_a[s, :nb] = np.asarray(ix.block_bits)
         base_a[s, :nb] = np.asarray(ix.block_base)
         cnt_a[s, :nb] = np.asarray(ix.block_count)
@@ -505,7 +505,7 @@ def build_doc_sharded_packed(host: PostingsHost, n_shards: int,
             host.term_hashes[order][None, :], (S, W)).copy(),
         df_global=np.broadcast_to(
             host.df[order].astype(np.int32)[None, :], (S, W)).copy(),
-        block_offsets=offs_a, packed=pk, block_tfs=bt, block_bits=bits_a,
+        block_offsets=offs_a, packed=pk, tf_pairs=tp, block_bits=bits_a,
         block_base=base_a, block_count=cnt_a,
         tile_first=tf_arr, tile_count=tc_arr, norm=norm_a,
         doc_base=bounds[:-1].astype(np.int32), n_shards=S,
@@ -570,7 +570,7 @@ def make_doc_sharded_fused_scorer(
         build_batched_pairs, default_k_tile, fused_topk_blocked_pallas,
         fused_topk_packed_pallas)
     from repro.kernels.ops import (expand_block_candidates,
-                                    round_up_pairs, warn_on_overflow)
+                                    term_pairs_bound, warn_on_overflow)
 
     packed_layout = isinstance(index, PackedDocShardedIndex)
     arrs = index.device_arrays()
@@ -582,24 +582,24 @@ def make_doc_sharded_fused_scorer(
     m_blocks = max(index.max_blocks_per_term, 1)
     # tuned geometry for this shard size — the tile itself is pinned by
     # the sharded routing arrays, so only the routing-free axes (k_pad,
-    # q_pad, reducer, unroll) follow the tuning table
+    # q_pad, reducer) follow the tuning table
     cfg = autotune.lookup("pallas", dmax,
                           "packed" if packed_layout else "hor")
     q_pad = cfg.q_pad
-    pps = cfg.pairs_per_step
     if cfg.tile == tile:
         k_tile = cfg.resolve_k_tile(k)
     else:
         k_tile = min(default_k_tile(k, tile, cfg.k_pad), tile)
 
     names = ("sorted_hash", "df_global", "block_offsets", "tile_first",
-             "tile_count", "norm", "doc_base", "block_tfs")
-    names += (("packed", "block_bits", "block_base", "block_count")
-              if packed_layout else ("block_docs",))
+             "tile_count", "norm", "doc_base")
+    names += (("packed", "tf_pairs", "block_bits", "block_base",
+               "block_count") if packed_layout
+              else ("block_docs", "block_tfs"))
     sharded = {n: P(axis) for n in names}
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(sharded, P()), out_specs=(P(), P()), check_vma=False)
     def score(ix, qh):
         sq = {n: v[0] for n, v in ix.items()}    # drop shard dim
@@ -616,15 +616,11 @@ def make_doc_sharded_fused_scorer(
             expand_block_candidates(sq["block_offsets"], tid[None],
                                     w[None], m_blocks, block)
         max_pairs = max(min(index.route_pairs_max,
-                            t * m_blocks * max(index.route_span_max, 1)), 8)
-        if pps > 1:
-            # run-aligned padding inserts up to pps-1 no-op pairs per tile
-            max_pairs += n_tiles * (pps - 1)
-        max_pairs = round_up_pairs(max_pairs, pps)
+                            t * m_blocks * max(index.route_span_max, 1),
+                            term_pairs_bound(t, m_blocks, n_tiles)), 8)
         pb, pt, pqw, pcap, ovf = build_batched_pairs(
             cand_block, cand_valid, cand_q, cand_w,
-            sq["tile_first"], sq["tile_count"], n_tiles, 1, max_pairs,
-            pairs_per_step=pps)
+            sq["tile_first"], sq["tile_count"], n_tiles, 1, max_pairs)
         # budget above is exact, so this won't fire unless the budget
         # formula is ever loosened
         warn_on_overflow(ovf, "doc-sharded fused engine")
@@ -633,16 +629,16 @@ def make_doc_sharded_fused_scorer(
         qn = jnp.full((q_pad,), 1.0, jnp.float32).at[0].set(qnorm)
         if packed_layout:
             vals, ids = fused_topk_packed_pallas(
-                sq["packed"], sq["block_tfs"], pb, pt, pqw, pcap,
+                sq["packed"], sq["tf_pairs"], pb, pt, pqw, pcap,
                 sq["block_bits"][pb], sq["block_base"][pb],
                 sq["block_count"][pb], sq["norm"],
                 jnp.zeros_like(sq["norm"]), qn, dmax, block, k_tile,
-                tile=tile, reducer=cfg.reducer, pairs_per_step=pps)
+                tile=tile, reducer=cfg.reducer)
         else:
             vals, ids = fused_topk_blocked_pallas(
                 sq["block_docs"], sq["block_tfs"], pb, pt, pqw, pcap,
                 sq["norm"], jnp.zeros_like(sq["norm"]), qn, dmax, k_tile,
-                tile=tile, reducer=cfg.reducer, pairs_per_step=pps)
+                tile=tile, reducer=cfg.reducer)
         gids = jnp.where(ids[0] >= 0, ids[0] + sq["doc_base"], -1)
         return local_candidate_merge(vals[0], gids, k, axis)
 
@@ -725,7 +721,7 @@ def _segment_group_key(ix) -> StackGroupMeta:
 def _group_array_names(layout: str) -> tuple:
     common = ("sorted_hash", "block_offsets", "tile_first", "tile_count",
               "norm", "doc_base")
-    packed = ("packed", "block_tfs", "block_bits", "block_base",
+    packed = ("packed", "tf_pairs", "block_bits", "block_base",
               "block_count")
     if layout == "banded":
         # the un-prefixed block arrays are the packed band's (the vocab
@@ -755,8 +751,10 @@ def _empty_group_arrays(meta: StackGroupMeta, n_shards: int) -> dict:
     }
     if meta.layout in ("packed", "banded"):
         arrays.update({
-            "packed": np.zeros((S, G, nb, meta.words_per_block), np.uint32),
-            "block_tfs": np.zeros((S, G, nb, b), np.float16),
+            "packed": np.zeros(
+                (S, G, nb, layouts.lane_width(meta.words_per_block)),
+                np.uint32),
+            "tf_pairs": np.zeros((S, G, -(-nb // 2), b), np.uint32),
             "block_bits": np.ones((S, G, nb), np.int32),
             "block_base": np.zeros((S, G, nb), np.int32),
             "block_count": np.zeros((S, G, nb), np.int32),
@@ -796,7 +794,7 @@ def _fill_group_slot(arrays: dict, s: int, g: int, seg) -> None:
     arrays["doc_base"][s, g] = seg.doc_base
     if isinstance(ix, layouts.PackedCsrIndex):
         arrays["packed"][s, g] = np.asarray(ix.packed)
-        arrays["block_tfs"][s, g] = np.asarray(ix.block_tfs)
+        arrays["tf_pairs"][s, g] = np.asarray(ix.tf_pairs)
         arrays["block_bits"][s, g] = np.asarray(ix.block_bits)
         arrays["block_base"][s, g] = np.asarray(ix.block_base)
         arrays["block_count"][s, g] = np.asarray(ix.block_count)
@@ -821,7 +819,7 @@ class SegmentStackShards:
     vocab_hash: np.ndarray     # u32[Wp] unified, hash-sorted (replicated)
     vocab_df: np.ndarray       # i32[Wp] LIVE global df (replicated)
     n_shards: int
-    live_docs: int             # D behind idf (traced at query time)
+    live_docs: int             # D behind idf (host query weights)
     tile: int
 
     def signature(self) -> tuple:
@@ -829,13 +827,21 @@ class SegmentStackShards:
         return tuple(meta for meta, _ in self.groups)
 
     def device_arrays(self) -> dict:
-        return {
-            "groups": [{n: jnp.asarray(v) for n, v in arrays.items()}
-                       for _, arrays in self.groups],
-            "vocab_hash": jnp.asarray(self.vocab_hash),
-            "vocab_df": jnp.asarray(self.vocab_df),
-            "live_docs": jnp.float32(self.live_docs),
-        }
+        return {"groups": [{n: jnp.asarray(v) for n, v in arrays.items()}
+                           for _, arrays in self.groups]}
+
+    def query_weights(self, qh):
+        """(dedup'd hashes u32[T], idf f32[T], qnorm f32) of one query
+        from the replicated live vocabulary stats — the single-node live
+        index's host computation (``live_index.query_weights``)."""
+        from repro.core.live_index import _dedup_np, query_weights
+        qh = _dedup_np(np.asarray(qh, np.uint32))
+        pos = np.clip(np.searchsorted(self.vocab_hash, qh), 0,
+                      len(self.vocab_hash) - 1)
+        hit = (self.vocab_hash[pos] == qh) & (qh != 0)
+        w, qnorm = query_weights(np.where(hit, self.vocab_df[pos], 0),
+                                 self.live_docs)
+        return qh, w, qnorm
 
 
 def stack_segment_shards(live_index, n_shards: int) -> SegmentStackShards:
@@ -938,7 +944,7 @@ def _build_stack_scorer(mesh: Mesh, axis: str, k: int, tile: int,
         build_batched_pairs, default_k_tile, extract_tile_candidates,
         fused_score_blocked_pallas, fused_score_packed_pallas,
         fused_topk_blocked_pallas, fused_topk_packed_pallas)
-    from repro.kernels.ops import expand_block_candidates, round_up_pairs
+    from repro.kernels.ops import expand_block_candidates, term_pairs_bound
 
     if not cfgs:
         cfgs = tuple(autotune.lookup("pallas", m.d_pad, m.layout)
@@ -953,25 +959,16 @@ def _build_stack_scorer(mesh: Mesh, axis: str, k: int, tile: int,
         return min(default_k_tile(k, tile, cfg.k_pad), tile)
     group_specs = [{n: P(axis) for n in _group_array_names(m.layout)}
                    for m in metas]
-    in_specs = ({"groups": group_specs, "vocab_hash": P(),
-                 "vocab_df": P(), "live_docs": P()}, P())
+    in_specs = ({"groups": group_specs}, P(), P(), P())
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
+        jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
         check_vma=False)
-    def score(ix, qh):
-        qh = dedup_query_hashes(qh)
+    def score(ix, qh, w, qnorm):
+        # qh is dedup'd and (w, qnorm) are the global idf weights of the
+        # live vocabulary, computed on the host exactly as the
+        # single-node live index computes them (live_index.query_weights)
         t = qh.shape[0]
-        # global idf from the replicated live vocabulary stats; the live
-        # doc count is TRACED (same op sequence as the live index's
-        # _query_weights), so ingest between stack rebuilds changes no
-        # static — only array contents
-        vh, vdf = ix["vocab_hash"], ix["vocab_df"]
-        vpos = jnp.searchsorted(vh, qh).astype(jnp.int32)
-        vpos = jnp.clip(vpos, 0, vh.shape[0] - 1)
-        vhit = (vh[vpos] == qh) & (qh != 0)
-        w = idf_fn(jnp.where(vhit, vdf[vpos], 0), ix["live_docs"])
-        qnorm = jnp.sqrt(jnp.maximum(jnp.sum(w * w), 1e-12))
         all_v, all_i = [], []
         for meta, cfg, g_arrs in zip(metas, cfgs, ix["groups"]):
             sq = {n: v[0] for n, v in g_arrs.items()}   # drop shard dim
@@ -986,11 +983,11 @@ def _build_stack_scorer(mesh: Mesh, axis: str, k: int, tile: int,
                 # launches, shared scoring tail, per-tile candidates
                 m_h = max(meta.hor_max_blocks_per_term, 1)
                 mp_p = max(min(meta.route_pairs_max,
-                               t * m_blocks * max(meta.route_span_max, 1)),
-                           8)
+                               t * m_blocks * max(meta.route_span_max, 1),
+                               term_pairs_bound(t, m_blocks, n_tiles)), 8)
                 mp_h = max(min(meta.hor_route_pairs_max,
-                               t * m_h * max(meta.hor_route_span_max, 1)),
-                           8)
+                               t * m_h * max(meta.hor_route_span_max, 1),
+                               term_pairs_bound(t, m_h, n_tiles)), 8)
                 for g in range(meta.n_slots):
                     pos = jnp.searchsorted(sq["sorted_hash"][g],
                                            qh).astype(jnp.int32)
@@ -1005,7 +1002,7 @@ def _build_stack_scorer(mesh: Mesh, axis: str, k: int, tile: int,
                         sq["tile_count"][g], n_tiles, 1, mp_p)
                     pqw = jnp.pad(pqw, ((0, 0), (0, cfg.q_pad - 1)))
                     acc = fused_score_packed_pallas(
-                        sq["packed"][g], sq["block_tfs"][g], pb, pt, pqw,
+                        sq["packed"][g], sq["tf_pairs"][g], pb, pt, pqw,
                         pcap, sq["block_bits"][g][pb],
                         sq["block_base"][g][pb], sq["block_count"][g][pb],
                         meta.d_pad, meta.block, tile)[0]
@@ -1029,14 +1026,10 @@ def _build_stack_scorer(mesh: Mesh, axis: str, k: int, tile: int,
                     all_i.append(jnp.where(ids[0] >= 0,
                                            ids[0] + sq["doc_base"][g], -1))
                 continue
-            pps = cfg.pairs_per_step
             qn = jnp.full((cfg.q_pad,), 1.0, jnp.float32).at[0].set(qnorm)
             max_pairs = max(min(meta.route_pairs_max,
-                                t * m_blocks * max(meta.route_span_max, 1)),
-                            8)
-            if pps > 1:
-                max_pairs += n_tiles * (pps - 1)
-            max_pairs = round_up_pairs(max_pairs, pps)
+                                t * m_blocks * max(meta.route_span_max, 1),
+                                term_pairs_bound(t, m_blocks, n_tiles)), 8)
             for g in range(meta.n_slots):             # static stack depth
                 pos = jnp.searchsorted(sq["sorted_hash"][g],
                                        qh).astype(jnp.int32)
@@ -1050,23 +1043,22 @@ def _build_stack_scorer(mesh: Mesh, axis: str, k: int, tile: int,
                 pb, pt, pqw, pcap, _ovf = build_batched_pairs(
                     cand_block, cand_valid, cand_q, cand_w,
                     sq["tile_first"][g], sq["tile_count"][g], n_tiles, 1,
-                    max_pairs, pairs_per_step=pps)
+                    max_pairs)
                 pqw = jnp.pad(pqw, ((0, 0), (0, cfg.q_pad - 1)))
                 if meta.layout == "packed":
                     vals, ids = fused_topk_packed_pallas(
-                        sq["packed"][g], sq["block_tfs"][g], pb, pt, pqw,
+                        sq["packed"][g], sq["tf_pairs"][g], pb, pt, pqw,
                         pcap, sq["block_bits"][g][pb],
                         sq["block_base"][g][pb], sq["block_count"][g][pb],
                         sq["norm"][g], jnp.zeros_like(sq["norm"][g]), qn,
                         meta.d_pad, meta.block, k_tile, tile=tile,
-                        reducer=cfg.reducer, pairs_per_step=pps)
+                        reducer=cfg.reducer)
                 else:
                     vals, ids = fused_topk_blocked_pallas(
                         sq["block_docs"][g], sq["block_tfs"][g], pb, pt,
                         pqw, pcap, sq["norm"][g],
                         jnp.zeros_like(sq["norm"][g]), qn, meta.d_pad,
-                        k_tile, tile=tile,
-                        reducer=cfg.reducer, pairs_per_step=pps)
+                        k_tile, tile=tile, reducer=cfg.reducer)
                 all_v.append(vals[0])
                 all_i.append(jnp.where(ids[0] >= 0,
                                        ids[0] + sq["doc_base"][g], -1))
@@ -1109,8 +1101,7 @@ def make_doc_sharded_segment_scorer(index: SegmentStackShards, mesh: Mesh,
     # stale geometry
     cfgs = tuple(autotune.lookup("pallas", m.d_pad, m.layout)
                  for m in metas)
-    key = (mesh, axis, k, index.tile, index.n_shards,
-           int(index.vocab_hash.shape[0]), metas, cfgs)
+    key = (mesh, axis, k, index.tile, index.n_shards, metas, cfgs)
     fn = _STACK_SCORER_CACHE.get(key)
     if fn is None:
         fn = _build_stack_scorer(mesh, axis, k, index.tile, metas, cfgs)
@@ -1120,13 +1111,14 @@ def make_doc_sharded_segment_scorer(index: SegmentStackShards, mesh: Mesh,
     def scorer(qh, trace=None):
         # trace=None is the hot path: no span objects, no extra sync —
         # the caller blocks on the results whenever it reads them
+        qh, w, qnorm = index.query_weights(qh)
         if trace is None:
-            return fn(arrs, qh)
+            return fn(arrs, qh, w, qnorm)
         span = trace.span(
             "shard_fanout", parent="score", n_shards=index.n_shards,
             k=k, groups=[{"size_class": m.d_pad, "layout": m.layout}
                          for m in metas])
-        out = fn(arrs, qh)
+        out = fn(arrs, qh, w, qnorm)
         span.end()
         sync = trace.span("shard_sync", parent="score")
         out = jax.block_until_ready(out)
@@ -1215,16 +1207,17 @@ class PackedTermShardedIndex:
     Each shard owns a contiguous hash range of the vocabulary as whole
     posting lists, re-compressed per shard: doc-id deltas bit-packed at
     a per-block width (GLOBAL doc ids, so the doc/tile space is the full
-    corpus and identical on every shard), f16 tfs, plus the per-block
-    decode scalars and the build-time (block -> doc-tile) routing cache.
+    corpus and identical on every shard), f16 tfs in u32 pair rows, plus
+    the per-block decode scalars and the build-time (block -> doc-tile)
+    routing cache.
     The fused kernel decodes blocks IN VMEM, so the compressed words are
     the only posting bytes a query moves across HBM per shard.
     """
     sorted_hash: np.ndarray    # u32[S, Wmax]  (padded with 0xFFFFFFFF)
     df: np.ndarray             # i32[S, Wmax]  global df (terms are whole)
     block_offsets: np.ndarray  # i32[S, Wmax+1]
-    packed: np.ndarray         # u32[S, NBmax, WPB]  bit-packed deltas
-    block_tfs: np.ndarray      # f16[S, NBmax, BLOCK]
+    packed: np.ndarray         # u32[S, NBmax, lanes]  bit-packed deltas
+    tf_pairs: np.ndarray       # u32[S, ceil(NBmax/2), BLOCK]
     block_bits: np.ndarray     # i32[S, NBmax]  (1 on padding blocks)
     block_base: np.ndarray     # i32[S, NBmax]
     block_count: np.ndarray    # i32[S, NBmax]  (0 on padding blocks)
@@ -1291,8 +1284,8 @@ def build_term_sharded_packed(host: PostingsHost, n_shards: int
     sh_a = np.full((S, wmax), 0xFFFFFFFF, np.uint32)
     df_a = np.zeros((S, wmax), np.int32)
     offs_a = np.zeros((S, wmax + 1), np.int32)
-    pk = np.zeros((S, nbmax, wpb), np.uint32)
-    bt = np.zeros((S, nbmax, block), np.float16)
+    pk = np.zeros((S, nbmax, layouts.lane_width(wpb)), np.uint32)
+    tp = np.zeros((S, -(-nbmax // 2), block), np.uint32)
     bits_a = np.ones((S, nbmax), np.int32)     # padding blocks decode inert
     base_a = np.zeros((S, nbmax), np.int32)
     cnt_a = np.zeros((S, nbmax), np.int32)
@@ -1305,8 +1298,8 @@ def build_term_sharded_packed(host: PostingsHost, n_shards: int
         df_a[s, :w] = np.asarray(ix.df)
         offs_a[s, :w + 1] = np.asarray(ix.block_offsets)
         offs_a[s, w + 1:] = offs_a[s, w]
-        pk[s, :nb, :ix.words_per_block] = np.asarray(ix.packed)
-        bt[s, :nb] = np.asarray(ix.block_tfs)
+        pk[s, :nb, :ix.packed.shape[1]] = np.asarray(ix.packed)
+        tp[s, :ix.tf_pairs.shape[0]] = np.asarray(ix.tf_pairs)
         bits_a[s, :nb] = np.asarray(ix.block_bits)
         base_a[s, :nb] = np.asarray(ix.block_base)
         cnt_a[s, :nb] = np.asarray(ix.block_count)
@@ -1314,7 +1307,7 @@ def build_term_sharded_packed(host: PostingsHost, n_shards: int
         tc_a[s, :nb] = np.asarray(ix.tile_count)
     return PackedTermShardedIndex(
         sorted_hash=sh_a, df=df_a, block_offsets=offs_a, packed=pk,
-        block_tfs=bt, block_bits=bits_a, block_base=base_a,
+        tf_pairs=tp, block_bits=bits_a, block_base=base_a,
         block_count=cnt_a, tile_first=tf_a, tile_count=tc_a,
         norm=host.norm.astype(np.float32), n_shards=S,
         num_docs=host.num_docs, tile=layouts.ROUTE_TILE, block=block,
@@ -1342,8 +1335,8 @@ class BandedTermShardedIndex:
     sorted_hash: np.ndarray        # u32[S, Wmax]  (padded with 0xFFFFFFFF)
     df: np.ndarray                 # i32[S, Wmax]  global df (whole terms)
     block_offsets: np.ndarray      # i32[S, Wmax+1]   packed band
-    packed: np.ndarray             # u32[S, NBmax, WPB]
-    block_tfs: np.ndarray          # f16[S, NBmax, BLOCK]
+    packed: np.ndarray             # u32[S, NBmax, lanes]
+    tf_pairs: np.ndarray           # u32[S, ceil(NBmax/2), BLOCK]
     block_bits: np.ndarray         # i32[S, NBmax]  (1 on padding blocks)
     block_base: np.ndarray         # i32[S, NBmax]
     block_count: np.ndarray        # i32[S, NBmax]  (0 on padding blocks)
@@ -1388,8 +1381,8 @@ def build_term_sharded_banded(host: PostingsHost, n_shards: int
     sh_a = np.full((S, wmax), 0xFFFFFFFF, np.uint32)
     df_a = np.zeros((S, wmax), np.int32)
     offs_a = np.zeros((S, wmax + 1), np.int32)
-    pk = np.zeros((S, nbmax, wpb), np.uint32)
-    bt = np.zeros((S, nbmax, block), np.float16)
+    pk = np.zeros((S, nbmax, layouts.lane_width(wpb)), np.uint32)
+    tp = np.zeros((S, -(-nbmax // 2), block), np.uint32)
     bits_a = np.ones((S, nbmax), np.int32)     # padding blocks decode inert
     base_a = np.zeros((S, nbmax), np.int32)
     cnt_a = np.zeros((S, nbmax), np.int32)
@@ -1409,8 +1402,8 @@ def build_term_sharded_banded(host: PostingsHost, n_shards: int
         df_a[s, :w] = np.asarray(ix.df)
         offs_a[s, :w + 1] = np.asarray(p.block_offsets)
         offs_a[s, w + 1:] = offs_a[s, w]
-        pk[s, :nb, :p.words_per_block] = np.asarray(p.packed)
-        bt[s, :nb] = np.asarray(p.block_tfs)
+        pk[s, :nb, :p.packed.shape[1]] = np.asarray(p.packed)
+        tp[s, :p.tf_pairs.shape[0]] = np.asarray(p.tf_pairs)
         bits_a[s, :nb] = np.asarray(p.block_bits)
         base_a[s, :nb] = np.asarray(p.block_base)
         cnt_a[s, :nb] = np.asarray(p.block_count)
@@ -1424,7 +1417,7 @@ def build_term_sharded_banded(host: PostingsHost, n_shards: int
         h_tc_a[s, :hnb] = np.asarray(h.tile_count)
     return BandedTermShardedIndex(
         sorted_hash=sh_a, df=df_a, block_offsets=offs_a, packed=pk,
-        block_tfs=bt, block_bits=bits_a, block_base=base_a,
+        tf_pairs=tp, block_bits=bits_a, block_base=base_a,
         block_count=cnt_a, tile_first=tf_a, tile_count=tc_a,
         hor_block_offsets=h_offs_a, hor_block_docs=h_bd,
         hor_block_tfs=h_bt, hor_tile_first=h_tf_a, hor_tile_count=h_tc_a,
@@ -1528,11 +1521,11 @@ def make_term_sharded_fused_scorer(
     names = ("sorted_hash", "df", "block_offsets", "tile_first",
              "tile_count")
     if banded_layout:
-        names += ("packed", "block_tfs", "block_bits", "block_base",
+        names += ("packed", "tf_pairs", "block_bits", "block_base",
                   "block_count", "hor_block_offsets", "hor_block_docs",
                   "hor_block_tfs", "hor_tile_first", "hor_tile_count")
     elif packed_layout:
-        names += ("packed", "block_tfs", "block_bits", "block_base",
+        names += ("packed", "tf_pairs", "block_bits", "block_base",
                   "block_count")
     else:
         names += ("block_docs", "block_tfs")
@@ -1540,7 +1533,7 @@ def make_term_sharded_fused_scorer(
     sharded["norm"] = P()
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(sharded, P()), out_specs=(P(), P(), P()),
         check_vma=False)
     def score(ix, qh):
@@ -1574,7 +1567,7 @@ def make_term_sharded_fused_scorer(
         pqw = jnp.pad(pqw, ((0, 0), (0, q_pad - 1)))
         if packed_layout or banded_layout:
             partial = fused_score_packed_pallas(
-                sq["packed"], sq["block_tfs"], pb, pt, pqw, pcap,
+                sq["packed"], sq["tf_pairs"], pb, pt, pqw, pcap,
                 sq["block_bits"][pb], sq["block_base"][pb],
                 sq["block_count"][pb], num_docs, block, tile)[0]
         else:

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.shmap import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -150,7 +149,7 @@ def sharded_topk(mesh: Mesh, axis: str, scores_spec: P = None):
 
     def make(k: int):
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=(spec,),
+            jax.shard_map, mesh=mesh, in_specs=(spec,),
             out_specs=(P(), P()), check_vma=False)
         def fn(scores):
             local = scores.reshape(-1)

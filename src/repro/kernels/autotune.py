@@ -2,11 +2,10 @@
 
 The fused kernels historically baked in one geometry — ``TILE = 512``
 doc-tile width, ``Q_PAD = 8`` query quantum, ``K_PAD = 8`` candidate
-quantum, one routing pair per grid step, successive-maxima tile
-reduction.  Those constants are good defaults for a TPU MXU but have no
-reason to be optimal for every (backend, index size, layout) triple —
-interpret-mode CPU runs in particular pay per-grid-step Python
-overhead, so fewer/wider steps win there, and the bitonic tile reducer
+quantum, successive-maxima tile reduction.  Those constants are good
+defaults for a TPU MXU but have no reason to be optimal for every
+(backend, index size, layout) triple — the tile width trades pair count
+against per-tile work, and the bitonic tile reducer
 beats ``k_tile`` successive-maxima passes once ``k_tile`` outgrows the
 fixed ``log2(tile)*(log2(tile)+1)/2`` stage count of a full sort.
 
@@ -61,7 +60,6 @@ class TuneConfig:
     k_pad: int = _K_PAD_DEFAULT
     k_tile: int | None = None
     reducer: str = "successive"
-    pairs_per_step: int = 1
 
     def resolve_k_tile(self, k: int) -> int:
         from repro.kernels.fused_decode_score import default_k_tile
@@ -86,6 +84,8 @@ class TuneConfig:
 
     @classmethod
     def from_dict(cls, d: dict) -> "TuneConfig":
+        """Unknown keys are dropped, so tables stored with a retired
+        field (``pairs_per_step``) still load."""
         fields = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: v for k, v in d.items() if k in fields})
 
@@ -258,13 +258,12 @@ def lookup(backend: str, num_docs: int, layout: str) -> TuneConfig:
 def candidate_configs(k: int, tile_default: int = _TILE_DEFAULT,
                       tiles: Iterable[int] = (256, 512, 1024),
                       reducers: Iterable[str] = ("successive", "bitonic"),
-                      pairs: Iterable[int] = (1, 2),
                       include_wide_k: bool = True) -> list[TuneConfig]:
     """The pruned sweep grid: geometry axes that can plausibly matter,
-    not the full cross product.  Reducer and pairs-per-step only vary at
-    the default tile (they are independent of tile width to first
-    order); tile varies with everything else at defaults; ``k_tile``
-    widening is tried once (2x the floor) at the default tile."""
+    not the full cross product.  The reducer only varies at the default
+    tile (it is independent of tile width to first order); tile varies
+    with everything else at defaults; ``k_tile`` widening is tried once
+    (2x the floor) at the default tile."""
     from repro.kernels.fused_decode_score import default_k_tile
     out: list[TuneConfig] = [TuneConfig()]
     for t in tiles:
@@ -273,19 +272,12 @@ def candidate_configs(k: int, tile_default: int = _TILE_DEFAULT,
     for r in reducers:
         if r != "successive":
             out.append(TuneConfig(reducer=r))
-    for p in pairs:
-        if p != 1:
-            out.append(TuneConfig(pairs_per_step=p))
     if include_wide_k:
         floor = default_k_tile(k, tile_default, _K_PAD_DEFAULT)
         wide = min(2 * floor, tile_default)
         if wide > floor:
             out.append(TuneConfig(k_tile=wide))
             out.append(TuneConfig(k_tile=wide, reducer="bitonic"))
-    # combine the two grid-step amortizations (wider tile, multi-pair)
-    big = max(tiles)
-    if big != tile_default:
-        out.append(TuneConfig(tile=big, pairs_per_step=max(pairs)))
     return out
 
 
@@ -302,20 +294,23 @@ def time_config(index, query_hashes, idf_w, k: int, cap: int,
     under ``cfg`` (jit-compiled; warmup excluded)."""
     import jax
 
+    from repro.core.live_index import query_norms
     from repro.kernels import ops
 
     k_tile = cfg.resolve_k_tile(k)
-    # same widened budget as the query paths — a pps > 1 candidate must
-    # be timed doing the FULL pair set, not a silently truncated one
-    max_pairs = ops.padded_pairs_budget(index, cfg.tile,
-                                        cfg.pairs_per_step)
+    qnorm = jax.numpy.asarray(query_norms(idf_w))
+    # the query paths' budget: every candidate is timed doing the FULL
+    # pair set, not a silently truncated one
+    max_pairs = ops.default_max_pairs(index, *query_hashes.shape, cap,
+                                      cfg.tile)
 
     def run():
         vals, ids, _ = ops.fused_segment_topk(
-            index, query_hashes, idf_w, jax.numpy.int32(0), k_tile=k_tile,
-            cap=cap, max_pairs=max_pairs, rank_blend=rank_blend,
+            index, query_hashes, idf_w, qnorm, jax.numpy.int32(0),
+            k_tile=k_tile, cap=cap, max_pairs=max_pairs,
+            rank_blend=rank_blend,
             tile=cfg.tile, backend=backend, q_pad=cfg.q_pad,
-            reducer=cfg.reducer, pairs_per_step=cfg.pairs_per_step)
+            reducer=cfg.reducer)
         jax.block_until_ready((vals, ids))
 
     for _ in range(max(warmup, 1)):

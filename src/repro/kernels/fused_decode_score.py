@@ -4,34 +4,35 @@ candidate path) straight to per-tile top-k candidates.
 
 The paper's §4.3 claim is that query cost is dominated by posting-list
 I/O, so the compressed layout must NOT be decompressed through HBM
-before scoring.  This kernel closes that gap: its grid walks
-scalar-prefetched routing pairs ``(block, tile)`` and, per step,
+before scoring.  One kernel invocation walks the tile-sorted routing
+pairs ``(block, tile)`` and, per pair,
 
-  1. DMAs ONE posting block into VMEM — either raw int32 doc ids
+  1. DMAs ONE posting row into VMEM — either raw int32 doc ids
      (HOR/BlockedIndex) or delta+bit-packed u32 words (PackedCsrIndex);
-  2. for packed blocks, unpacks IN VMEM (per-lane variable shifts +
-     intra-block prefix sum — the ``packed_postings`` kernel body folded
-     into the scorer), so compressed bytes are the only posting bytes
-     that ever cross HBM;
-  3. one-hot-matmuls the block's tfs against a ``tile``-wide doc tile on
-     the MXU and rank-1 updates a ``[Q, tile]`` accumulator with the
-     per-query term weights — a hot block is read ONCE and serves every
-     query in the batch that touches it.
+  2. for packed blocks, unpacks IN VMEM (per-lane variable shifts over
+     a lane gather of the word row + a log-step lane prefix sum);
+  3. scatters ``qw * tf`` into a ``tile``-wide doc tile with a one-hot
+     matmul on the MXU and adds it to a ``[Q, tile]`` accumulator — a
+     hot block is read ONCE and serves every query in the batch that
+     touches it.
 
 Routing pairs are deduplicated across the query batch (two queries
 sharing a term share the block read) and sorted by tile so each output
-tile stays resident in VMEM for one contiguous run of grid steps
-(revisit-accumulation, as in ``posting_score``).  The block -> tile span
-table is a build-time cache on the index (``tile_first``/``tile_count``),
-not a per-query computation.
+tile stays resident in VMEM for one contiguous run of pairs.  The
+block -> tile span table is a build-time cache on the index
+(``tile_first``/``tile_count``), not a per-query computation.  The
+kernels DMA the rows the layouts store: 128-lane word rows and u32
+tf-pair rows for packed blocks (Mosaic DMAs neither a single 16-bit row
+nor a row narrower than 128 lanes, and loads no f16 on v5e), so no call
+copies the index.
 
 CANDIDATE EXTRACTION (the ``fused_topk_*`` variants): the dense engine
 still wrote a ``[Q, num_docs]`` score array to HBM before ``top_k`` —
 at corpus scale that write dwarfs the compressed posting bytes the read
 path saved.  The candidate kernels keep the ``[Q, tile]`` accumulator in
-VMEM SCRATCH instead of an output block; on a tile's LAST grid step
-(tile-sorted pairs make the run contiguous, so "last" is a prefetched
-flag) the accumulator is reduced IN VMEM to a per-tile candidate set:
+VMEM scratch; when the walk leaves a tile (tile-sorted pairs make its
+run contiguous) the accumulator is reduced IN VMEM to a per-tile
+candidate set:
 
   * the doc-metadata tail (norm division, deleted-doc mask, static-rank
     blend — bit-identical op sequence to the jnp oracle's scoring tail)
@@ -48,9 +49,10 @@ guarantees no global top-k entry is lost.
 
 HBM bytes per batch ~ sum over unique (block, tile) pairs of the block's
 payload: ``4*ceil(128*bits/32) + 2*128`` bytes packed vs ``8*128`` bytes
-unpacked, plus ``Q * n_tiles * k_tile * 8`` candidate bytes out (vs
-``Q * num_docs * 4`` dense) — the roofline benchmark reports both
-ratios.
+unpacked (the layouts' accounting; the DMAs move whole rows, ``4*128``
+bytes of words plus a ``4*128``-byte tf-pair row per packed pair), plus
+``Q * n_tiles * k_tile * 8`` candidate bytes out (vs ``Q * num_docs *
+4`` dense) — the roofline benchmark reports both ratios.
 """
 from __future__ import annotations
 
@@ -61,7 +63,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.query import final_scores
+from repro.core.query import scoring_tail
 from repro.kernels.runtime import resolve_interpret
 
 Array = jax.Array
@@ -96,54 +98,114 @@ def _check_k_tile(k_tile: int, tile: int) -> None:
         raise ValueError(f"k_tile must be >= 1, got {k_tile}")
 
 
-def _tile_contribution(docs, tfs, qw, tile_base, lane_cap, tile: int):
-    """Shared scoring step: one-hot matmul + rank-1 batch update.
+def _scan_lanes(x):
+    """Inclusive prefix sum along the lanes of an int32 ``[1, n]`` row.
 
-    ``lane_cap`` truncates the block at posting granularity so the
-    engine honours a per-term ``cap`` that cuts mid-block, exactly like
-    the jnp oracle's gather.  Returns the [Q, tile] contribution.
-    """
-    block = docs.shape[0]
-    lane0 = jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
+    Mosaic has no ``cumsum``; a log-step scan of ``pltpu.roll`` + lane
+    masks does the same integer additions in a different association,
+    which integer arithmetic makes bit-identical to ``jnp.cumsum``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    s = 1
+    while s < x.shape[1]:
+        x = x + jnp.where(lane >= s, pltpu.roll(x, s, 1), 0)
+        s *= 2
+    return x
+
+
+def _take_lanes(x, idx):
+    """``x[0, idx[0, l]]`` for every lane ``l`` of two int32 ``[1, n]``
+    rows.  Mosaic lowers only vreg-shaped 2-D lane gathers, so both rows
+    are broadcast to 8 sublanes and one is kept."""
+    shape = (8, x.shape[1])
+    return jnp.take_along_axis(
+        jnp.broadcast_to(x, shape), jnp.broadcast_to(idx, shape), axis=1,
+        mode="promise_in_bounds")[:1]
+
+
+def _unpack_block_row(words, bits, base, count, block: int):
+    """In-VMEM decode of one delta+bit-packed block.
+
+    ``words`` is the block's u32 word row, lane-padded to a multiple of
+    128 (``>= block``); ``bits``/``base``/``count`` are the block's
+    decode scalars.  Returns the i32 ``[1, block]`` doc-id row (-1 past
+    ``count``)."""
+    width = words.shape[1]
+    w = jax.lax.bitcast_convert_type(words, jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    bitpos = lane * bits
+    wi = jnp.minimum(bitpos >> 5, width - 1)
+    off = (bitpos & 31).astype(jnp.uint32)
+    lo = jax.lax.bitcast_convert_type(_take_lanes(w, wi), jnp.uint32) >> off
+    nxt = jax.lax.bitcast_convert_type(
+        _take_lanes(w, jnp.minimum(wi + 1, width - 1)), jnp.uint32)
+    hi = jnp.where(off > 0, nxt << (jnp.uint32(32) - off), jnp.uint32(0))
+    mask = jnp.where(bits >= 32, jnp.uint32(0xFFFFFFFF),
+                     (jnp.uint32(1) << bits.astype(jnp.uint32))
+                     - jnp.uint32(1))
+    deltas = jax.lax.bitcast_convert_type((lo | hi) & mask, jnp.int32)
+    docs = base + _scan_lanes(deltas)
+    return jnp.where(lane < count, docs, -1)[:, :block]
+
+
+def _f16_bits_to_f32(h):
+    """Exact f16 -> f32 of the 16-bit patterns held in int32 ``h``,
+    with integer ops and bitcasts only (Mosaic loads no f16 on v5e):
+    normals rebias the exponent, subnormals are ``m * 2**-24``, and
+    inf/nan keep their mantissa."""
+    sign = (h >> 15) << 31
+    e = (h >> 10) & 31
+    m = h & 1023
+    normal = jax.lax.bitcast_convert_type(
+        sign | ((e + 112) << 23) | (m << 13), jnp.float32)
+    special = jax.lax.bitcast_convert_type(
+        sign | (255 << 23) | (m << 13), jnp.float32)
+    sub = m.astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    sub = jnp.where(sign != 0, -sub, sub)
+    return jnp.where(e == 0, sub, jnp.where(e == 31, special, normal))
+
+
+def _unpair_tf_row(pairs, half):
+    """f32 tfs of one packed block from its u32 tf-pair row
+    (``layouts.pair_tf_rows``): the f16 bits in half ``half`` (0 low,
+    1 high) of every lane."""
+    w = jax.lax.bitcast_convert_type(pairs, jnp.int32)
+    return _f16_bits_to_f32((w >> (16 * half)) & 0xFFFF)
+
+
+def _tile_contribution(docs, tfs, qw, tile_base, lane_cap, tile: int):
+    """Shared scoring step: ``[Q, tile]`` contribution of one block.
+
+    ``docs``/``tfs`` are ``[1, B]`` rows, ``qw`` the ``[Q, 1]`` query
+    weight column.  Each posting's ``qw * tf`` is one f32 product (as in
+    the oracle), and the one-hot ``[Q, B] x [tile, B]^T`` MXU product
+    only places it: postings of one block are distinct docs, so every
+    output lane sums one product and zeros, exactly, at HIGHEST
+    precision.  ``lane_cap`` truncates the block at posting granularity
+    so a per-term ``cap`` that cuts mid-block matches the oracle's
+    gather."""
+    block = docs.shape[1]
+    lane0 = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     local = docs - tile_base
     inb = (docs >= 0) & (local >= 0) & (local < tile) & (lane0 < lane_cap)
-    w = jnp.where(inb, tfs, 0.0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (docs.shape[0], tile), 1)
-    onehot = (local[:, None] == lane).astype(jnp.float32)     # [B, tile]
-    row = jnp.dot(w[None, :], onehot,
-                  preferred_element_type=jnp.float32)         # [1, tile] MXU
-    return jnp.dot(qw[:, None], row,
-                   preferred_element_type=jnp.float32)        # [Q, tile]
-
-
-def _unpack_block_vmem(words, bits, base, count, block: int):
-    """In-VMEM decode of one delta+bit-packed block (the
-    ``packed_postings`` kernel body, shared by both packed kernels)."""
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (block,), 0)
-    bitpos = lane * bits
-    wi = (bitpos >> 5).astype(jnp.int32)
-    off = bitpos & jnp.uint32(31)
-    lo = words[wi] >> off
-    hi = jnp.where(off > 0,
-                   words[jnp.minimum(wi + 1, words.shape[0] - 1)]
-                   << (jnp.uint32(32) - off), jnp.uint32(0))
-    raw = lo | hi
-    mask = jnp.where(bits >= 32, jnp.uint32(0xFFFFFFFF),
-                     (jnp.uint32(1) << bits) - jnp.uint32(1))
-    deltas = (raw & mask).astype(jnp.int32)
-    docs = base + jnp.cumsum(deltas)
-    valid = jax.lax.broadcasted_iota(jnp.int32, (block,), 0) < count
-    return jnp.where(valid, docs, -1)
+    w = qw * jnp.where(inb, tfs, 0.0)                            # [Q, B]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (tile, block), 0)
+    onehot = (slot == local).astype(jnp.float32)                 # [tile, B]
+    return jax.lax.dot_general(
+        w, onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                      # [Q, tile]
 
 
 def _final_from_acc(acc, norm, rank, qnorm, rank_blend: float):
-    """The oracle's q_doc scoring tail, applied to one resident tile.
+    """The oracle's q_doc scoring tail, applied to one resident tile
+    (``norm``/``rank`` ``[1, tile]`` rows, ``qnorm`` a ``[Q, 1]``
+    column).
 
-    Delegates to the ONE shared definition (``core.query.final_scores``)
+    Delegates to the ONE shared definition (``core.query.scoring_tail``)
     so candidate values stay bit-identical to the dense reference — any
     change to the tail changes both sides at once.
     """
-    return final_scores(acc, norm, rank, qnorm, rank_blend)
+    return scoring_tail(acc, norm, rank, qnorm, rank_blend)
 
 
 def _tile_topk(final, base, k_tile: int, tile: int):
@@ -159,13 +221,14 @@ def _tile_topk(final, base, k_tile: int, tile: int):
 
     def body(j, carry):
         work, vals, ids = carry
-        m = jnp.max(work, axis=1)                              # [Q]
-        am = jnp.min(jnp.where(work == m[:, None], lane, tile), axis=1)
-        gid = jnp.where(jnp.isfinite(m), base + am, -1)
+        m = jnp.max(work, axis=1, keepdims=True)                # [Q, 1]
+        am = jnp.min(jnp.where(work == m, lane, tile), axis=1,
+                     keepdims=True)
+        gid = jnp.where(m > -jnp.inf, base + am, -1)
         sel = kidx == j
-        vals = jnp.where(sel, m[:, None], vals)
-        ids = jnp.where(sel, gid[:, None], ids)
-        work = jnp.where(lane == am[:, None], -jnp.inf, work)
+        vals = jnp.where(sel, m, vals)
+        ids = jnp.where(sel, gid, ids)
+        work = jnp.where(lane == am, -jnp.inf, work)
         return work, vals, ids
 
     _, vals, ids = jax.lax.fori_loop(
@@ -247,53 +310,198 @@ def _tile_reduce(final, base, k_tile: int, tile: int, reducer: str):
 
 
 # ---------------------------------------------------------------------------
-# dense kernels (scores for every document; the PR-1 engine)
+# the pair walk: one kernel body behind all four entry points
 # ---------------------------------------------------------------------------
+#
+# A routing budget may be the whole-index bound (``scaled_pairs_budget``,
+# 2**27 pairs at a million-doc class) while SMEM holds 1 MiB.  So the
+# per-pair scalars stay in HBM and are staged into SMEM ``CHUNK`` pairs
+# at a time; posting rows are DMA'd by the staged block index into VMEM
+# (a one-row BlockSpec breaks Mosaic's (8, 128) block rule).  One
+# invocation walks the tile-sorted pairs in order: a tile change flushes
+# the previous tile (dense: the accumulator; candidates: scoring tail +
+# per-tile top-k) to its HBM row, so the op sequence per doc is the one
+# the interpreter always ran.  Only pairs up to the last real one are
+# walked; tiles never flushed stay garbage and are masked by
+# ``_finish*`` from ``pair_tile``.
+
+CHUNK = 1024   # pairs staged per SMEM fill (the 1-D HBM tiling quantum)
+LANES = 128
 
 
-def _fused_blocked_kernel(pair_block, pair_tile, pair_first,
-                          pair_cap,                            # SMEM prefetch
-                          docs_ref, tfs_ref, qw_ref,           # VMEM inputs
-                          out_ref, *, tile: int):
-    i = pl.program_id(0)
-
-    @pl.when(pair_first[i] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[0] += _tile_contribution(docs_ref[0, :], tfs_ref[0, :],
-                                     qw_ref[0, :], pair_tile[i] * tile,
-                                     pair_cap[i], tile)
+def _lane_pad(n: int) -> int:
+    return -(-int(n) // LANES) * LANES
 
 
-def _fused_packed_kernel(pair_block, pair_tile, pair_first, pair_cap,
-                         pair_bits, pair_base, pair_count,     # SMEM prefetch
-                         words_ref, tfs_ref, qw_ref,           # VMEM inputs
-                         out_ref, *, tile: int, block: int):
-    i = pl.program_id(0)
-
-    @pl.when(pair_first[i] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    docs = _unpack_block_vmem(words_ref[0, :],
-                              pair_bits[i].astype(jnp.uint32),
-                              pair_base[i], pair_count[i], block)
-    out_ref[0] += _tile_contribution(docs, tfs_ref[0, :].astype(jnp.float32),
-                                     qw_ref[0, :], pair_tile[i] * tile,
-                                     pair_cap[i], tile)
+def _n_walk(pair_tile: Array, pair_cap: Array, n_tiles: int) -> Array:
+    """Pairs to walk: through the last real pair.  Unused budget slots
+    are trash-tile pairs sorted to the end (and a pair with cap 0 reads
+    no lane); neither can change a result."""
+    idx = jnp.arange(pair_tile.shape[0], dtype=jnp.int32) + 1
+    real = (pair_tile < n_tiles) & (pair_cap > 0)
+    return jnp.max(jnp.where(real, idx, 0), initial=0).reshape(1)
 
 
-def _pair_first(pair_tile: Array) -> Array:
-    return jnp.concatenate(
-        [jnp.ones(1, jnp.int32),
-         (pair_tile[1:] != pair_tile[:-1]).astype(jnp.int32)])
+def _pad_pairs(x: Array, n: int) -> Array:
+    return jnp.pad(x.astype(jnp.int32), (0, n - x.shape[0]))
 
 
-def _pair_last(pair_tile: Array) -> Array:
-    return jnp.concatenate(
-        [(pair_tile[1:] != pair_tile[:-1]).astype(jnp.int32),
-         jnp.ones(1, jnp.int32)])
+def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
+                pair_tile: Array, pair_qw: Array, pair_cap: Array,
+                decode, num_docs: int, tile: int, block: int,
+                interpret: bool, tail=None):
+    """Run the pair walk; returns the raw per-tile HBM output(s).
+
+    ``rows`` are the posting rows the kernel DMAs per pair: i32 doc ids
+    (HOR) or 128-lane u32 word rows (packed, with ``decode`` the
+    per-pair (bits, base, count)).  ``tfs`` is f32[NB, block] (HOR) or
+    the u32[ceil(NB/2), block] tf-pair rows (packed).  ``tail`` is None for
+    the dense engine (output f32[n_tiles+1, Q, tile]) or
+    ``(norm_t, rank_t, qnorm, k_tile, rank_blend, reducer)`` for the
+    candidate engine (outputs f32/i32[n_tiles+1, Q, lane_pad(k_tile)])."""
+    np_pairs, q = pair_qw.shape
+    n_tiles = max(-(-num_docs // tile), 1)
+    npc = max(-(-np_pairs // CHUNK), 1) * CHUNK
+    scalars = [pair_block, pair_tile, pair_cap] + list(decode or ())
+    scalars = [_pad_pairs(x, npc) for x in scalars]
+    qwt = jnp.pad(pair_qw.astype(jnp.float32).T,
+                  ((0, 0), (0, npc - np_pairs)))
+    n_scal = len(scalars)
+    packed = decode is not None
+    dense = tail is None
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    inputs = [*scalars, rows, tfs, qwt]
+    in_specs = [hbm] * len(inputs)
+    scratch = [pltpu.SMEM((CHUNK,), jnp.int32) for _ in scalars] + [
+        pltpu.VMEM((q, CHUNK), jnp.float32),
+        pltpu.VMEM((1, rows.shape[1]), rows.dtype),
+        pltpu.VMEM((1, block), tfs.dtype),
+        pltpu.VMEM((q, tile), jnp.float32)]
+    if dense:
+        out_shape = jax.ShapeDtypeStruct((n_tiles + 1, q, tile), jnp.float32)
+        n_out = 1
+    else:
+        norm_t, rank_t, qnorm, k_tile, rank_blend, reducer = tail
+        kp = _lane_pad(k_tile)
+        # [n_tiles + 1, 1, tile]: a tile's row is one slice of the
+        # untiled leading dim, whatever tiling the compiler picks
+        inputs += [norm_t[:, None, :], rank_t[:, None, :],
+                   qnorm.reshape(q, 1).astype(jnp.float32)]
+        in_specs += [hbm, hbm, pl.BlockSpec((q, 1), lambda i, n: (0, 0))]
+        out_shape = (
+            jax.ShapeDtypeStruct((n_tiles + 1, q, kp), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles + 1, q, kp), jnp.int32))
+        n_out = 2
+        scratch += [pltpu.VMEM((1, tile), jnp.float32),
+                    pltpu.VMEM((1, tile), jnp.float32),
+                    pltpu.VMEM((q, kp), jnp.float32),
+                    pltpu.VMEM((q, kp), jnp.int32)]
+    scratch.append(pltpu.SemaphoreType.DMA((1,)))
+
+    def kernel(n_ref, *refs):
+        s_hbm = refs[:n_scal]
+        rows_hbm, tfs_hbm, qw_hbm = refs[n_scal:n_scal + 3]
+        r = n_scal + 3
+        if not dense:
+            norm_hbm, rank_hbm, qn_ref = refs[r:r + 3]
+            r += 3
+        outs = refs[r:r + n_out]
+        r += n_out
+        s_sm = refs[r:r + n_scal]
+        qw_v, row_v, tf_v, acc = refs[r + n_scal:r + n_scal + 4]
+        extra = refs[r + n_scal + 4:-1]
+        sem = refs[-1]
+
+        def copy(src, dst):
+            cp = pltpu.make_async_copy(src, dst, sem.at[0])
+            cp.start()
+            cp.wait()
+
+        def flush(t):
+            if dense:
+                copy(acc, outs[0].at[t])
+                return
+            norm_v, rank_v, val_v, id_v = extra
+            copy(norm_hbm.at[t], norm_v)
+            copy(rank_hbm.at[t], rank_v)
+            final = _final_from_acc(acc[...], norm_v[...], rank_v[...],
+                                    qn_ref[...], rank_blend)
+            vals, ids = _tile_reduce(final, t * tile, k_tile, tile, reducer)
+            val_v[...] = jnp.full((q, kp), -jnp.inf, jnp.float32)
+            id_v[...] = jnp.full((q, kp), -1, jnp.int32)
+            val_v[:, :k_tile] = vals
+            id_v[:, :k_tile] = ids
+            copy(val_v, outs[0].at[t])
+            copy(id_v, outs[1].at[t])
+
+        def pair(j, cur):
+            t = s_sm[1][j]
+            b = s_sm[0][j]
+
+            @pl.when(t != cur)
+            def _new_tile():
+                @pl.when(cur >= 0)
+                def _():
+                    flush(cur)
+                acc[...] = jnp.zeros_like(acc)
+
+            copy(rows_hbm.at[pl.ds(b, 1)], row_v)
+            if packed:
+                copy(tfs_hbm.at[pl.ds(b >> 1, 1)], tf_v)
+                tf = _unpair_tf_row(tf_v[...], b & 1)
+                docs = _unpack_block_row(row_v[...], s_sm[3][j], s_sm[4][j],
+                                         s_sm[5][j], block)
+            else:
+                copy(tfs_hbm.at[pl.ds(b, 1)], tf_v)
+                tf = tf_v[...]
+                docs = row_v[...]
+            g = pl.multiple_of((j // LANES) * LANES, LANES)
+            qwg = qw_v[:, pl.ds(g, LANES)]
+            lane = jax.lax.broadcasted_iota(jnp.int32, qwg.shape, 1)
+            qcol = jnp.sum(jnp.where(lane == j % LANES, qwg, 0.0), axis=1,
+                           keepdims=True)                           # [Q, 1]
+            acc[...] += _tile_contribution(docs, tf, qcol, t * tile,
+                                           s_sm[2][j], tile)
+            return t
+
+        n = n_ref[0]
+
+        def chunk(c, cur):
+            base = pl.multiple_of(c * CHUNK, CHUNK)
+            for src, dst in zip(s_hbm, s_sm):
+                copy(src.at[pl.ds(base, CHUNK)], dst)
+            copy(qw_hbm.at[:, pl.ds(base, CHUNK)], qw_v)
+            return jax.lax.fori_loop(0, jnp.minimum(CHUNK, n - base), pair,
+                                     cur)
+
+        cur = jax.lax.fori_loop(0, (n + CHUNK - 1) // CHUNK, chunk,
+                                jnp.int32(-1))
+
+        @pl.when(cur >= 0)
+        def _last():
+            flush(cur)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(1,), in_specs=in_specs,
+        out_specs=(hbm if dense else [hbm, hbm]),
+        scratch_shapes=scratch)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret,
+    )(_n_walk(pair_tile, pair_cap, n_tiles), *inputs)
+
+
+def _check_packed_rows(packed: Array, tf_pairs: Array, block: int) -> None:
+    """The packed kernels DMA the layout's stored rows as they are."""
+    if packed.shape[1] % LANES or packed.shape[1] < block:
+        raise ValueError(
+            f"packed rows are {packed.shape[1]} lanes wide; the kernel "
+            f"DMAs whole rows of a multiple of {LANES} lanes >= block "
+            f"({block}): build with layouts.build_packed_csr")
+    if (tf_pairs.dtype != jnp.uint32
+            or 2 * tf_pairs.shape[0] < packed.shape[0]):
+        raise ValueError("tfs must be the u32 pair rows of "
+                         "layouts.pair_tf_rows (one row per two blocks)")
 
 
 def _finish(out: Array, pair_tile: Array, n_tiles: int, tile: int,
@@ -315,31 +523,15 @@ def fused_score_blocked_pallas(block_docs: Array, block_tfs: Array,
     pair_* [NP] tile-sorted routing, pair_qw f32[NP, Q] per-query weight
     rows (Q padded to a multiple of 8), pair_cap i32[NP] per-pair valid
     lane count (posting-granular cap).  Returns f32[Q, num_docs]."""
-    nb, b = block_docs.shape
-    np_pairs, q = pair_qw.shape
-    n_tiles = -(-num_docs // tile)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(np_pairs,),
-        in_specs=[
-            pl.BlockSpec((1, b), lambda i, pb, pt, pf, pc: (pb[i], 0)),
-            pl.BlockSpec((1, b), lambda i, pb, pt, pf, pc: (pb[i], 0)),
-            pl.BlockSpec((1, q), lambda i, pb, pt, pf, pc: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, q, tile),
-                               lambda i, pb, pt, pf, pc: (pt[i], 0, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_fused_blocked_kernel, tile=tile),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles + 1, q, tile), jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(pair_block, pair_tile, _pair_first(pair_tile), pair_cap,
-      block_docs, block_tfs, pair_qw)
+    n_tiles = max(-(-num_docs // tile), 1)
+    out = _walk_pairs(block_docs.astype(jnp.int32),
+                      block_tfs.astype(jnp.float32), pair_block, pair_tile,
+                      pair_qw, pair_cap, None, num_docs, tile,
+                      block_docs.shape[1], resolve_interpret(interpret))
     return _finish(out, pair_tile, n_tiles, tile, num_docs)
 
 
-def fused_score_packed_pallas(packed: Array, block_tfs: Array,
+def fused_score_packed_pallas(packed: Array, tf_pairs: Array,
                               pair_block: Array, pair_tile: Array,
                               pair_qw: Array, pair_cap: Array,
                               pair_bits: Array, pair_base: Array,
@@ -347,121 +539,19 @@ def fused_score_packed_pallas(packed: Array, block_tfs: Array,
                               num_docs: int, block: int,
                               tile: int = TILE,
                               interpret: bool | None = None) -> Array:
-    """Packed path: packed u32[NB, Wpb] words + f16 tfs stay compressed in
-    HBM; decode happens inside the scoring step.  Same routing contract
-    as the HOR path plus per-pair (bits, base, count) decode scalars.
-    The term-sharded packed engine runs this kernel per vocab shard
-    (partial scores over the GLOBAL doc space, ahead of the [D] psum)."""
-    nb, wpb = packed.shape
-    np_pairs, q = pair_qw.shape
-    n_tiles = -(-num_docs // tile)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(np_pairs,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, wpb),
-                lambda i, pb, pt, pf, pc, pbt, pba, pcnt: (pb[i], 0)),
-            pl.BlockSpec(
-                (1, block),
-                lambda i, pb, pt, pf, pc, pbt, pba, pcnt: (pb[i], 0)),
-            pl.BlockSpec(
-                (1, q),
-                lambda i, pb, pt, pf, pc, pbt, pba, pcnt: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, q, tile),
-            lambda i, pb, pt, pf, pc, pbt, pba, pcnt: (pt[i], 0, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_fused_packed_kernel, tile=tile, block=block),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles + 1, q, tile), jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(pair_block, pair_tile, _pair_first(pair_tile), pair_cap,
-      pair_bits, pair_base, pair_count, packed, block_tfs, pair_qw)
+    """Packed path: packed u32[NB, lanes] word rows stay compressed;
+    decode happens inside the scoring step.  ``tf_pairs`` holds the f16
+    tfs two blocks per u32 row (``layouts.pair_tf_rows``).  Same routing
+    contract as the HOR path plus per-pair (bits, base, count) decode
+    scalars.  The term-sharded packed engine runs this kernel per vocab
+    shard (partial scores over the GLOBAL doc space, ahead of the [D]
+    psum)."""
+    _check_packed_rows(packed, tf_pairs, block)
+    n_tiles = max(-(-num_docs // tile), 1)
+    out = _walk_pairs(packed, tf_pairs, pair_block, pair_tile,
+                      pair_qw, pair_cap, (pair_bits, pair_base, pair_count),
+                      num_docs, tile, block, resolve_interpret(interpret))
     return _finish(out, pair_tile, n_tiles, tile, num_docs)
-
-
-# ---------------------------------------------------------------------------
-# candidate-extraction kernels (per-tile partial top-k; the dense score
-# write never reaches HBM)
-# ---------------------------------------------------------------------------
-
-
-def _fused_blocked_topk_kernel(pair_block, pair_tile, pair_first, pair_last,
-                               pair_cap,                       # SMEM prefetch
-                               *refs,
-                               tile: int, k_tile: int, rank_blend: float,
-                               reducer: str, pps: int):
-    """``pps`` (pairs-per-grid-step) sub-pairs are unrolled inside one
-    grid step: ``refs`` carries ``pps`` replicated (docs, tfs, qw) VMEM
-    views (one per sub-pair, each with its own ``pb[i*pps+j]`` index
-    map) followed by the shared (norm, rank, qnorm) tiles, the two
-    candidate outputs, and the accumulator scratch.  Run-aligned pair
-    padding (``build_batched_pairs``) guarantees a tile transition only
-    ever happens at a step boundary, so init stays at sub-pair 0 and
-    the reduce at sub-pair pps-1."""
-    i = pl.program_id(0)
-    docs_refs = refs[:pps]
-    tfs_refs = refs[pps:2 * pps]
-    qw_refs = refs[2 * pps:3 * pps]
-    (norm_ref, rank_ref, qn_ref, val_ref, idx_ref, acc_ref) = refs[3 * pps:]
-    base = i * pps
-
-    @pl.when(pair_first[base] == 1)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    for j in range(pps):
-        acc_ref[...] += _tile_contribution(
-            docs_refs[j][0, :], tfs_refs[j][0, :], qw_refs[j][0, :],
-            pair_tile[base + j] * tile, pair_cap[base + j], tile)
-
-    @pl.when(pair_last[base + pps - 1] == 1)
-    def _reduce():
-        final = _final_from_acc(acc_ref[...], norm_ref[0, :], rank_ref[0, :],
-                                qn_ref[0, :], rank_blend)
-        vals, ids = _tile_reduce(final, pair_tile[base] * tile, k_tile, tile,
-                                 reducer)
-        val_ref[0] = vals
-        idx_ref[0] = ids
-
-
-def _fused_packed_topk_kernel(pair_block, pair_tile, pair_first, pair_last,
-                              pair_cap, pair_bits, pair_base,
-                              pair_count,                      # SMEM prefetch
-                              *refs,
-                              tile: int, block: int, k_tile: int,
-                              rank_blend: float, reducer: str, pps: int):
-    i = pl.program_id(0)
-    words_refs = refs[:pps]
-    tfs_refs = refs[pps:2 * pps]
-    qw_refs = refs[2 * pps:3 * pps]
-    (norm_ref, rank_ref, qn_ref, val_ref, idx_ref, acc_ref) = refs[3 * pps:]
-    base = i * pps
-
-    @pl.when(pair_first[base] == 1)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    for j in range(pps):
-        docs = _unpack_block_vmem(words_refs[j][0, :],
-                                  pair_bits[base + j].astype(jnp.uint32),
-                                  pair_base[base + j], pair_count[base + j],
-                                  block)
-        acc_ref[...] += _tile_contribution(
-            docs, tfs_refs[j][0, :].astype(jnp.float32), qw_refs[j][0, :],
-            pair_tile[base + j] * tile, pair_cap[base + j], tile)
-
-    @pl.when(pair_last[base + pps - 1] == 1)
-    def _reduce():
-        final = _final_from_acc(acc_ref[...], norm_ref[0, :], rank_ref[0, :],
-                                qn_ref[0, :], rank_blend)
-        vals, ids = _tile_reduce(final, pair_tile[base] * tile, k_tile, tile,
-                                 reducer)
-        val_ref[0] = vals
-        idx_ref[0] = ids
 
 
 def _doc_tiles(norm: Array, rank: Array, n_tiles: int, tile: int):
@@ -479,8 +569,8 @@ def _finish_candidates(vals: Array, ids: Array, pair_tile: Array,
     """Mask never-visited (garbage) tiles to (-inf, -1), flatten the
     per-tile candidate lists tile-major to [Q, n_tiles * k_tile]."""
     visited = jnp.zeros((n_tiles + 1,), jnp.bool_).at[pair_tile].set(True)
-    vals = jnp.where(visited[:, None, None], vals, -jnp.inf)
-    ids = jnp.where(visited[:, None, None], ids, -1)
+    vals = jnp.where(visited[:, None, None], vals[..., :k_tile], -jnp.inf)
+    ids = jnp.where(visited[:, None, None], ids[..., :k_tile], -1)
     q = vals.shape[1]
     return (vals[:n_tiles].transpose(1, 0, 2).reshape(q, n_tiles * k_tile),
             ids[:n_tiles].transpose(1, 0, 2).reshape(q, n_tiles * k_tile))
@@ -501,13 +591,19 @@ def _check_reducer(reducer: str, interpret: bool) -> None:
             "runs (or force it with REPRO_REDUCER=successive)")
 
 
-def _check_pairs_per_step(np_pairs: int, pps: int) -> None:
-    if pps < 1:
-        raise ValueError(f"pairs_per_step must be >= 1, got {pps}")
-    if pps > 1 and np_pairs % pps:
-        raise ValueError(
-            f"np_pairs={np_pairs} not a multiple of pairs_per_step={pps}; "
-            "build pairs with build_batched_pairs(..., pairs_per_step=pps)")
+def _topk_walk(rows, tfs, pair_block, pair_tile, pair_qw, pair_cap, decode,
+               norm, rank, qnorm, num_docs, block, k_tile, rank_blend, tile,
+               reducer, interpret):
+    interp = resolve_interpret(interpret)
+    _check_k_tile(k_tile, tile)
+    _check_reducer(reducer, interp)
+    n_tiles = max(-(-num_docs // tile), 1)
+    norm_t, rank_t = _doc_tiles(norm, rank, n_tiles, tile)
+    vals, ids = _walk_pairs(
+        rows, tfs, pair_block, pair_tile, pair_qw,
+        pair_cap, decode, num_docs, tile, block, interp,
+        tail=(norm_t, rank_t, qnorm, k_tile, rank_blend, reducer))
+    return _finish_candidates(vals, ids, pair_tile, n_tiles, k_tile)
 
 
 def fused_topk_blocked_pallas(block_docs: Array, block_tfs: Array,
@@ -517,76 +613,21 @@ def fused_topk_blocked_pallas(block_docs: Array, block_tfs: Array,
                               num_docs: int, k_tile: int,
                               rank_blend: float = 0.0, tile: int = TILE,
                               reducer: str = "successive",
-                              pairs_per_step: int = 1,
                               interpret: bool | None = None):
     """HOR candidate path: same routing contract as the dense kernel,
     plus per-doc metadata (norm f32[num_docs], rank f32[num_docs]) and
     per-query norms (qnorm f32[Q], padding queries should carry 1.0).
     Returns (values f32[Q, n_tiles*k_tile], ids i32[Q, n_tiles*k_tile])
     tile-major candidate lists of FINAL scores — the dense [Q, num_docs]
-    array never leaves VMEM.
-
-    ``pairs_per_step > 1`` amortizes grid-step overhead by processing
-    that many routing pairs per step; callers must build the pair
-    arrays with matching run-aligned padding
-    (``build_batched_pairs(..., pairs_per_step=...)``)."""
-    nb, b = block_docs.shape
-    np_pairs, q = pair_qw.shape
-    pps = pairs_per_step
-    interp = resolve_interpret(interpret)
-    _check_k_tile(k_tile, tile)
-    _check_pairs_per_step(np_pairs, pps)
-    _check_reducer(reducer, interp)
-    n_tiles = max(-(-num_docs // tile), 1)
-    norm_t, rank_t = _doc_tiles(norm, rank, n_tiles, tile)
-
-    def _block_spec(j):
-        return pl.BlockSpec(
-            (1, b), lambda i, pb, pt, pf, pg, pc, j=j: (pb[i * pps + j], 0))
-
-    def _qw_spec(j):
-        return pl.BlockSpec(
-            (1, q), lambda i, pb, pt, pf, pg, pc, j=j: (i * pps + j, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(np_pairs // pps,),
-        in_specs=(
-            [_block_spec(j) for j in range(pps)]
-            + [_block_spec(j) for j in range(pps)]
-            + [_qw_spec(j) for j in range(pps)]
-            + [
-                pl.BlockSpec((1, tile),
-                             lambda i, pb, pt, pf, pg, pc: (pt[i * pps], 0)),
-                pl.BlockSpec((1, tile),
-                             lambda i, pb, pt, pf, pg, pc: (pt[i * pps], 0)),
-                pl.BlockSpec((1, q), lambda i, pb, pt, pf, pg, pc: (0, 0)),
-            ]),
-        out_specs=[
-            pl.BlockSpec((1, q, k_tile),
-                         lambda i, pb, pt, pf, pg, pc: (pt[i * pps], 0, 0)),
-            pl.BlockSpec((1, q, k_tile),
-                         lambda i, pb, pt, pf, pg, pc: (pt[i * pps], 0, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((q, tile), jnp.float32)],
-    )
-    vals, ids = pl.pallas_call(
-        functools.partial(_fused_blocked_topk_kernel, tile=tile,
-                          k_tile=k_tile, rank_blend=rank_blend,
-                          reducer=reducer, pps=pps),
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((n_tiles + 1, q, k_tile), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles + 1, q, k_tile), jnp.int32)),
-        interpret=interp,
-    )(pair_block, pair_tile, _pair_first(pair_tile), _pair_last(pair_tile),
-      pair_cap,
-      *([block_docs] * pps), *([block_tfs] * pps), *([pair_qw] * pps),
-      norm_t, rank_t, qnorm.reshape(1, q))
-    return _finish_candidates(vals, ids, pair_tile, n_tiles, k_tile)
+    array never leaves VMEM."""
+    return _topk_walk(block_docs.astype(jnp.int32),
+                      block_tfs.astype(jnp.float32), pair_block,
+                      pair_tile, pair_qw, pair_cap, None, norm, rank, qnorm,
+                      num_docs, block_docs.shape[1], k_tile, rank_blend,
+                      tile, reducer, interpret)
 
 
-def fused_topk_packed_pallas(packed: Array, block_tfs: Array,
+def fused_topk_packed_pallas(packed: Array, tf_pairs: Array,
                              pair_block: Array, pair_tile: Array,
                              pair_qw: Array, pair_cap: Array,
                              pair_bits: Array, pair_base: Array,
@@ -595,84 +636,16 @@ def fused_topk_packed_pallas(packed: Array, block_tfs: Array,
                              num_docs: int, block: int, k_tile: int,
                              rank_blend: float = 0.0, tile: int = TILE,
                              reducer: str = "successive",
-                             pairs_per_step: int = 1,
                              interpret: bool | None = None):
     """Packed candidate path: in-VMEM decode + per-tile top-k; only
-    compressed posting bytes in, only candidates out."""
-    nb, wpb = packed.shape
-    np_pairs, q = pair_qw.shape
-    pps = pairs_per_step
-    interp = resolve_interpret(interpret)
-    _check_k_tile(k_tile, tile)
-    _check_pairs_per_step(np_pairs, pps)
-    _check_reducer(reducer, interp)
-    n_tiles = max(-(-num_docs // tile), 1)
-    norm_t, rank_t = _doc_tiles(norm, rank, n_tiles, tile)
-
-    def _words_spec(j):
-        return pl.BlockSpec(
-            (1, wpb),
-            lambda i, pb, pt, pf, pg, pc, pbt, pba, pcnt, j=j:
-                (pb[i * pps + j], 0))
-
-    def _tfs_spec(j):
-        return pl.BlockSpec(
-            (1, block),
-            lambda i, pb, pt, pf, pg, pc, pbt, pba, pcnt, j=j:
-                (pb[i * pps + j], 0))
-
-    def _qw_spec(j):
-        return pl.BlockSpec(
-            (1, q),
-            lambda i, pb, pt, pf, pg, pc, pbt, pba, pcnt, j=j:
-                (i * pps + j, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=8,
-        grid=(np_pairs // pps,),
-        in_specs=(
-            [_words_spec(j) for j in range(pps)]
-            + [_tfs_spec(j) for j in range(pps)]
-            + [_qw_spec(j) for j in range(pps)]
-            + [
-                pl.BlockSpec(
-                    (1, tile),
-                    lambda i, pb, pt, pf, pg, pc, pbt, pba, pcnt:
-                        (pt[i * pps], 0)),
-                pl.BlockSpec(
-                    (1, tile),
-                    lambda i, pb, pt, pf, pg, pc, pbt, pba, pcnt:
-                        (pt[i * pps], 0)),
-                pl.BlockSpec(
-                    (1, q),
-                    lambda i, pb, pt, pf, pg, pc, pbt, pba, pcnt: (0, 0)),
-            ]),
-        out_specs=[
-            pl.BlockSpec(
-                (1, q, k_tile),
-                lambda i, pb, pt, pf, pg, pc, pbt, pba, pcnt:
-                    (pt[i * pps], 0, 0)),
-            pl.BlockSpec(
-                (1, q, k_tile),
-                lambda i, pb, pt, pf, pg, pc, pbt, pba, pcnt:
-                    (pt[i * pps], 0, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((q, tile), jnp.float32)],
-    )
-    vals, ids = pl.pallas_call(
-        functools.partial(_fused_packed_topk_kernel, tile=tile, block=block,
-                          k_tile=k_tile, rank_blend=rank_blend,
-                          reducer=reducer, pps=pps),
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((n_tiles + 1, q, k_tile), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles + 1, q, k_tile), jnp.int32)),
-        interpret=interp,
-    )(pair_block, pair_tile, _pair_first(pair_tile), _pair_last(pair_tile),
-      pair_cap, pair_bits, pair_base, pair_count,
-      *([packed] * pps), *([block_tfs] * pps), *([pair_qw] * pps),
-      norm_t, rank_t, qnorm.reshape(1, q))
-    return _finish_candidates(vals, ids, pair_tile, n_tiles, k_tile)
+    compressed posting words and candidates cross the kernel boundary.
+    Rows as for ``fused_score_packed_pallas``."""
+    _check_packed_rows(packed, tf_pairs, block)
+    return _topk_walk(packed, tf_pairs, pair_block,
+                      pair_tile, pair_qw, pair_cap,
+                      (pair_bits, pair_base, pair_count), norm, rank, qnorm,
+                      num_docs, block, k_tile, rank_blend, tile, reducer,
+                      interpret)
 
 
 def extract_tile_candidates(final: Array, tile: int, k_tile: int):
@@ -698,8 +671,7 @@ def extract_tile_candidates(final: Array, tile: int, k_tile: int):
 def build_batched_pairs(cand_block: Array, cand_valid: Array, cand_q: Array,
                         cand_w: Array, tile_first: Array, tile_count: Array,
                         n_tiles: int, num_queries: int, max_pairs: int,
-                        cand_cap: Array | None = None,
-                        pairs_per_step: int = 1):
+                        cand_cap: Array | None = None):
     """jnp glue: batch candidates -> deduplicated tile-sorted routing pairs.
 
     cand_* [S]: one entry per (query, term, block) candidate across the
@@ -715,14 +687,6 @@ def build_batched_pairs(cand_block: Array, cand_valid: Array, cand_q: Array,
     overflow) with NP == max_pairs; overflow counts pairs dropped
     because ``max_pairs`` was too small (0 in healthy runs — surfaced by
     the engine).
-
-    ``pairs_per_step > 1`` additionally RUN-ALIGNS the tile-sorted
-    pairs: each tile's contiguous run is padded with no-op pairs
-    (qw = 0, cap = 0) to a multiple of ``pairs_per_step``, so a kernel
-    that unrolls that many pairs per grid step only ever sees a tile
-    transition at a step boundary.  ``max_pairs`` must then be a
-    multiple of ``pairs_per_step``; padding that pushes real pairs past
-    ``max_pairs`` counts toward ``overflow`` like any other drop.
     """
     s = cand_block.shape[0]
     sentinel = jnp.int32(2**30)
@@ -770,42 +734,4 @@ def build_batched_pairs(cand_block: Array, cand_valid: Array, cand_q: Array,
     overflow = jnp.maximum(total - max_pairs, 0)
     pair_block = pair_block[tile_order]
     pair_tile = pair_tile[tile_order]
-    if pairs_per_step <= 1:
-        return pair_block, pair_tile, pair_qw, pair_cap, overflow
-
-    pps = int(pairs_per_step)
-    if max_pairs % pps:
-        raise ValueError(
-            f"max_pairs={max_pairs} must be a multiple of "
-            f"pairs_per_step={pps}")
-    # Re-scatter each real pair to its run-aligned slot: runs of equal
-    # tile get padded to a multiple of pps, consecutive runs stay
-    # contiguous, so every run start lands on a step boundary.
-    pos = jnp.arange(max_pairs, dtype=jnp.int32)
-    real_s = pair_tile < n_tiles
-    start = jnp.searchsorted(pair_tile, pair_tile,
-                             side="left").astype(jnp.int32)
-    end = jnp.searchsorted(pair_tile, pair_tile,
-                           side="right").astype(jnp.int32)
-    rank = pos - start
-    runlen = end - start
-    extra = (-(-runlen // pps)) * pps - runlen      # pad of my run
-    is_start = rank == 0
-    cum = jnp.cumsum(jnp.where(is_start & real_s, extra, 0))
-    pad_before = cum - jnp.where(real_s, extra, 0)  # pads of EARLIER runs
-    new_pos = jnp.where(real_s, start + pad_before + rank, max_pairs)
-    overflow = overflow + jnp.sum(
-        (real_s & (new_pos >= max_pairs)).astype(jnp.int32))
-    nb_ = jnp.zeros((max_pairs,), jnp.int32).at[new_pos].set(
-        pair_block, mode="drop")
-    nqw = jnp.zeros_like(pair_qw).at[new_pos].set(pair_qw, mode="drop")
-    ncap = jnp.zeros((max_pairs,), jnp.int32).at[new_pos].set(
-        pair_cap, mode="drop")
-    nt = jnp.full((max_pairs,), -1, jnp.int32).at[new_pos].set(
-        pair_tile, mode="drop")
-    # Padding slots inherit their run's tile (forward fill keeps the
-    # sequence sorted so pair_first/pair_last stay step-aligned); a
-    # fully empty prefix/batch falls through to the trash tile.
-    nt = jax.lax.cummax(nt)
-    nt = jnp.where(nt < 0, n_tiles, nt)
-    return nb_, nt, nqw, ncap, overflow
+    return pair_block, pair_tile, pair_qw, pair_cap, overflow

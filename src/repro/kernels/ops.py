@@ -22,7 +22,8 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
-from repro.core.layouts import BandedCsrIndex, BlockedIndex, PackedCsrIndex
+from repro.core.layouts import (BandedCsrIndex, BlockedIndex,
+                                PackedCsrIndex, unpair_tfs)
 from repro.core.query import final_scores
 from repro.kernels import ref
 from repro.kernels.embedding_bag import embedding_bag_pallas
@@ -149,17 +150,33 @@ def blocked_query_scores(index: BlockedIndex, term_ids: Array, idf_w: Array,
 # ---------------------------------------------------------------------------
 
 
+def term_pairs_bound(n_terms: int, m_blocks: int, n_tiles: int) -> int:
+    """Most routing pairs ``n_terms`` posting lists of at most
+    ``m_blocks`` blocks each can produce over ``n_tiles`` doc tiles.
+
+    A term's blocks are doc-ordered and disjoint, so consecutive blocks
+    share at most their boundary tile: one term's (block, tile) pairs
+    number at most ``n_tiles + m_blocks - 1``, at any tile width."""
+    return int(n_terms) * (int(n_tiles) + max(int(m_blocks), 1) - 1)
+
+
 def default_max_pairs(index: BlockedIndex | PackedCsrIndex, num_queries: int,
                       num_terms: int, cap: int, tile: int = TILE) -> int:
     """Static routing-pair budget for a batch.
 
-    After cross-query dedup, pairs are unique (block, tile) — bounded
-    both by the whole index's span sum (``route_pairs_max``) and by
-    candidate-count x worst single-block span.  Both bounds are exact
-    for ``tile == route_tile``, so overflow is impossible at the default
-    tile; for other widths the span scales by ``route_tile / tile``.
+    After cross-query dedup, pairs are unique (block, tile) — bounded by
+    the whole index's span sum (``route_pairs_max``), by candidate-count
+    x worst single-block span, and by ``term_pairs_bound`` over the
+    batch's term slots.  All three bounds are exact upper bounds, so
+    overflow is impossible at the default tile; for other widths the
+    span scales by ``route_tile / tile``.  At a million-doc class this is
+    a few hundred thousand pairs for a served 8x8 batch where the
+    whole-index ``scaled_pairs_budget`` is 2**27: the difference between
+    a batch whose pair weights fit on the device and one that does not.
     """
     m = max(-(-min(cap, max(index.max_posting_len, 1)) // index.block), 1)
+    if isinstance(index, BlockedIndex):
+        m = min(m, max(index.max_blocks_per_term, 1))
     cands = num_queries * num_terms * m
     span = index.route_span_max
     pairs_max = index.route_pairs_max
@@ -169,7 +186,9 @@ def default_max_pairs(index: BlockedIndex | PackedCsrIndex, num_queries: int,
               else index.block_docs.shape[0])
         span = span * scale + 1
         pairs_max = pairs_max * scale + nb
-    return max(min(pairs_max, cands * max(span, 1)), 8)
+    n_tiles = max(-(-index.docs.num_docs // tile), 1)
+    return max(min(pairs_max, cands * max(span, 1),
+                   term_pairs_bound(num_queries * num_terms, m, n_tiles)), 8)
 
 
 def scaled_pairs_budget(index: BlockedIndex | PackedCsrIndex,
@@ -189,45 +208,6 @@ def scaled_pairs_budget(index: BlockedIndex | PackedCsrIndex,
     nb = (index.packed.shape[0] if isinstance(index, PackedCsrIndex)
           else index.block_docs.shape[0])
     return max(int(index.route_pairs_max) * scale + int(nb), 8)
-
-
-def round_up_pairs(max_pairs: int, pairs_per_step: int) -> int:
-    """Pair budgets must be a multiple of the kernel's unroll factor."""
-    pps = max(int(pairs_per_step), 1)
-    return -(-int(max_pairs) // pps) * pps
-
-
-def widen_pairs_for_step(max_pairs: int, num_docs: int, tile: int,
-                         pairs_per_step: int) -> int:
-    """Widen a pair budget for run-aligned no-op padding, then round up.
-
-    ``build_batched_pairs(..., pairs_per_step=pps)`` pads every tile's
-    pair run to a multiple of ``pps``, inserting up to ``pps - 1`` no-op
-    pairs per visited tile — so a budget that is exact at ``pps == 1``
-    (e.g. ``route_pairs_max`` at the route tile) overflows under
-    ``pps > 1`` and real routing pairs get DROPPED.  Every ``pps``-aware
-    budget must flow through here (the sharded scorers inline the same
-    arithmetic on their meta shapes).
-    """
-    pps = max(int(pairs_per_step), 1)
-    if pps > 1:
-        n_tiles = max(-(-int(num_docs) // max(int(tile), 1)), 1)
-        max_pairs = int(max_pairs) + n_tiles * (pps - 1)
-    return round_up_pairs(max_pairs, pps)
-
-
-def padded_pairs_budget(index: BlockedIndex | PackedCsrIndex,
-                        tile: int = TILE,
-                        pairs_per_step: int = 1) -> int:
-    """``scaled_pairs_budget`` made safe for a tuned ``pairs_per_step``:
-    the whole-index budget at ``tile``, widened for run-aligned padding
-    and rounded to the unroll quantum.  THE budget the per-segment query
-    paths (LiveView.topk, the autotuner's timing loop) must use — taking
-    ``scaled_pairs_budget`` + ``round_up_pairs`` directly silently drops
-    postings whenever ``pairs_per_step > 1``."""
-    return widen_pairs_for_step(
-        scaled_pairs_budget(index, tile), index.docs.num_docs, tile,
-        pairs_per_step)
 
 
 def expand_block_candidates(block_offsets: Array, term_ids: Array,
@@ -306,7 +286,7 @@ def fused_batched_scores(index: BlockedIndex | PackedCsrIndex,
             docs = ref.ref_unpack_blocks(
                 index.packed[pb], index.block_bits[pb],
                 index.block_base[pb], index.block_count[pb], block)
-            tfs = index.block_tfs[pb].astype(jnp.float32)
+            tfs = unpair_tfs(index.tf_pairs, pb)
         else:
             docs = index.block_docs[pb]
             tfs = index.block_tfs[pb]
@@ -332,7 +312,7 @@ def fused_batched_scores(index: BlockedIndex | PackedCsrIndex,
 
     if isinstance(index, PackedCsrIndex):
         scores = fused_score_packed_pallas(
-            index.packed, index.block_tfs, pb, pt, pqw, pcap,
+            index.packed, index.tf_pairs, pb, pt, pqw, pcap,
             index.block_bits[pb], index.block_base[pb],
             index.block_count[pb], num_docs, block, tile,
             interpret=_interp(backend))
@@ -350,7 +330,7 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
                        k_tile: int | None = None,
                        backend: Backend = "pallas", q_pad: int = Q_PAD,
                        reducer: str = "successive",
-                       pairs_per_step: int = 1):
+                       qnorm: Array | None = None):
     """The candidate path: per-tile partial top-k INSIDE the fused
     engine, so the dense [B, num_docs] score array never reaches HBM.
 
@@ -366,19 +346,22 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
     Returns (cand_values f32[B, n_tiles*k_tile],
     cand_ids i32[B, n_tiles*k_tile], overflow).
 
-    ``reducer`` / ``pairs_per_step`` / ``q_pad`` are autotuner-selected
+    ``reducer`` / ``q_pad`` are autotuner-selected
     kernel geometry (see ``kernels/autotune.py``); the defaults are the
     historical hardcoded values, so untuned callers are bit-identical
-    to the pre-autotuner engine.
+    to the pre-autotuner engine.  ``qnorm`` f32[B] overrides the query
+    norms derived from ``idf_w`` (the live index passes its host ones).
     """
     b, t = term_ids.shape
     num_docs = index.docs.num_docs
     if k_tile is None:
         k_tile = default_k_tile(k, tile)
     k_tile = min(k_tile, tile)
-    # per-query norm of the idf weight vector (duplicate slots carry 0
-    # after dedup) — same reduction the oracle's scoring tail performs
-    qnorm = jnp.sqrt(jnp.maximum(jnp.sum(idf_w * idf_w, axis=1), 1e-12))
+    if qnorm is None:
+        # per-query norm of the idf weight vector (duplicate slots carry
+        # 0 after dedup) — same reduction the oracle's scoring tail does
+        qnorm = jnp.sqrt(jnp.maximum(jnp.sum(idf_w * idf_w, axis=1),
+                                     1e-12))
 
     if backend == "xla":
         # plain-HLO lowering: dense scores (same block dedup), then the
@@ -396,12 +379,7 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
     if isinstance(index, BlockedIndex):
         m = min(m, max(index.max_blocks_per_term, 1))
     if max_pairs is None:
-        # callers passing an explicit budget own its pps widening; the
-        # derived default must widen here or pps > 1 overflows it
-        max_pairs = widen_pairs_for_step(
-            default_max_pairs(index, b, t, cap, tile), num_docs, tile,
-            pairs_per_step)
-    max_pairs = round_up_pairs(max_pairs, pairs_per_step)
+        max_pairs = default_max_pairs(index, b, t, cap, tile)
 
     cand_block, cand_valid, cand_q, cand_w, cand_cap = \
         expand_block_candidates(index.block_offsets, term_ids, idf_w,
@@ -410,7 +388,7 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
     pb, pt, pqw, pcap, overflow = build_batched_pairs(
         cand_block, cand_valid, cand_q,
         cand_w.astype(jnp.float32), tfirst, tcount, n_tiles, b, max_pairs,
-        cand_cap=cand_cap, pairs_per_step=pairs_per_step)
+        cand_cap=cand_cap)
 
     # pad the query batch to the accumulator quantum (padding queries
     # get qnorm 1.0 — their zero accumulator masks them to -inf anyway)
@@ -422,18 +400,17 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
 
     if isinstance(index, PackedCsrIndex):
         vals, ids = fused_topk_packed_pallas(
-            index.packed, index.block_tfs, pb, pt, pqw, pcap,
+            index.packed, index.tf_pairs, pb, pt, pqw, pcap,
             index.block_bits[pb], index.block_base[pb],
             index.block_count[pb], index.docs.norm, index.docs.rank,
             qnorm_p, num_docs, block, k_tile, rank_blend=rank_blend,
-            tile=tile, reducer=reducer, pairs_per_step=pairs_per_step,
-            interpret=_interp(backend))
+            tile=tile, reducer=reducer, interpret=_interp(backend))
     else:
         vals, ids = fused_topk_blocked_pallas(
             index.block_docs, index.block_tfs, pb, pt, pqw, pcap,
             index.docs.norm, index.docs.rank, qnorm_p, num_docs, k_tile,
             rank_blend=rank_blend, tile=tile, reducer=reducer,
-            pairs_per_step=pairs_per_step, interpret=_interp(backend))
+            interpret=_interp(backend))
     return vals[:b], ids[:b], overflow
 
 
@@ -460,14 +437,13 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
 
 @functools.partial(jax.jit, static_argnames=(
     "k_tile", "cap", "max_pairs", "rank_blend", "tile", "backend",
-    "q_pad", "reducer", "pairs_per_step"))
+    "q_pad", "reducer"))
 def fused_segment_topk(index: BlockedIndex | PackedCsrIndex,
-                       query_hashes: Array,
-                       idf_w: Array, doc_base: Array, *, k_tile: int,
+                       query_hashes: Array, idf_w: Array, qnorm: Array,
+                       doc_base: Array, *, k_tile: int,
                        cap: int, max_pairs: int, rank_blend: float = 0.0,
                        tile: int = TILE, backend: Backend = "pallas",
-                       q_pad: int = Q_PAD, reducer: str = "successive",
-                       pairs_per_step: int = 1):
+                       q_pad: int = Q_PAD, reducer: str = "successive"):
     """Candidate engine over one segment: fused decode-and-score kernel
     with in-kernel per-tile top-k (tombstones ride in as norm == 0).
 
@@ -484,7 +460,7 @@ def fused_segment_topk(index: BlockedIndex | PackedCsrIndex,
     vals, ids, overflow = fused_batched_topk(
         index, tids, idf_w, cap, k=k_tile, rank_blend=rank_blend,
         max_pairs=max_pairs, tile=tile, k_tile=k_tile, backend=backend,
-        q_pad=q_pad, reducer=reducer, pairs_per_step=pairs_per_step)
+        q_pad=q_pad, reducer=reducer, qnorm=qnorm)
     gids = jnp.where(ids >= 0, ids + doc_base, -1)
     return vals, gids, overflow
 
@@ -493,8 +469,8 @@ def fused_segment_topk(index: BlockedIndex | PackedCsrIndex,
     "k_tile", "cap", "max_pairs", "rank_blend", "tile", "backend",
     "q_pad"))
 def fused_segment_dense_topk(index: BlockedIndex | PackedCsrIndex,
-                             query_hashes: Array,
-                             idf_w: Array, doc_base: Array, *, k_tile: int,
+                             query_hashes: Array, idf_w: Array,
+                             qnorm: Array, doc_base: Array, *, k_tile: int,
                              cap: int, max_pairs: int,
                              rank_blend: float = 0.0, tile: int = TILE,
                              backend: Backend = "pallas",
@@ -506,7 +482,6 @@ def fused_segment_dense_topk(index: BlockedIndex | PackedCsrIndex,
     scores, overflow = fused_batched_scores(
         index, tids, idf_w, cap, max_pairs=max_pairs, tile=tile,
         backend=backend, q_pad=q_pad)
-    qnorm = jnp.sqrt(jnp.maximum(jnp.sum(idf_w * idf_w, axis=1), 1e-12))
     final = final_scores(scores, index.docs.norm, index.docs.rank, qnorm,
                          rank_blend)
     vals, ids = extract_tile_candidates(final, tile, k_tile)
@@ -514,24 +489,30 @@ def fused_segment_dense_topk(index: BlockedIndex | PackedCsrIndex,
     return vals, gids, overflow
 
 
-def banded_pairs_budgets(index: BandedCsrIndex, tile: int = TILE,
-                         pairs_per_step: int = 1) -> tuple[int, int]:
-    """Per-band static pair budgets for a banded segment: each band is
-    its own fused-kernel launch with its own routing-pair buffer.  A
-    band can be EMPTY (every term landed on the other side of the cut);
-    an unpadded empty band carries ``route_pairs_max == 0``, which would
-    size a zero-length pair buffer — clamp to the same floor the
-    whole-index budgets use (padded sealed bands never hit this: the
-    size-class pad lifts ``route_pairs_max`` to >= one class)."""
-    return (max(padded_pairs_budget(index.packed, tile, pairs_per_step), 8),
-            max(padded_pairs_budget(index.hor, tile, pairs_per_step), 8))
+def banded_pairs_budgets(index: BandedCsrIndex, num_queries: int,
+                         num_terms: int, cap: int, tile: int = TILE
+                         ) -> tuple[int, int, int, int]:
+    """Per-band static pair budgets of a served ``[num_queries,
+    num_terms]`` batch over a banded segment: each band is its own
+    fused-kernel launch with its own routing-pair buffer, bounded like
+    any segment by ``default_max_pairs`` (a term lives in one band, so
+    the batch's term slots bound each band).  Returns the packed and
+    HOR budgets and the per-band caps they were sized for."""
+    cap_p = min(int(cap), max(index.packed.max_posting_len, 1))
+    cap_h = min(int(cap), max(index.hor.max_posting_len, 1))
+    return (default_max_pairs(index.packed, num_queries, num_terms, cap_p,
+                              tile),
+            default_max_pairs(index.hor, num_queries, num_terms, cap_h,
+                              tile),
+            cap_p, cap_h)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "k_tile", "cap_packed", "cap_hor", "max_pairs_packed", "max_pairs_hor",
     "rank_blend", "tile", "backend", "q_pad"))
 def fused_segment_banded_topk(index: BandedCsrIndex, query_hashes: Array,
-                              idf_w: Array, doc_base: Array, *, k_tile: int,
+                              idf_w: Array, qnorm: Array, doc_base: Array,
+                              *, k_tile: int,
                               cap_packed: int, cap_hor: int,
                               max_pairs_packed: int, max_pairs_hor: int,
                               rank_blend: float = 0.0, tile: int = TILE,
@@ -564,7 +545,6 @@ def fused_segment_banded_topk(index: BandedCsrIndex, query_hashes: Array,
         index.hor, tids, idf_w, cap_hor, max_pairs=max_pairs_hor,
         tile=tile, backend=backend, q_pad=q_pad)
     scores = acc_p + acc_h
-    qnorm = jnp.sqrt(jnp.maximum(jnp.sum(idf_w * idf_w, axis=1), 1e-12))
     final = final_scores(scores, index.docs.norm, index.docs.rank, qnorm,
                          rank_blend)
     vals, ids = extract_tile_candidates(final, tile, k_tile)
@@ -575,7 +555,7 @@ def fused_segment_banded_topk(index: BandedCsrIndex, query_hashes: Array,
 @functools.partial(jax.jit, static_argnames=(
     "k_tile", "cap", "rank_blend", "tile"))
 def jnp_segment_topk(index, query_hashes: Array, idf_w: Array,
-                     doc_base: Array, *, k_tile: int, cap: int,
+                     qnorm: Array, doc_base: Array, *, k_tile: int, cap: int,
                      rank_blend: float = 0.0, tile: int = TILE):
     """Pure-jnp oracle engine over one segment (gather + scatter-add),
     reduced to the same per-tile candidate lists as the fused kernels."""
@@ -585,11 +565,15 @@ def jnp_segment_topk(index, query_hashes: Array, idf_w: Array,
     def one(qh, w):
         present = qh != 0
         tids = jnp.where(present, index.lookup_terms(qh), -1)
+        # ascending term id: the order the fused engines add a doc's
+        # per-term contributions in (routing pairs are block-sorted)
+        order = jnp.argsort(jnp.where(tids >= 0, tids,
+                                      jnp.iinfo(jnp.int32).max))
+        tids, w = tids[order], w[order]
         d, tf, valid = index.gather_postings(tids, cap)
         return accumulate_scores(d, tf * w[:, None], valid, num_docs)
 
     scores = jax.vmap(one)(query_hashes, idf_w)
-    qnorm = jnp.sqrt(jnp.maximum(jnp.sum(idf_w * idf_w, axis=1), 1e-12))
     final = final_scores(scores, index.docs.norm, index.docs.rank, qnorm,
                          rank_blend)
     vals, ids = extract_tile_candidates(final, tile, k_tile)

@@ -35,7 +35,10 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
     from repro.core import build, layouts, query
+    from repro.kernels.runtime import enable_compile_cache
     from repro.text import corpus
+
+    enable_compile_cache()
 
     t0 = time.time()
     tc = corpus.generate(corpus.CorpusSpec(
@@ -51,7 +54,11 @@ def main() -> None:
 
     if args.shards > 0:
         from repro.distributed import retrieval as dist_ret
-        mesh = jax.make_mesh((args.shards,), ("data",))
+        if args.shards > len(jax.devices()):
+            raise SystemExit(f"--shards {args.shards} but "
+                             f"{len(jax.devices())} devices")
+        mesh = jax.make_mesh((args.shards,), ("data",),
+                             devices=jax.devices()[:args.shards])
         ds = dist_ret.build_doc_sharded(host, args.shards)
         scorer1 = dist_ret.make_doc_sharded_scorer(ds, mesh, "data",
                                                    k=args.topk)

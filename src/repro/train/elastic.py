@@ -26,7 +26,8 @@ def largest_mesh(axis_names: tuple[str, ...] = ("data", "model"),
     n = len(jax.devices())
     model = min(model_parallelism, n)
     data = n // model
-    return jax.make_mesh((data, model), axis_names)
+    return jax.make_mesh((data, model), axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def shardings_for(tree: Any, mesh: Mesh,

@@ -164,8 +164,8 @@ def test_non_default_tile_ranks_identically(layout):
 
 @pytest.mark.parametrize("cfg", [
     autotune.TuneConfig(reducer="bitonic"),
-    autotune.TuneConfig(pairs_per_step=2),
-    autotune.TuneConfig(pairs_per_step=4, reducer="bitonic"),
+    autotune.TuneConfig(k_pad=16),
+    autotune.TuneConfig(k_pad=16, reducer="bitonic"),
     autotune.TuneConfig(q_pad=16),
     autotune.TuneConfig(k_tile=32),
 ])
@@ -194,7 +194,7 @@ def test_active_table_changes_make_scorer_geometry():
                              backend="xla")(jnp.asarray(qh))
     table = autotune.TuningTable()
     table.put("xla", autotune.size_class_of(int(ix.docs.num_docs)), "hor",
-              autotune.TuneConfig(reducer="bitonic", pairs_per_step=2))
+              autotune.TuneConfig(reducer="bitonic", k_tile=32))
     prev = autotune.set_active(table)
     try:
         tuned = query.make_scorer(ix, k=10, cap=cap, engine="pallas",
@@ -216,13 +216,13 @@ def test_active_table_changes_make_scorer_geometry():
 def test_tuning_table_roundtrip(tmp_path):
     t = autotune.TuningTable()
     t.put("pallas", 2048, "hor",
-          autotune.TuneConfig(tile=1024, pairs_per_step=2))
+          autotune.TuneConfig(tile=1024, q_pad=16))
     t.put("xla", 512, "packed", autotune.TuneConfig(reducer="bitonic"))
     p = tmp_path / "table.json"
     t.save(str(p))
     t2 = autotune.TuningTable.load(str(p))
     assert t2.get("pallas", 2048, "hor") == autotune.TuneConfig(
-        tile=1024, pairs_per_step=2)
+        tile=1024, q_pad=16)
     assert t2.get("xla", 512, "packed") == autotune.TuneConfig(
         reducer="bitonic")
     # schema check refuses foreign files
@@ -235,7 +235,7 @@ def test_tuning_table_roundtrip(tmp_path):
 
 def test_lookup_falls_back_to_smaller_class_then_default():
     t = autotune.TuningTable()
-    cfg = autotune.TuneConfig(pairs_per_step=2)
+    cfg = autotune.TuneConfig(q_pad=16)
     t.put("pallas", autotune.size_class_of(1000), "hor", cfg)
     # bigger class inherits the nearest smaller tuned class
     assert t.lookup("pallas", 500_000, "hor") == cfg
@@ -251,7 +251,6 @@ def test_empty_table_resolves_to_historical_defaults():
     assert autotune.DEFAULT_CONFIG.q_pad == 8
     assert autotune.DEFAULT_CONFIG.k_pad == 8
     assert autotune.DEFAULT_CONFIG.reducer == "successive"
-    assert autotune.DEFAULT_CONFIG.pairs_per_step == 1
 
 
 def test_reducer_env_override(monkeypatch):
@@ -269,7 +268,7 @@ def test_autotune_index_selects_and_stores_winner():
             jnp.asarray(np.where(qh > 0, 3.0, 0.0)), 1.0))
     table = autotune.TuningTable()
     configs = [autotune.DEFAULT_CONFIG,
-               autotune.TuneConfig(pairs_per_step=2)]
+               autotune.TuneConfig(q_pad=16)]
     best, records = autotune.autotune_index(
         ix, jnp.asarray(qh), idf_w, k=10, backend="xla",
         configs=configs, reps=1, warmup=1, table=table)
@@ -355,8 +354,7 @@ def test_live_view_with_tuned_table_matches_default():
     for cls in {autotune.size_class_of(int(s.index.docs.num_docs))
                 for s in si.segments()}:
         table.put("xla", cls, "hor",
-                  autotune.TuneConfig(reducer="bitonic", pairs_per_step=2,
-                                      k_tile=32))
+                  autotune.TuneConfig(reducer="bitonic", k_tile=32))
     prev = autotune.set_active(table)
     try:
         tuned_i, tuned_s = _live_topk_ids(si, qh)
@@ -368,17 +366,14 @@ def test_live_view_with_tuned_table_matches_default():
 
 
 # ---------------------------------------------------------------------------
-# pairs_per_step budget widening: run-aligned padding must never drop
-# real routing pairs
+# routing budgets: the served budget never drops a real routing pair
 # ---------------------------------------------------------------------------
 
 
-def test_padded_pairs_budget_covers_run_alignment():
-    """Regression: a budget that is EXACT at pps == 1 (route_pairs_max
-    at the route tile, reached by querying every term at full cap)
-    overflows under pps == 2 run-aligned no-op padding — (2600 docs,
-    80 terms, seed 1) is a corpus where the old round_up-only budget
-    demonstrably drops a real pair.  ``padded_pairs_budget`` must not."""
+def _full_vocab_candidates():
+    """Every term of an engineered corpus (2600 docs, 80 terms, seed 1)
+    queried at full cap: the batch routes every (block, tile) pair of
+    the index, so ``route_pairs_max`` is reached exactly."""
     tc = corpus.generate(corpus.CorpusSpec(num_docs=2600, vocab=80,
                                            avg_distinct=20, seed=1))
     host = build.bulk_build(tc)
@@ -388,27 +383,46 @@ def test_padded_pairs_budget_covers_run_alignment():
     qh = jnp.asarray(th[th != 0][None, :])
     t_ids = jnp.where(qh != 0, ix.lookup_terms(qh), -1)
     m = min(max(-(-cap // ix.block), 1), max(ix.max_blocks_per_term, 1))
-    cb, cv, cq, cw, cc = ops.expand_block_candidates(
+    cands = ops.expand_block_candidates(
         ix.block_offsets, t_ids, jnp.ones_like(t_ids, jnp.float32), m,
         ix.block, cap)
+    return ix, qh, cap, cands
+
+
+def test_default_max_pairs_is_exact_on_full_vocab_batch():
+    """The served budget covers the full pair set (overflow 0) and is
+    tight: one pair fewer drops exactly one real pair."""
+    ix, qh, cap, (cb, cv, cq, cw, cc) = _full_vocab_candidates()
     tf, tcn, n_tiles = ops.routing_spans(ix, 512)
 
     def overflow_at(mp):
         *_, ovf = build_batched_pairs(
             cb, cv, cq, cw.astype(jnp.float32), tf, tcn, n_tiles, 1, mp,
-            cand_cap=cc, pairs_per_step=2)
+            cand_cap=cc)
         return int(ovf)
 
-    narrow = ops.round_up_pairs(ops.scaled_pairs_budget(ix, 512), 2)
-    assert overflow_at(narrow) > 0          # the pre-fix budget
-    assert overflow_at(ops.padded_pairs_budget(ix, 512, 2)) == 0
+    budget = ops.default_max_pairs(ix, *qh.shape, cap, 512)
+    assert budget == ops.scaled_pairs_budget(ix, 512) == ix.route_pairs_max
+    assert overflow_at(budget) == 0
+    assert overflow_at(budget - 1) == 1
 
 
-def test_live_view_tuned_pps_no_silent_drop():
-    """LiveView.topk under a pps > 1 tuned geometry must process the
-    FULL pair set (overflow 0, bit-identical ranking) — and the
-    default stats-free path must route the summed overflow through the
-    loud-overflow contract rather than silently discarding it."""
+@pytest.mark.parametrize("tile", [256, 1024])
+def test_default_max_pairs_covers_retuned_tile(tile):
+    """Away from the route tile the span bound scales: still no drop."""
+    ix, qh, cap, (cb, cv, cq, cw, cc) = _full_vocab_candidates()
+    tf, tcn, n_tiles = ops.routing_spans(ix, tile)
+    *_, ovf = build_batched_pairs(
+        cb, cv, cq, cw.astype(jnp.float32), tf, tcn, n_tiles, 1,
+        ops.default_max_pairs(ix, *qh.shape, cap, tile), cand_cap=cc)
+    assert int(ovf) == 0
+
+
+def test_live_view_full_vocab_no_silent_drop():
+    """LiveView.topk over a query naming every term processes the FULL
+    pair set (overflow 0), at the default geometry and at a retuned
+    tile, with the same ranking; the stats-free path routes the summed
+    overflow through the loud-overflow contract."""
     tc = corpus.generate(corpus.CorpusSpec(num_docs=700, vocab=150,
                                            avg_distinct=25, seed=2))
     si = SegmentedIndex(term_hashes=tc.term_hashes, delta_doc_capacity=256,
@@ -417,21 +431,29 @@ def test_live_view_tuned_pps_no_silent_drop():
     si.seal()
     th = np.asarray(si.view().hashes)
     qh = th[th != 0][None, :].astype(np.uint32)
-    ref = si.topk(qh, 10)
-    tuned, stats = si.topk(qh, 10,
-                           tune=autotune.TuneConfig(pairs_per_step=2),
+    ref, stats = si.topk(qh, 10, return_stats=True)
+    assert stats["pair_overflow"] == 0
+    tuned, stats = si.topk(qh, 10, tune=autotune.TuneConfig(tile=1024),
                            return_stats=True)
     assert stats["pair_overflow"] == 0
     np.testing.assert_array_equal(np.asarray(ref.doc_ids),
                                   np.asarray(tuned.doc_ids))
-    np.testing.assert_array_equal(
-        np.asarray(ref.scores).view(np.uint32),
-        np.asarray(tuned.scores).view(np.uint32))
-    # stats-free path: warn_on_overflow runs (no-op at 0) and the
-    # ranking is unchanged
-    quiet = si.topk(qh, 10, tune=autotune.TuneConfig(pairs_per_step=2))
+    quiet = si.topk(qh, 10)
     np.testing.assert_array_equal(np.asarray(ref.doc_ids),
                                   np.asarray(quiet.doc_ids))
+
+
+def test_tuning_table_ignores_retired_pairs_per_step(tmp_path):
+    """Tables stored while ``pairs_per_step`` was a geometry axis still
+    load: the retired field is dropped, the rest of the entry kept."""
+    p = tmp_path / "old.json"
+    p.write_text(json.dumps({"schema": autotune.TUNE_SCHEMA, "entries": [
+        {"backend": "xla", "size_class": 4096, "layout": "hor",
+         "config": {"tile": 1024, "q_pad": 8, "k_pad": 8, "k_tile": None,
+                    "reducer": "successive", "pairs_per_step": 2}}]}))
+    t = autotune.TuningTable.load(str(p))
+    assert t.get("xla", 4096, "hor") == autotune.TuneConfig(tile=1024)
+    assert "pairs_per_step" not in autotune.TuneConfig().to_dict()
 
 
 # ---------------------------------------------------------------------------

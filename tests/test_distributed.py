@@ -15,9 +15,9 @@ from jax.sharding import PartitionSpec as P
 from repro.text import corpus
 from repro.core import build, layouts, query
 from repro.distributed import retrieval, compress, decode_attn, topk
-from repro.distributed.shmap import shard_map
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 tc = corpus.generate(corpus.CorpusSpec(num_docs=640, vocab=500,
                                        avg_distinct=30, seed=9))
@@ -171,7 +171,7 @@ assert set(np.asarray(ids)[hits].tolist()) == \
 # 4) int8 compressed grad mean ~ identity within quantization error
 x = jnp.asarray(np.random.default_rng(0).normal(size=(128,))
                 .astype(np.float32))
-cm = jax.jit(shard_map(
+cm = jax.jit(jax.shard_map(
     lambda v: compress.quantized_psum_mean(v, "data", 8),
     mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
 np.testing.assert_allclose(np.asarray(cm(x)), np.asarray(x), rtol=0.1,
@@ -450,7 +450,8 @@ def test_smoke_cell_dryrun_on_host_mesh():
     script = r"""
 import jax
 from repro import configs
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for arch_id, shape_id in [("qwen3-0.6b", "train_4k"),
                           ("mixtral-8x7b", "decode_32k"),
                           ("pna", "full_graph_sm"),

@@ -372,3 +372,21 @@ def test_make_scorer_rejects_unblocked_index_for_pallas():
     with pytest.raises(TypeError, match="BlockedIndex or PackedCsrIndex"):
         query.make_scorer(layouts.build_csr(host), k=5, cap=8,
                           engine="pallas")
+
+
+def test_term_pairs_bound_holds_for_every_term():
+    """The per-batch routing budget rests on this bound: a term's
+    (block, tile) pairs never exceed n_tiles + blocks - 1, at the route
+    tile and at a narrower one."""
+    from repro.kernels import ops
+    host = _host()
+    ix = layouts.build_packed_csr(host)
+    offs = np.asarray(ix.block_offsets)
+    for tile in (ix.route_tile, 128):
+        _, tcount, n_tiles = ops.routing_spans(ix, tile)
+        tcount = np.asarray(tcount)
+        for t in range(len(offs) - 1):
+            nb = int(offs[t + 1] - offs[t])
+            if nb:
+                pairs = int(tcount[offs[t]:offs[t + 1]].sum())
+                assert pairs <= ops.term_pairs_bound(1, nb, n_tiles), t

@@ -194,3 +194,52 @@ def test_flash_matches_chunked_model_attention():
                           backend="pallas", block_q=16, block_k=16)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
                                    atol=1e-5)
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins untouched; otherwise the cache
+    sits at ``<checkout>/.jax_cache`` (no temp name, pid or time)."""
+    import os
+
+    from repro.kernels.runtime import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                                ".."))
+        want = os.path.join(checkout, ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_f16_bits_to_f32_exact_for_every_pattern():
+    """The kernels' integer f16 widening equals the f16 -> f32 cast for
+    all 65,536 bit patterns (NaNs stay NaN)."""
+    from repro.kernels.fused_decode_score import _f16_bits_to_f32
+    bits = np.arange(1 << 16, dtype=np.uint16)
+    want = bits.view(np.float16).astype(np.float32)
+    got = np.asarray(_f16_bits_to_f32(jnp.asarray(bits.astype(np.int32))))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
+def test_unpair_tf_row_matches_layout_decode():
+    """The kernels' per-row tf decode equals ``layouts.unpair_tfs`` for
+    both halves of every pair row."""
+    from repro.kernels.fused_decode_score import _unpair_tf_row
+    rng = np.random.default_rng(5)
+    tfs = rng.integers(0, 3000, (7, 128)).astype(np.float16)
+    pairs = jnp.asarray(layouts.pair_tf_rows(tfs))
+    for b in range(7):
+        got = _unpair_tf_row(pairs[b >> 1][None], jnp.int32(b & 1))[0]
+        want = layouts.unpair_tfs(pairs, jnp.int32(b))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(want),
+                                      tfs[b].astype(np.float32))

@@ -191,3 +191,117 @@ def test_pad_packed_to_class_roundtrip(small_host, query_hashes):
         layouts.pad_packed_to_class(pk, nb_pad=1, w_pad=1,
                                     max_posting_len=1, words_per_block=1,
                                     route_pairs_max=1, route_span_max=1)
+
+
+def test_packed_rows_are_stored_dma_ready(small_host):
+    """Packed word rows are stored lane-padded to 128 and the f16 tfs as
+    u32 pair rows that decode back exactly; ``posting_bytes`` still
+    counts the compressed format, not the lane padding."""
+    pk = layouts.build_packed_csr(small_host)
+    nb = int(pk.packed.shape[0])
+    assert pk.packed.shape[1] == layouts.lane_width(pk.words_per_block)
+    assert pk.packed.shape[1] % 128 == 0
+    assert pk.tf_pairs.shape == (-(-nb // 2), pk.block)
+    assert pk.tf_pairs.dtype == jnp.uint32
+    assert np.all(np.asarray(pk.packed)[:, pk.words_per_block:] == 0)
+    tfs = np.asarray(layouts.unpair_tfs(pk.tf_pairs,
+                                        jnp.arange(nb, dtype=jnp.int32)))
+    for b in (0, nb // 2, nb - 1):
+        docs, t, valid = pk.unpack_block(jnp.int32(b))
+        np.testing.assert_array_equal(np.asarray(t), np.where(
+            np.asarray(valid), tfs[b], 0.0))
+    assert pk.posting_bytes() == int(
+        pk.block_offsets.nbytes + 3 * nb * 4
+        + nb * (4 * pk.words_per_block + 2 * pk.block))
+
+
+@pytest.mark.parametrize("nb", [1, 2, 5])
+def test_pair_tf_rows_roundtrip(nb):
+    """Odd and even block counts: every block's tfs come back exact."""
+    rng = np.random.default_rng(nb)
+    tfs = (rng.random((nb, 128)) * 500).astype(np.float16)
+    pairs = layouts.pair_tf_rows(tfs)
+    assert pairs.shape == (-(-nb // 2), 128) and pairs.dtype == np.uint32
+    got = np.asarray(layouts.unpair_tfs(jnp.asarray(pairs),
+                                        jnp.arange(nb, dtype=jnp.int32)))
+    np.testing.assert_array_equal(got, tfs.astype(np.float32))
+
+
+def _packed_reference(h, block, max_bits=32):
+    """The per-block loop the vectorized packed builder replaced."""
+    order = np.argsort(h.term_hashes, kind="stable")
+    lengths = np.diff(h.offsets)[order]
+    nblocks = np.maximum(-(-lengths // block), (lengths > 0).astype(np.int64))
+    block_offsets = np.zeros(h.num_terms + 1, dtype=np.int64)
+    np.cumsum(nblocks, out=block_offsets[1:])
+    nb = int(block_offsets[-1])
+    out = {"bits": np.zeros(nb, np.int32), "base": np.zeros(nb, np.int32),
+           "count": np.zeros(nb, np.int32), "min": np.zeros(nb, np.int32),
+           "max": np.full(nb, -1, np.int32),
+           "tfs": np.zeros((nb, block), np.float16)}
+    words = []
+    for newpos, old in enumerate(order):
+        s, e = int(h.offsets[old]), int(h.offsets[old + 1])
+        docs = h.doc_ids[s:e].astype(np.int64)
+        for k in range(int(nblocks[newpos])):
+            lo, hi = k * block, min((k + 1) * block, len(docs))
+            blk = docs[lo:hi]
+            prev = int(docs[lo - 1]) if lo > 0 else -1
+            deltas = np.diff(np.concatenate([[prev], blk]))
+            width = min(max(1, int(deltas.max()).bit_length()), max_bits)
+            padded = np.zeros(block, dtype=np.int64)
+            padded[:len(deltas)] = deltas
+            words.append(layouts._pack_block_np(padded, width, block))
+            b = int(block_offsets[newpos]) + k
+            out["bits"][b], out["base"][b] = width, prev
+            out["count"][b] = len(blk)
+            out["min"][b], out["max"][b] = blk[0], blk[-1]
+            out["tfs"][b, :len(blk)] = h.tfs[s:e][lo:hi]
+    wpb = max((len(w) for w in words), default=1)
+    out["packed"] = np.zeros((nb, layouts.lane_width(wpb)), np.uint32)
+    for i, w in enumerate(words):
+        out["packed"][i, :len(w)] = w
+    return out, wpb
+
+
+def _edge_host():
+    """Empty terms, a term spanning blocks, deltas near 2**31."""
+    big = np.array([0, 5, 2**30, 2**31 - 2], np.int32)
+    doc_ids = np.concatenate([np.array([1, 3], np.int32),
+                              np.arange(0, 600, 2, dtype=np.int32), big])
+    offsets = np.array([0, 2, 2, 302, 306], np.int64)
+    n = len(doc_ids)
+    return layouts.PostingsHost(
+        term_hashes=np.array([9, 4, 7, 1], np.uint32),
+        df=np.diff(offsets).astype(np.int32), offsets=offsets,
+        doc_ids=doc_ids, tfs=(np.arange(n) % 37 + 1).astype(np.float32),
+        num_docs=2**31 - 1, norm=np.ones(4, np.float32),
+        rank=np.zeros(4, np.float32))
+
+
+@pytest.mark.parametrize("case", ["small-32", "small-128", "edge-128",
+                                  "empty"])
+def test_build_packed_csr_matches_block_loop(small_host, case):
+    """The vectorized packed builder writes the same bytes as the
+    per-block loop: words, decode scalars, doc summaries and tfs."""
+    if case == "empty":
+        h = layouts.PostingsHost(
+            term_hashes=np.zeros(0, np.uint32), df=np.zeros(0, np.int32),
+            offsets=np.zeros(1, np.int64), doc_ids=np.zeros(0, np.int32),
+            tfs=np.zeros(0, np.float32), num_docs=0,
+            norm=np.zeros(0, np.float32), rank=np.zeros(0, np.float32))
+        block = 128
+    else:
+        name, block = case.split("-")
+        h = small_host if name == "small" else _edge_host()
+        block = int(block)
+    ref, wpb = _packed_reference(h, block)
+    pk = layouts.build_packed_csr(h, block=block)
+    assert pk.words_per_block == wpb
+    assert np.asarray(pk.packed).tobytes() == ref["packed"].tobytes()
+    for name, got in (("bits", pk.block_bits), ("base", pk.block_base),
+                      ("count", pk.block_count), ("min", pk.block_min),
+                      ("max", pk.block_max)):
+        np.testing.assert_array_equal(np.asarray(got), ref[name], name)
+    assert np.asarray(pk.tf_pairs).tobytes() == \
+        layouts.pair_tf_rows(ref["tfs"]).tobytes()

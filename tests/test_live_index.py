@@ -527,3 +527,45 @@ def test_doc_sharded_segment_stack_scorer():
                          timeout=500)
     assert "LIVE_SHARDED_OK" in out.stdout, out.stderr[-3000:]
     assert "VIEW_SHARDED_OK" in out.stdout, out.stderr[-3000:]
+
+
+@pytest.mark.parametrize("layout", ["hor", "packed"])
+def test_engines_bit_identical_on_multi_term_batches(layout):
+    """Scores, not only ids: the fused engines and the jnp oracle add a
+    doc's per-term contributions in the same (ascending term id) order,
+    so 3- and 4-term queries agree to the bit across a sealed segment
+    and the delta."""
+    tc = corpus.generate(corpus.CorpusSpec(num_docs=700, vocab=300,
+                                           avg_distinct=30, seed=21))
+    si = SegmentedIndex(term_hashes=tc.term_hashes, delta_doc_capacity=64,
+                        delta_posting_capacity=64 * 64)
+    first, rest = _slices(tc, [0, 660, 700])
+    si.add_batch(first)
+    si.seal(layout=layout)
+    si.add_batch(rest)
+    qb = np.zeros((8, 8), np.uint32)
+    for i, n_terms in enumerate((3, 4)):
+        qb[4 * i:4 * i + 4, :n_terms] = corpus.sample_query_terms(
+            np.asarray(si._df), si.term_hashes, 4, n_terms,
+            num_docs=si.live_doc_count, seed=30 + i)
+    want = si.topk(qb, k=10, engine="jnp")
+    got = si.topk(qb, k=10)
+    np.testing.assert_array_equal(np.asarray(got.doc_ids),
+                                  np.asarray(want.doc_ids))
+    np.testing.assert_array_equal(
+        np.asarray(got.scores).view(np.uint32),
+        np.asarray(want.scores).view(np.uint32))
+
+
+def test_query_weights_do_not_depend_on_batch_shape():
+    """Every server takes its query weights from one host function, so a
+    row gets the same bits alone or inside any batch."""
+    rng = np.random.default_rng(3)
+    df = rng.integers(0, 5000, size=(8, 8)).astype(np.int32)
+    df[:, 5:] = 0
+    w, qn = li.query_weights(df, 12345)
+    for i in range(8):
+        w1, qn1 = li.query_weights(df[i], 12345)
+        np.testing.assert_array_equal(w1.view(np.uint32),
+                                      w[i].view(np.uint32))
+        assert qn1.view(np.uint32) == qn[i].view(np.uint32)

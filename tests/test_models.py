@@ -223,7 +223,8 @@ def test_bucketed_retrieval_recall():
                                rtol=1e-6)
 
     # bucketed pipeline (chunked path): measure recall@k
-    with jax.make_mesh((1,), ("data",)):
+    with jax.make_mesh((1,), ("data",),
+                       axis_types=(jax.sharding.AxisType.Auto,)):
         bk_v, bk_i = recsys.retrieval_topk(uv, cand, k=k, chunk=512,
                                            batch_axes=("data",))
     recall = np.mean([

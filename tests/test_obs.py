@@ -278,8 +278,8 @@ def test_server_metrics_summary_cache_arg_deprecated():
 
 
 def test_overflow_counter_increments_on_engineered_corpus():
-    """The PR-6 overflow corpus (2600 docs / 80 terms / seed 1) under
-    the deliberately narrow pre-fix budget drops real pairs; the loud-
+    """The engineered overflow corpus (2600 docs / 80 terms / seed 1)
+    under a deliberately narrow budget drops real pairs; the loud-
     overflow warning must now ALSO land in the global registry counter
     so capacity pressure is visible without scraping stderr."""
     from repro.kernels.fused_decode_score import build_batched_pairs
@@ -297,10 +297,11 @@ def test_overflow_counter_increments_on_engineered_corpus():
         ix.block_offsets, t_ids, jnp.ones_like(t_ids, jnp.float32), m,
         ix.block, cap)
     tf, tcn, n_tiles = ops.routing_spans(ix, 512)
-    narrow = ops.round_up_pairs(ops.scaled_pairs_budget(ix, 512), 2)
+    # one pair short of the exact whole-index budget
+    narrow = ops.scaled_pairs_budget(ix, 512) - 1
     *_, ovf = build_batched_pairs(
         cb, cv, cq, cw.astype(jnp.float32), tf, tcn, n_tiles, 1, narrow,
-        cand_cap=cc, pairs_per_step=2)
+        cand_cap=cc)
     assert int(ovf) > 0
     c = GLOBAL.counter("engine_pair_overflow")
     before = c.value
