@@ -108,6 +108,7 @@ from repro.core.query import QueryResult, final_scores
 from repro.distributed.topk import merge_topk_candidates_host
 from repro.kernels import autotune, ops
 from repro.obs.registry import EventLog
+from repro.obs.trace import stage
 from repro.kernels.fused_decode_score import (TILE, default_k_tile,
                                               extract_tile_candidates)
 
@@ -156,24 +157,25 @@ def _delta_candidates(terms: Array, tfs: Array, doc_of: Array, norm: Array,
     kernels emit.  All shapes are delta capacities — static for the
     index's lifetime."""
     dcap = norm.shape[0]
-    # per-posting query weight: each posting's unified term id against
-    # the query's (dedup'd) term-id slots
-    match = ((terms[None, :, None] == tids[:, None, :]) &
-             (tids[:, None, :] >= 0) & (terms[None, :, None] >= 0))
-    w_p = jnp.sum(jnp.where(match, idf_w[:, None, :], 0.0), axis=2)
-    valid = doc_of >= 0
-    safe_d = jnp.where(valid, doc_of, dcap)
-    contrib = jnp.where(valid[None, :], tfs[None, :] * w_p, 0.0)
+    with jax.named_scope("delta_scan"):
+        # per-posting query weight: each posting's unified term id
+        # against the query's (dedup'd) term-id slots
+        match = ((terms[None, :, None] == tids[:, None, :]) &
+                 (tids[:, None, :] >= 0) & (terms[None, :, None] >= 0))
+        w_p = jnp.sum(jnp.where(match, idf_w[:, None, :], 0.0), axis=2)
+        valid = doc_of >= 0
+        safe_d = jnp.where(valid, doc_of, dcap)
+        contrib = jnp.where(valid[None, :], tfs[None, :] * w_p, 0.0)
 
-    def row(c):
-        acc = jnp.zeros((dcap + 1,), jnp.float32).at[safe_d].add(
-            c, mode="drop")
-        return acc[:dcap]
+        def row(c):
+            acc = jnp.zeros((dcap + 1,), jnp.float32).at[safe_d].add(
+                c, mode="drop")
+            return acc[:dcap]
 
-    scores = jax.vmap(row)(contrib)
-    final = final_scores(scores, norm, rank, qnorm, rank_blend)
-    vals, ids = extract_tile_candidates(final, tile, k_tile)
-    gids = jnp.where(ids >= 0, ids + doc_base, -1)
+        scores = jax.vmap(row)(contrib)
+        final = final_scores(scores, norm, rank, qnorm, rank_blend)
+        vals, ids = extract_tile_candidates(final, tile, k_tile)
+        gids = jnp.where(ids >= 0, ids + doc_base, -1)
     return vals, gids
 
 
@@ -467,95 +469,103 @@ class LiveView:
         qh = np.asarray(query_hashes, np.uint32)
         if qh.ndim != 2:
             raise ValueError("query_hashes must be [B, T]")
-        qh, tids, idf_w, qnorm = self._prep(qh)
-        qh_dev = jnp.asarray(qh)
-        k_tile = default_k_tile(k)        # delta path: TILE-wide tiles
-        vals, ids, overflows = [], [], []
-        for seg in self.segments:
-            cfg = (tune if tune is not None else autotune.lookup(
-                backend, int(seg.index.docs.num_docs), seg.layout))
-            seg_kt = cfg.resolve_k_tile(k)
-            c = int(cap) if cap is not None else seg.index.max_posting_len
-            if seg.layout == "banded":
-                mp_p, mp_h, cap_p, cap_h = ops.banded_pairs_budgets(
-                    seg.index, *qh.shape, c, cfg.tile)
-                mp = mp_p + mp_h
-            else:
-                mp = ops.default_max_pairs(seg.index, *qh.shape, c,
-                                           cfg.tile)
-            b = jnp.asarray(np.int32(seg.doc_base))
-            span = None
-            if trace is not None:
-                span = trace.span(
-                    "segment", parent="score", doc_base=int(seg.doc_base),
-                    size_class=int(seg.size_class), layout=seg.layout,
-                    tile=int(cfg.tile), k_tile=int(seg_kt),
-                    reducer=cfg.reducer, max_pairs=int(mp),
-                    candidate_bytes=size_model.candidate_bytes_per_query(
-                        int(seg.index.docs.num_docs), int(cfg.tile),
-                        int(seg_kt)),
-                    posting_bytes=size_model.est_posting_bytes(
-                        seg.stats, seg.layout),
-                    **({"band_cut": int(seg.band_cut)}
-                       if seg.layout == "banded" else {}))
-            if engine == "jnp":
-                v, g, o = ops.jnp_segment_topk(
-                    seg.index, qh_dev, idf_w, qnorm, b, k_tile=k_tile,
-                    cap=c, rank_blend=rank_blend)
-            elif seg.layout == "banded":
-                # one fused dense launch per band, partials summed in
-                # the engine; both "candidates" and "dense" modes route
-                # here (a per-band candidate top-k cannot merge — scores
-                # are additive over terms, not max-mergeable)
-                v, g, o = ops.fused_segment_banded_topk(
-                    seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt,
-                    cap_packed=cap_p, cap_hor=cap_h,
-                    max_pairs_packed=mp_p, max_pairs_hor=mp_h,
-                    rank_blend=rank_blend, tile=cfg.tile,
-                    backend=backend, q_pad=cfg.q_pad)
-            elif mode == "dense":
-                v, g, o = ops.fused_segment_dense_topk(
-                    seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt, cap=c,
-                    max_pairs=mp, rank_blend=rank_blend, tile=cfg.tile,
-                    backend=backend, q_pad=cfg.q_pad)
-            else:
-                v, g, o = ops.fused_segment_topk(
-                    seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt, cap=c,
-                    max_pairs=mp, rank_blend=rank_blend, tile=cfg.tile,
-                    backend=backend, q_pad=cfg.q_pad, reducer=cfg.reducer)
-            # keep device arrays until every segment is dispatched —
-            # transferring here would serialize the per-segment launches
-            vals.append(v)
-            ids.append(g)
-            overflows.append(o)
-            if span is not None:
-                # dispatch-only latency: candidates transfer in merge
-                span.end()
-        dspan = (trace.span("delta", parent="score",
-                            postings=int(self.delta_terms.shape[0]),
-                            docs=int(self.delta_n_docs), k_tile=int(k_tile))
-                 if trace is not None else None)
-        dev = self.delta_dev
-        dv, dg = _delta_candidates(
-            dev["terms"], dev["tfs"], dev["doc_of"], dev["norm"],
-            dev["rank"], jnp.asarray(tids.astype(np.int32)), idf_w, qnorm,
-            jnp.asarray(np.int32(self.delta_doc_base)), k_tile=k_tile,
-            rank_blend=rank_blend)
-        vals.append(dv)
-        ids.append(dg)
-        if dspan is not None:
-            dspan.end()
-        overflow = sum(int(o) for o in overflows)
-        if not return_stats:
-            # stats callers inspect the counter themselves; everyone
-            # else gets the engines' loud-overflow contract
-            ops.warn_on_overflow(jnp.asarray(overflow), "live-view "
-                                 "fused engine")
+        with stage(trace, "dispatch", parent="score"):
+            qh, tids, idf_w, qnorm = self._prep(qh)
+            qh_dev = jnp.asarray(qh)
+            k_tile = default_k_tile(k)        # delta path: TILE-wide tiles
+            vals, ids, overflows = [], [], []
+            for seg in self.segments:
+                cfg = (tune if tune is not None else autotune.lookup(
+                    backend, int(seg.index.docs.num_docs), seg.layout))
+                seg_kt = cfg.resolve_k_tile(k)
+                c = int(cap) if cap is not None else seg.index.max_posting_len
+                if seg.layout == "banded":
+                    mp_p, mp_h, cap_p, cap_h = ops.banded_pairs_budgets(
+                        seg.index, *qh.shape, c, cfg.tile)
+                    mp = mp_p + mp_h
+                else:
+                    mp = ops.default_max_pairs(seg.index, *qh.shape, c,
+                                               cfg.tile)
+                b = jnp.asarray(np.int32(seg.doc_base))
+                span = None
+                if trace is not None:
+                    span = trace.span(
+                        "segment", parent="score", doc_base=int(seg.doc_base),
+                        size_class=int(seg.size_class), layout=seg.layout,
+                        tile=int(cfg.tile), k_tile=int(seg_kt),
+                        reducer=cfg.reducer, max_pairs=int(mp),
+                        candidate_bytes=size_model.candidate_bytes_per_query(
+                            int(seg.index.docs.num_docs), int(cfg.tile),
+                            int(seg_kt)),
+                        posting_bytes=size_model.est_posting_bytes(
+                            seg.stats, seg.layout),
+                        **({"band_cut": int(seg.band_cut)}
+                           if seg.layout == "banded" else {}))
+                if engine == "jnp":
+                    v, g, o = ops.jnp_segment_topk(
+                        seg.index, qh_dev, idf_w, qnorm, b, k_tile=k_tile,
+                        cap=c, rank_blend=rank_blend)
+                elif seg.layout == "banded":
+                    # one fused dense launch per band, partials summed in
+                    # the engine; both "candidates" and "dense" modes route
+                    # here (a per-band candidate top-k cannot merge — scores
+                    # are additive over terms, not max-mergeable)
+                    v, g, o = ops.fused_segment_banded_topk(
+                        seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt,
+                        cap_packed=cap_p, cap_hor=cap_h,
+                        max_pairs_packed=mp_p, max_pairs_hor=mp_h,
+                        rank_blend=rank_blend, tile=cfg.tile,
+                        backend=backend, q_pad=cfg.q_pad)
+                elif mode == "dense":
+                    v, g, o = ops.fused_segment_dense_topk(
+                        seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt,
+                        cap=c, max_pairs=mp, rank_blend=rank_blend,
+                        tile=cfg.tile, backend=backend, q_pad=cfg.q_pad)
+                else:
+                    v, g, o = ops.fused_segment_topk(
+                        seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt,
+                        cap=c, max_pairs=mp, rank_blend=rank_blend,
+                        tile=cfg.tile, backend=backend, q_pad=cfg.q_pad,
+                        reducer=cfg.reducer)
+                # keep device arrays until every segment is dispatched —
+                # transferring here would serialize the per-segment launches
+                vals.append(v)
+                ids.append(g)
+                overflows.append(o)
+                if span is not None:
+                    # dispatch only: the device is waited for later
+                    span.end()
+            dspan = (trace.span("delta", parent="score",
+                                postings=int(self.delta_terms.shape[0]),
+                                docs=int(self.delta_n_docs),
+                                k_tile=int(k_tile))
+                     if trace is not None else None)
+            dev = self.delta_dev
+            dv, dg = _delta_candidates(
+                dev["terms"], dev["tfs"], dev["doc_of"], dev["norm"],
+                dev["rank"], jnp.asarray(tids.astype(np.int32)), idf_w, qnorm,
+                jnp.asarray(np.int32(self.delta_doc_base)), k_tile=k_tile,
+                rank_blend=rank_blend)
+            vals.append(dv)
+            ids.append(dg)
+            if dspan is not None:
+                dspan.end()
+        with stage(trace, "device_wait", parent="score"):
+            # the one sync point: every segment's and the delta's
+            # results are on the device before the merge reads them
+            jax.block_until_ready((overflows, dv, dg))
+            overflow = sum(int(o) for o in overflows)
+            if not return_stats:
+                # stats callers inspect the counter themselves; everyone
+                # else gets the engines' loud-overflow contract
+                ops.warn_on_overflow(overflow, "live-view fused engine")
         mv, mi = merge_topk_candidates_host(vals, ids, k, trace=trace)
-        hit = np.isfinite(mv)
-        result = QueryResult(
-            doc_ids=jnp.asarray(np.where(hit, mi, -1).astype(np.int32)),
-            scores=jnp.asarray(np.where(hit, mv, 0.0).astype(np.float32)))
+        with stage(trace, "result", parent="score"):
+            hit = np.isfinite(mv)
+            result = QueryResult(
+                doc_ids=jnp.asarray(np.where(hit, mi, -1).astype(np.int32)),
+                scores=jnp.asarray(np.where(hit, mv, 0.0).astype(
+                    np.float32)))
         if return_stats:
             return result, {"pair_overflow": overflow}
         return result

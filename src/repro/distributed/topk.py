@@ -26,6 +26,8 @@ import numpy as np
 
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.obs.trace import stage
+
 Array = jax.Array
 
 
@@ -44,28 +46,23 @@ def merge_topk_candidates_host(values, ids, k: int, trace=None):
     ranking tie-breaks on lowest global doc id, like the dense oracle.
 
     ``trace`` optionally records a ``"merge"`` child span (of
-    ``"score"``) — note the span covers the device->host transfer of
-    every source's candidates (the np.concatenate below is the sync
-    point), which is exactly what an operator needs to see.
+    ``"score"``), also the profiler annotation ``serve.merge``; it
+    covers the device->host copy of every source's candidates (the
+    live view has already waited for the device in ``device_wait``).
     """
-    span = None
-    if trace is not None:
-        span = trace.span(
-            "merge", parent="score", sources=len(values),
-            candidates=int(sum(x.shape[-1] for x in ids)))
-    v = np.concatenate([np.asarray(x, np.float32) for x in values], axis=-1)
-    i = np.concatenate([np.asarray(x, np.int32) for x in ids], axis=-1)
-    c = v.shape[-1]
-    if c < k:
-        pad = [(0, 0)] * (v.ndim - 1) + [(0, k - c)]
-        v = np.pad(v, pad, constant_values=-np.inf)
-        i = np.pad(i, pad, constant_values=-1)
-    order = np.argsort(-v, axis=-1, kind="stable")[..., :k]
-    out = (np.take_along_axis(v, order, axis=-1),
-           np.take_along_axis(i, order, axis=-1))
-    if span is not None:
-        span.end()
-    return out
+    with stage(trace, "merge", parent="score", sources=len(values),
+               candidates=int(sum(x.shape[-1] for x in ids))):
+        v = np.concatenate([np.asarray(x, np.float32) for x in values],
+                           axis=-1)
+        i = np.concatenate([np.asarray(x, np.int32) for x in ids], axis=-1)
+        c = v.shape[-1]
+        if c < k:
+            pad = [(0, 0)] * (v.ndim - 1) + [(0, k - c)]
+            v = np.pad(v, pad, constant_values=-np.inf)
+            i = np.pad(i, pad, constant_values=-1)
+        order = np.argsort(-v, axis=-1, kind="stable")[..., :k]
+        return (np.take_along_axis(v, order, axis=-1),
+                np.take_along_axis(i, order, axis=-1))
 
 
 def canonicalize_candidates(values: Array, ids: Array

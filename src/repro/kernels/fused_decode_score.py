@@ -487,7 +487,7 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
         scratch_shapes=scratch)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name="pair_walk",
     )(_n_walk(pair_tile, pair_cap, n_tiles), *inputs)
 
 

@@ -53,16 +53,26 @@ def _count_capacity_pressure(name: str, amount) -> None:
     GLOBAL.counter(name).inc(int(amount))
 
 
-def warn_on_overflow(overflow: Array, label: str) -> None:
+def warn_on_overflow(overflow, label: str) -> None:
     """Routing overflow is surfaced, never silent — shared by every
     engine entry point so the contract can't drift between them.  Each
     overflow also increments the process-global ``engine_pair_overflow``
-    registry counter (same taken-branch — zero work when clean)."""
+    registry counter (same taken-branch — zero work when clean).
+
+    A host int (the live view has read its counts already) is checked
+    on the host, with no device work and nothing to compile; a traced
+    array (inside ``jit``) takes a ``lax.cond`` whose taken branch
+    prints and counts through host callbacks."""
+    message = (label + ": routing overflow dropped {o} (block, tile) "
+               "pairs — raise max_pairs")
+    if isinstance(overflow, (int, float)):
+        if overflow > 0:
+            print(message.format(o=overflow))
+            _count_capacity_pressure("engine_pair_overflow", overflow)
+        return
 
     def _warn(o):
-        jax.debug.print(
-            label + ": routing overflow dropped {o} (block, tile) "
-            "pairs — raise max_pairs", o=o)
+        jax.debug.print(message, o=o)
         jax.debug.callback(
             functools.partial(_count_capacity_pressure,
                               "engine_pair_overflow"), o)
@@ -277,11 +287,12 @@ def fused_batched_scores(index: BlockedIndex | PackedCsrIndex,
         # block-level dedup only: one pair per unique block, so the
         # candidate count itself is an exact pair bound
         max_pairs = min(max_pairs, cand_block.shape[0])
-        pb, _, pqw, pcap, overflow = build_batched_pairs(
-            cand_block, cand_valid, cand_q, cand_w.astype(jnp.float32),
-            jnp.zeros((nb_total,), jnp.int32),
-            jnp.ones((nb_total,), jnp.int32), 1, b, max_pairs=max_pairs,
-            cand_cap=cand_cap)
+        with jax.named_scope("route"):
+            pb, _, pqw, pcap, overflow = build_batched_pairs(
+                cand_block, cand_valid, cand_q, cand_w.astype(jnp.float32),
+                jnp.zeros((nb_total,), jnp.int32),
+                jnp.ones((nb_total,), jnp.int32), 1, b,
+                max_pairs=max_pairs, cand_cap=cand_cap)
         if isinstance(index, PackedCsrIndex):
             docs = ref.ref_unpack_blocks(
                 index.packed[pb], index.block_bits[pb],
@@ -300,10 +311,11 @@ def fused_batched_scores(index: BlockedIndex | PackedCsrIndex,
         return acc[:num_docs].T[:b], overflow
 
     tfirst, tcount, n_tiles = routing_spans(index, tile)
-    pb, pt, pqw, pcap, overflow = build_batched_pairs(
-        cand_block, cand_valid, cand_q,
-        cand_w.astype(jnp.float32), tfirst, tcount, n_tiles, b, max_pairs,
-        cand_cap=cand_cap)
+    with jax.named_scope("route"):
+        pb, pt, pqw, pcap, overflow = build_batched_pairs(
+            cand_block, cand_valid, cand_q,
+            cand_w.astype(jnp.float32), tfirst, tcount, n_tiles, b,
+            max_pairs, cand_cap=cand_cap)
 
     # pad the query batch to the accumulator quantum
     bp = -(-b // max(q_pad, 1)) * max(q_pad, 1)
@@ -385,10 +397,11 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
         expand_block_candidates(index.block_offsets, term_ids, idf_w,
                                 m, block, cap)
     tfirst, tcount, n_tiles = routing_spans(index, tile)
-    pb, pt, pqw, pcap, overflow = build_batched_pairs(
-        cand_block, cand_valid, cand_q,
-        cand_w.astype(jnp.float32), tfirst, tcount, n_tiles, b, max_pairs,
-        cand_cap=cand_cap)
+    with jax.named_scope("route"):
+        pb, pt, pqw, pcap, overflow = build_batched_pairs(
+            cand_block, cand_valid, cand_q,
+            cand_w.astype(jnp.float32), tfirst, tcount, n_tiles, b,
+            max_pairs, cand_cap=cand_cap)
 
     # pad the query batch to the accumulator quantum (padding queries
     # get qnorm 1.0 — their zero accumulator masks them to -inf anyway)

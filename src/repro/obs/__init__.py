@@ -15,16 +15,18 @@ ONE span format without import cycles:
                threaded through the serving read path, a sampling
                ``Tracer`` (zero span construction when disabled), and
                the ``StageAggregator`` that folds per-request stage
-               durations into registry histograms
+               durations into registry histograms; ``stage`` opens a
+               span that is also a profiler annotation (``serve.*``)
 """
 from repro.obs.registry import (GLOBAL, Counter, EventLog, Gauge, Histogram,
                                 MetricsRegistry, global_registry,
                                 parse_prometheus, snapshot_from_json,
                                 snapshot_to_json)
-from repro.obs.trace import Span, StageAggregator, Trace, Tracer
+from repro.obs.trace import Span, StageAggregator, Trace, Tracer, stage
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "EventLog",
     "GLOBAL", "global_registry", "parse_prometheus", "snapshot_to_json",
     "snapshot_from_json", "Span", "Trace", "Tracer", "StageAggregator",
+    "stage",
 ]

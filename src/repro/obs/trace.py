@@ -7,29 +7,57 @@ timestamps, so per-request stage durations sum EXACTLY to the measured
 end-to-end latency:
 
     queue_wait   submit -> batch pickup
-    assemble     batch pickup -> query block filled (attrs: fill,
-                 padded slots)
-    score        engine dispatch -> candidates on host
+    assemble     batch pickup -> query block filled: view pin, cache
+                 lookups, the padded block (attrs: fill, padded slots)
+    score        engine dispatch -> the answer on the host (it runs
+                 through the merge and the answer's round trip)
     respond      candidates -> response handed to the ticket
     cache_hit    batch pickup -> response, replacing assemble/score/
                  respond on a result-cache hit
 
 Children of ``score`` (``parent="score"``) record where the engine
-itself went: one ``segment`` span per sealed segment (size_class,
-layout, resolved TuneConfig geometry, analytic candidate/posting
-bytes), a ``delta`` span for the mutable tail, a ``merge`` span for
-the host candidate merge, and ``shard_fanout``/``shard_sync`` spans on
-the distributed scorers.
+itself went, in order:
+
+    dispatch     query weights, then every segment and the delta
+                 enqueued on the device
+    segment      one per sealed segment, inside ``dispatch`` (size_class,
+                 layout, resolved TuneConfig geometry, analytic
+                 candidate/posting bytes); it ends when the segment is
+                 DISPATCHED, so it is host time, not a kernel time
+    delta        the mutable tail's scan, dispatched, inside ``dispatch``
+    device_wait  the blocking wait for every segment's and the delta's
+                 results, then the overflow counts and their check:
+                 the device sync point
+    merge        the host candidate merge
+    result       the merged answer to the device (``QueryResult``)
+    fetch        the answer back to the host, in the server
+
+plus ``shard_fanout``/``shard_sync`` spans on the distributed scorers.
+
+Profiler annotations
+--------------------
+``stage(trace, name)`` opens a span that is also a
+``jax.profiler.TraceAnnotation`` named ``serve.<name>``, so the stage
+lands on the profiler's clock beside the device's operations.  Only
+LEAF stages are annotated — assemble, dispatch, device_wait, merge,
+result, fetch and respond (``annotate``, over the loop that resolves a
+batch's tickets) — so they never nest and they tile a scored batch: a
+device idle gap in a profile is named by the stage that was open.
 
 Tracing is sampled per ticket (``Tracer``); when disabled (the
-default) no ``Span``/``Trace`` object is constructed anywhere on the
-hot path — the test suite asserts this by making construction raise.
+default) no ``Span``/``Trace`` object is constructed and no annotation
+is entered anywhere on the hot path — the test suite asserts this by
+making construction raise.  ``jax`` is imported only when a traced
+stage first opens, so this module stays stdlib-only at import.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any
+
+_NO_STAGE = contextlib.nullcontext()
 
 
 class Span:
@@ -82,6 +110,18 @@ class Trace:
         self.spans.append(s)
         return s
 
+    @contextlib.contextmanager
+    def stage(self, name: str, t0: float | None = None,
+              parent: str | None = None, **attrs):
+        """``span`` as a context manager that is also the profiler
+        annotation ``serve.<name>``; yields the span, ended on exit."""
+        s = self.span(name, t0=t0, parent=parent, **attrs)
+        try:
+            with annotate(name):
+                yield s
+        finally:
+            s.end()
+
     def adopt(self, spans: list) -> None:
         """Share spans recorded once per micro-batch (assemble/score
         and their children) with every sampled ticket in the batch."""
@@ -100,6 +140,26 @@ class Trace:
 
     def to_dict(self) -> dict:
         return {"spans": [s.to_dict() for s in self.spans]}
+
+
+def annotate(name: str, enabled: bool = True):
+    """The profiler annotation ``serve.<name>`` (a context manager that
+    records nothing unless a profiler trace is being collected); a
+    shared no-op context when not ``enabled``."""
+    if not enabled:
+        return _NO_STAGE
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(f"serve.{name}")
+
+
+def stage(trace: Trace | None, name: str, t0: float | None = None,
+          parent: str | None = None, **attrs):
+    """``trace.stage(...)``, or a shared no-op context that constructs
+    nothing when ``trace`` is None (tracing off)."""
+    if trace is None:
+        return _NO_STAGE
+    return trace.stage(name, t0=t0, parent=parent, **attrs)
 
 
 class Tracer:

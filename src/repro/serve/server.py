@@ -6,7 +6,8 @@ server bridges the two: requests admission-queue, and each pump drains
 up to ``batch_size`` of them into one ``(batch_size, n_terms_budget)``
 pad-and-mask evaluation — the exact shapes the per-segment kernels are
 already warm for, so steady-state serving adds ZERO jit cache entries
-(asserted the same way as the PR-3 churn test).
+(asserted the same way as the PR-3 churn test, and counted while
+serving by the registry counter ``serve_compiles``).
 
 Consistency: each micro-batch pins the index's current epoch view
 (``LiveView``) and scores every request in the batch against it — a
@@ -29,13 +30,41 @@ import threading
 import time
 from collections import deque
 
+import jax
 import numpy as np
 
 from repro.core.live_index import LiveView, SegmentedIndex
 from repro.obs.registry import GLOBAL, MetricsRegistry
-from repro.obs.trace import StageAggregator, Trace, Tracer
+from repro.obs.trace import StageAggregator, Trace, Tracer, annotate, stage
 from repro.serve.cache import ResultCache
 from repro.serve.metrics import ServerMetrics
+
+
+# the two compile events a JAX program emits: a function traced to a
+# jaxpr, and a lowered module compiled by the backend
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/backend_compile_duration")
+# per thread: the ``serve_compiles`` counter of the server whose batch
+# that thread is serving (unset outside a batch)
+_in_batch = threading.local()
+_listening = False
+_listen_lock = threading.Lock()
+
+
+def _on_compile(event: str, duration: float, **_kw) -> None:
+    counter = getattr(_in_batch, "compiles", None)
+    if counter is not None and event in COMPILE_EVENTS:
+        counter.inc()
+
+
+def _listen_for_compiles() -> None:
+    """Register the process-wide compile listener once."""
+    global _listening
+    with _listen_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile)
+            _listening = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,9 +102,11 @@ class ServerConfig:
 
     ``trace_sample`` samples end-to-end query traces: every Nth
     submitted ticket carries a ``repro.obs.Trace`` through queue wait,
-    batch assembly, per-segment kernel dispatch, candidate merge, and
-    response (``1`` traces every request, ``0`` — the default —
-    disables tracing entirely: no span objects are constructed on the
+    batch assembly, dispatch, the device wait, candidate merge, and
+    response; a batch holding a sampled ticket also writes its leaf
+    stages as ``serve.<stage>`` profiler annotations (``1`` traces
+    every request, ``0`` — the default — disables tracing entirely: no
+    span objects are constructed and no annotation is entered on the
     hot path, and results are bit-identical either way).
     """
     batch_size: int = 8
@@ -165,6 +196,10 @@ class QueryServer:
                                      cache=self.cache)
         self.tracer = Tracer(self.config.trace_sample)
         self.stages = StageAggregator(self.registry)
+        # compiles while serving a batch: 0 in steady state, since
+        # warmup() compiled every shape the batches use
+        self._compiles = self.registry.counter("serve_compiles")
+        _listen_for_compiles()
         self._register_index_gauges()
         self._queue: deque[Ticket] = deque()
         self._qlock = threading.Lock()
@@ -298,7 +333,11 @@ class QueryServer:
             batch = self._take_batch()
             if not batch:
                 break
-            self._serve_batch(batch)
+            _in_batch.compiles = self._compiles
+            try:
+                self._serve_batch(batch)
+            finally:
+                _in_batch.compiles = None
             served += len(batch)
         return served
 
@@ -319,63 +358,64 @@ class QueryServer:
         t_batch = time.perf_counter() if traced else 0.0
         for t in traced:
             t.trace.span("queue_wait", t0=t.t_submit).end(t_batch)
-        view = self.refresh_view()
-        epoch = view.epoch
-        self.metrics.observe_epoch(epoch)
-        if epoch != self._purged_epoch:
-            # stale-epoch entries are already unreachable (keys carry
-            # their epoch); reclaim them once per advance, not per batch
-            self.cache.purge_below(epoch)
-            self._purged_epoch = epoch
-            # once per epoch advance: report the layout mix this epoch's
-            # stack converged to (seal/compact/rewrite all repin)
-            self.metrics.observe_layout_mix(view.layout_mix())
+        # batch-level spans (assembly, scoring + per-segment/merge
+        # children) are recorded ONCE and adopted by every sampled
+        # ticket the batch scores — the work is genuinely shared
+        btr = Trace() if traced else None
         pending: list[tuple[Ticket, tuple]] = []
-        for ticket in batch:
-            key = self.cache.make_key(ticket.row, cfg.k, epoch)
-            hit = self.cache.get(key)
-            if hit is not None:
-                self._respond(ticket, hit[0], hit[1], epoch, cached=True,
-                              stage_t0=t_batch)
-            else:
-                pending.append((ticket, key))
-        if pending:
-            # batch-level spans (assembly, scoring + per-segment/merge
-            # children) are recorded ONCE and adopted by every sampled
-            # ticket in the batch — the work is genuinely shared
-            btr = (Trace() if any(t.trace is not None for t, _ in pending)
-                   else None)
-            asm = (btr.span("assemble", t0=t_batch, epoch=epoch,
-                            fill=len(pending),
-                            padded_slots=cfg.batch_size - len(pending))
-                   if btr is not None else None)
+        with stage(btr, "assemble", t0=t_batch) as asm:
+            view = self.refresh_view()
+            epoch = view.epoch
+            self.metrics.observe_epoch(epoch)
+            if epoch != self._purged_epoch:
+                # stale-epoch entries are already unreachable (keys
+                # carry their epoch); reclaim them once per advance
+                self.cache.purge_below(epoch)
+                self._purged_epoch = epoch
+                # once per epoch advance: report the layout mix this
+                # epoch's stack converged to (seal/compact/rewrite all
+                # repin)
+                self.metrics.observe_layout_mix(view.layout_mix())
+            for ticket in batch:
+                key = self.cache.make_key(ticket.row, cfg.k, epoch)
+                hit = self.cache.get(key)
+                if hit is not None:
+                    self._respond(ticket, hit[0], hit[1], epoch,
+                                  cached=True, stage_t0=t_batch)
+                else:
+                    pending.append((ticket, key))
             qb = np.zeros((cfg.batch_size, cfg.n_terms_budget), np.uint32)
             for i, (ticket, _) in enumerate(pending):
                 qb[i] = ticket.row
             if asm is not None:
-                asm.end()
-            score = (btr.span("score", t0=asm.t1, engine=cfg.engine,
-                              mode=cfg.mode, backend=cfg.backend,
-                              segments=view.num_segments)
-                     if btr is not None else None)
-            result = view.topk(qb, cfg.k, cap=cfg.cap,
-                               rank_blend=cfg.rank_blend, engine=cfg.engine,
-                               mode=cfg.mode, backend=cfg.backend,
-                               tune=cfg.tune, trace=btr)
+                asm.attrs.update(epoch=epoch, fill=len(pending),
+                                 padded_slots=cfg.batch_size - len(pending))
+        if not pending:
+            return
+        score = (btr.span("score", t0=asm.t1, engine=cfg.engine,
+                          mode=cfg.mode, backend=cfg.backend,
+                          segments=view.num_segments)
+                 if btr is not None else None)
+        result = view.topk(qb, cfg.k, cap=cfg.cap,
+                           rank_blend=cfg.rank_blend, engine=cfg.engine,
+                           mode=cfg.mode, backend=cfg.backend,
+                           tune=cfg.tune, trace=btr)
+        with stage(btr, "fetch", parent="score"):
             ids = np.asarray(result.doc_ids)
             scores = np.asarray(result.scores)
-            if score is not None:
-                score.end()
-            t_scored = score.t1 if score is not None else None
+        if score is not None:
+            score.end()
+        t_scored = score.t1 if score is not None else None
+        with annotate("respond", enabled=btr is not None):
             for i, (ticket, key) in enumerate(pending):
                 self.cache.put(key, ids[i], scores[i])
                 if ticket.trace is not None:
                     ticket.trace.adopt(btr.spans)
                 self._respond(ticket, ids[i].copy(), scores[i].copy(),
                               epoch, cached=False, stage_t0=t_scored)
-            self.metrics.batches += 1
-            self.metrics.batched_queries += len(pending)
-            self.metrics.padded_slots += cfg.batch_size - len(pending)
+        self.metrics.batches += 1
+        self.metrics.batched_queries += len(pending)
+        self.metrics.padded_slots += cfg.batch_size - len(pending)
 
     def _respond(self, ticket: Ticket, doc_ids, scores, epoch: int,
                  cached: bool, stage_t0: float | None = None) -> None:

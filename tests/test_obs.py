@@ -361,7 +361,7 @@ def _mini_corpus():
                                              avg_distinct=16, seed=9))
 
 
-def _make_server(tc, trace_sample):
+def _make_server(tc, trace_sample, cache_capacity=4096):
     si = SegmentedIndex(term_hashes=tc.term_hashes, delta_doc_capacity=128,
                         delta_posting_capacity=128 * 64,
                         policy=compaction.TieredPolicy(size_ratio=4.0,
@@ -369,6 +369,7 @@ def _make_server(tc, trace_sample):
     si.add_batch(_slice(tc, 0, 300))
     si.seal()
     cfg = ServerConfig(batch_size=4, n_terms_budget=8, k=10,
+                       cache_capacity=cache_capacity,
                        trace_sample=trace_sample)
     return si, QueryServer(si, cfg)
 
@@ -556,3 +557,147 @@ def test_event_log_capacity_configurable_end_to_end():
                     mesh=jax.make_mesh((1,), ("shards",)))
     assert all(r.index.events.capacity == 11 for r in ms.replicas)
     ms.stop()
+
+
+# ---------------------------------------------------------------------------
+# profiler annotations, the compile counter, the host overflow branch
+# ---------------------------------------------------------------------------
+
+LEAVES = ["assemble", "dispatch", "device_wait", "merge", "result", "fetch",
+          "respond"]
+
+
+def _distinct_queries(tc, n):
+    return corpus.sample_query_terms(
+        build.bulk_build(_slice(tc, 0, 300)).df, tc.term_hashes, n, 3,
+        num_docs=300, seed=5)
+
+
+def _serve(server, rows):
+    tickets = [server.submit(r) for r in rows]
+    while server.pending:
+        server.pump()
+    return [t.result(timeout=120.0) for t in tickets]
+
+
+def test_traced_batch_annotates_its_leaf_stages(tmp_path):
+    """Under the benchmark's profiler options (host level 1, python
+    level 0) every scored batch writes one ``serve.<leaf>`` annotation
+    per leaf stage, in order, each as long as its span to within 1 ms;
+    the leaves never nest, and the typical hole between two of them is
+    under 1 ms (a rare scheduler pause may fall between two)."""
+    import gc
+    import statistics
+
+    tc = _mini_corpus()
+    _, server = _make_server(tc, 1, cache_capacity=0)
+    server.warmup()
+    rows = _distinct_queries(tc, 24)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    gc.disable()
+    try:
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        responses = _serve(server, rows)
+        jax.profiler.stop_trace()
+    finally:
+        gc.enable()
+    profile = jax.profiler.ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb"))))
+    notes = sorted((e for p in profile.planes for line in p.lines
+                    for e in line.events if e.name.startswith("serve.")),
+                   key=lambda e: e.start_ns)
+
+    def first(trace, name):
+        return next(s for s in trace.spans if s.name == name)
+
+    # each batch's tickets, grouped by their shared assemble span, in
+    # the order the batches were served
+    batches = {}
+    for r in responses:
+        batches.setdefault(id(first(r.trace, "assemble")), []).append(
+            r.trace)
+    traces = sorted(batches.values(),
+                    key=lambda ts: first(ts[0], "assemble").t0)
+    assert len(traces) == len(rows) // 4
+    assert [e.name for e in notes] == [f"serve.{n}" for n in LEAVES] * len(
+        traces)
+    holes = {}
+    for i, ts in enumerate(traces):
+        group = notes[i * len(LEAVES):(i + 1) * len(LEAVES)]
+        want = {name: first(ts[0], name).duration_us * 1e3
+                for name in LEAVES[:-1]}
+        # respond is one span per ticket; the annotation covers them all
+        want["respond"] = (max(first(t, "respond").t1 for t in ts)
+                           - first(ts[0], "score").t1) * 1e9
+        for note, name in zip(group, LEAVES):
+            assert abs(note.duration_ns - want[name]) < 1e6, (
+                name, note.duration_ns, want[name])
+        for a, b in zip(group, group[1:]):
+            gap = b.start_ns - (a.start_ns + a.duration_ns)
+            assert gap >= 0, (a.name, b.name)
+            holes.setdefault((a.name, b.name), []).append(gap)
+    for pair, gaps in holes.items():
+        assert statistics.median(gaps) < 1e6, (pair, gaps)
+
+
+def test_disabled_tracing_enters_no_annotation(monkeypatch):
+    """trace_sample=0: no Span, no Trace and no profiler annotation."""
+    tc = _mini_corpus()
+    _, server = _make_server(tc, 0, cache_capacity=0)
+    server.warmup()
+
+    def boom(*a, **k):
+        raise AssertionError("constructed with tracing disabled")
+
+    monkeypatch.setattr(obs_trace.Span, "__init__", boom)
+    monkeypatch.setattr(obs_trace.Trace, "__init__", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    responses = _serve(server, _distinct_queries(tc, 8))
+    assert len(responses) == 8 and all(r.ok for r in responses)
+    assert all(r.trace is None for r in responses)
+
+
+def test_serve_compiles_counts_only_compiles_inside_a_batch():
+    """A warm server compiles nothing while serving; a batch of a new
+    shape counts its compiles; a compile between batches does not
+    count."""
+    tc = _mini_corpus()
+    _, server = _make_server(tc, 0, cache_capacity=0)
+    server.warmup()
+    compiles = server.registry.get("serve_compiles")
+    rows = _distinct_queries(tc, 12)
+    _serve(server, rows[:8])
+    assert compiles.value == 0
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(5)).block_until_ready()
+    assert compiles.value == 0
+    assert server.metrics_snapshot()["serve_compiles"]["value"] == 0
+    # a wider term budget is a batch shape no warm-up compiled
+    server.config = dataclasses.replace(server.config, n_terms_budget=16)
+    _serve(server, rows[8:])
+    assert compiles.value > 0
+
+
+def test_overflow_host_count_prints_and_counts_like_the_jitted_path(capsys):
+    """A host int is checked on the host: the same message as the
+    jitted branch, and the same ``engine_pair_overflow`` count."""
+    c = GLOBAL.counter("engine_pair_overflow")
+    before = c.value
+    ops.warn_on_overflow(5, "test_obs host")
+    host_out = capsys.readouterr().out
+    assert c.value == before + 5
+
+    @jax.jit
+    def f(o):
+        ops.warn_on_overflow(o, "test_obs host")
+        return o
+
+    f(jnp.asarray(5, jnp.int32)).block_until_ready()
+    jax.effects_barrier()
+    assert c.value == before + 10
+    assert host_out.strip() == capsys.readouterr().out.strip() == (
+        "test_obs host: routing overflow dropped 5 (block, tile) pairs — "
+        "raise max_pairs")
+    ops.warn_on_overflow(0, "test_obs host zero")
+    assert capsys.readouterr().out == "" and c.value == before + 10
