@@ -668,6 +668,25 @@ def extract_tile_candidates(final: Array, tile: int, k_tile: int):
             gids.reshape(b, n_tiles * k_tile))
 
 
+def span_owners(offs: Array, max_pairs: int) -> Array:
+    """Expand spans into pair slots: the span that owns each of
+    ``max_pairs`` slots, i32[max_pairs].
+
+    offs i32[S+1] holds the spans' running start offsets (offs[0] == 0,
+    non-decreasing; a span of zero slots repeats its offset).  Slot p
+    belongs to the last span u with offs[u] <= p, clamped to [0, S-1]
+    (``searchsorted(offs, p, side="right") - 1``), and that is the count
+    of starts offs[1:] at or below p: a prefix sum of unit marks, one
+    S-wide scatter and one scan where a binary search would gather all
+    ``max_pairs`` slots at each of its log2(S) levels.  Starts at or
+    past the budget own no slot and are dropped.
+    """
+    s = offs.shape[0] - 1
+    marks = jnp.zeros((max_pairs,), jnp.int32).at[offs[1:]].add(
+        1, mode="drop")
+    return jnp.minimum(jnp.cumsum(marks, dtype=jnp.int32), max(s - 1, 0))
+
+
 def build_batched_pairs(cand_block: Array, cand_valid: Array, cand_q: Array,
                         cand_w: Array, tile_first: Array, tile_count: Array,
                         n_tiles: int, num_queries: int, max_pairs: int,
@@ -722,8 +741,7 @@ def build_batched_pairs(cand_block: Array, cand_valid: Array, cand_q: Array,
                             jnp.cumsum(cnt, dtype=jnp.int32)])
     total = offs[-1]
     p = jnp.arange(max_pairs, dtype=jnp.int32)
-    owner = jnp.clip(jnp.searchsorted(offs, p, side="right") - 1,
-                     0, max(s - 1, 0)).astype(jnp.int32)
+    owner = span_owners(offs, max_pairs)
     real = p < total
     pair_block = jnp.where(real, ublock[owner], 0)
     pair_tile = jnp.where(real, t0[owner] + (p - offs[owner]),

@@ -29,7 +29,7 @@ successor of this kernel):
     BUILD-TIME cache (``tile_first``/``tile_count`` on BlockedIndex and
     PackedCsrIndex, plus static ``route_pairs_max``/``route_span_max``
     pair budgets).  ``build_pairs`` only does the per-query cumsum /
-    searchsorted expansion over those cached spans.
+    ``span_owners`` expansion over those cached spans.
   * BATCH TILING — the fused kernel widens this kernel's ``[1, tile]``
     accumulator to ``[Q, tile]``: routing pairs are deduplicated across a
     batch of queries and carry a per-query weight ROW, so a hot posting
@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fused_decode_score import span_owners
 from repro.kernels.runtime import resolve_interpret
 
 Array = jax.Array
@@ -142,8 +143,7 @@ def build_pairs(sel_blocks: Array, sel_valid: Array, sel_w: Array,
                             jnp.cumsum(span, dtype=jnp.int32)])
     total = offs[-1]
     p = jnp.arange(max_pairs, dtype=jnp.int32)
-    owner = jnp.clip(jnp.searchsorted(offs, p, side="right") - 1,
-                     0, sel_blocks.shape[0] - 1).astype(jnp.int32)
+    owner = span_owners(offs, max_pairs)
     real = p < total
     tile_id = t0[owner] + (p - offs[owner])
     pair_block = jnp.where(real, safe[owner], 0)

@@ -390,3 +390,167 @@ def test_term_pairs_bound_holds_for_every_term():
             if nb:
                 pairs = int(tcount[offs[t]:offs[t + 1]].sum())
                 assert pairs <= ops.term_pairs_bound(1, nb, n_tiles), t
+
+
+def _np_owner(offs, max_pairs):
+    """The binary-search expansion that ``span_owners`` replaces."""
+    s = len(offs) - 1
+    p = np.arange(max_pairs)
+    return np.clip(np.searchsorted(offs, p, side="right") - 1, 0,
+                   max(s - 1, 0))
+
+
+def _np_batched_pairs(block, valid, q, w, tfirst, tcount, n_tiles,
+                      num_queries, max_pairs, cap):
+    """numpy reference of ``build_batched_pairs``, step for step."""
+    s = len(block)
+    key = np.where(valid, block, 2**30)
+    order = np.argsort(key, kind="stable")
+    k_s = key[order]
+    valid_s = k_s < 2**30
+    uniq = valid_s & np.concatenate([[True], k_s[1:] != k_s[:-1]])
+    uid = np.cumsum(uniq) - 1
+    total_u = int(uniq.sum())
+    ublock = np.zeros(s, np.int32)
+    ublock[uid[uniq]] = k_s[uniq]
+    qw = np.zeros((s, num_queries), np.float32)
+    ucap = np.zeros(s, np.int32)
+    for i in np.flatnonzero(valid_s):
+        qw[uid[i], q[order[i]]] += w[order[i]]
+        ucap[uid[i]] = max(ucap[uid[i]], cap[order[i]])
+    cnt = np.where(np.arange(s) < total_u, tcount[ublock], 0)
+    offs = np.concatenate([[0], np.cumsum(cnt)]).astype(np.int32)
+    total = int(offs[-1])
+    p = np.arange(max_pairs)
+    owner = _np_owner(offs, max_pairs)
+    real = p < total
+    pair_block = np.where(real, ublock[owner], 0).astype(np.int32)
+    pair_tile = np.where(real, tfirst[ublock][owner] + (p - offs[owner]),
+                         n_tiles).astype(np.int32)
+    t_order = np.argsort(pair_tile, kind="stable")
+    pair_qw = qw[owner[t_order]] * real[t_order][:, None]
+    return (pair_block[t_order], pair_tile[t_order], pair_qw,
+            ucap[owner[t_order]], max(total - max_pairs, 0))
+
+
+def _np_pairs(blocks, valid, w, tfirst, tcount, n_tiles, max_pairs):
+    """numpy reference of ``posting_score.build_pairs``."""
+    safe = np.maximum(blocks, 0)
+    span = np.where(valid, tcount[safe], 0)
+    offs = np.concatenate([[0], np.cumsum(span)]).astype(np.int32)
+    total = int(offs[-1])
+    p = np.arange(max_pairs)
+    owner = _np_owner(offs, max_pairs)
+    real = p < total
+    pair_block = np.where(real, safe[owner], 0).astype(np.int32)
+    pair_tile = np.where(real, tfirst[safe][owner] + (p - offs[owner]),
+                         n_tiles).astype(np.int32)
+    pair_w = np.where(real, w[owner], 0.0).astype(np.float32)
+    order = np.argsort(pair_tile, kind="stable")
+    return (pair_block[order], pair_tile[order], pair_w[order],
+            max(total - max_pairs, 0))
+
+
+def _routing_case(name):
+    """Candidates for one routing case: (block, valid, q, w, tile_first,
+    tile_count, n_tiles, num_queries, max_pairs)."""
+    rng = np.random.default_rng(11)
+    nb, n_tiles, nq = 64, 40, 4
+    tfirst = rng.integers(0, n_tiles - 3, nb).astype(np.int32)
+    tcount = rng.integers(1, 4, nb).astype(np.int32)
+    s, max_pairs = 48, 96
+    block = rng.integers(0, nb, s).astype(np.int32)
+    valid = rng.random(s) < 0.6
+    if name == "duplicate_blocks":
+        block[s // 2:] = block[:s // 2]     # each block in two queries
+    elif name == "zero_tile_spans":
+        tcount[::3] = 0
+    elif name == "over_budget":
+        valid[:] = True
+        max_pairs = 20
+    elif name == "no_valid_candidate":
+        valid[:] = False
+    elif name == "one_candidate":
+        s, max_pairs = 1, 8
+        block, valid = block[:1], np.ones(1, bool)
+    elif name == "segment0_ratio":
+        # paper-1m's first segment: a budget 1.25x the candidates, ~1/10
+        # of the candidates valid
+        s, max_pairs = 1376, 1720
+        block = rng.integers(0, nb, s).astype(np.int32)
+        valid = rng.random(s) < 0.1
+    q = (np.arange(s) * nq // s).astype(np.int32)
+    # one candidate per (block, query): distinct terms own distinct blocks
+    _, first = np.unique(np.stack([block, q]), axis=1, return_index=True)
+    keep = np.zeros(s, bool)
+    keep[first] = True
+    valid &= keep
+    w = (rng.integers(1, 64, s) / 16).astype(np.float32)
+    return block, valid, q, w, tfirst, tcount, n_tiles, nq, max_pairs
+
+
+ROUTING_CASES = ["duplicate_blocks", "zero_tile_spans", "over_budget",
+                 "no_valid_candidate", "one_candidate", "segment0_ratio"]
+
+
+@pytest.mark.parametrize("case", ROUTING_CASES)
+def test_batched_pairs_match_binary_search_reference(case):
+    """The scatter-and-prefix-sum expansion routes exactly as the binary
+    search did: every output bit-equal, overflow included."""
+    from repro.kernels.fused_decode_score import build_batched_pairs
+    block, valid, q, w, tfirst, tcount, n_tiles, nq, mp = \
+        _routing_case(case)
+    cap = (block % 7 + 1).astype(np.int32)
+    got = build_batched_pairs(
+        jnp.asarray(block), jnp.asarray(valid), jnp.asarray(q),
+        jnp.asarray(w), jnp.asarray(tfirst), jnp.asarray(tcount), n_tiles,
+        nq, mp, jnp.asarray(cap))
+    want = _np_batched_pairs(block, valid, q, w, tfirst, tcount, n_tiles,
+                             nq, mp, cap)
+    for g, e in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), e)
+    if case == "over_budget":
+        assert int(got[-1]) > 0
+
+
+@pytest.mark.parametrize("case", ROUTING_CASES)
+def test_single_query_pairs_match_binary_search_reference(case):
+    from repro.kernels.posting_score import build_pairs
+    block, valid, _, w, tfirst, tcount, n_tiles, _, mp = _routing_case(case)
+    block = np.where(valid, block, -1).astype(np.int32)
+    got = build_pairs(jnp.asarray(block), jnp.asarray(valid),
+                      jnp.asarray(w), jnp.asarray(tfirst),
+                      jnp.asarray(tcount), n_tiles, mp)
+    want = _np_pairs(block, valid, w, tfirst, tcount, n_tiles, mp)
+    for g, e in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), e)
+
+
+@pytest.mark.parametrize("s, max_pairs", [(0, 16), (1, 1), (5, 3),
+                                          (40, 200)])
+def test_span_owners_edge_shapes(s, max_pairs):
+    from repro.kernels.fused_decode_score import span_owners
+    rng = np.random.default_rng(s)
+    offs = np.concatenate([[0], np.cumsum(rng.integers(0, 4, s))])
+    got = span_owners(jnp.asarray(offs, jnp.int32), max_pairs)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _np_owner(offs, max_pairs))
+
+
+def test_routing_lowers_without_a_loop():
+    """At paper-1m's first segment (172,032 candidates, a 214,976-pair
+    budget) routing lowers to straight-line ops: no ``while``, whose
+    every trip would gather the whole pair budget."""
+    import jax
+    from repro.kernels.fused_decode_score import build_batched_pairs
+    s, max_pairs, nb = 172_032, 214_976, 642_432
+    i32 = jax.ShapeDtypeStruct((s,), jnp.int32)
+    args = (i32, jax.ShapeDtypeStruct((s,), jnp.bool_), i32,
+            jax.ShapeDtypeStruct((s,), jnp.float32),
+            jax.ShapeDtypeStruct((nb,), jnp.int32),
+            jax.ShapeDtypeStruct((nb,), jnp.int32), i32)
+    text = jax.jit(
+        lambda b, v, q, w, tf, tc, cap: build_batched_pairs(
+            b, v, q, w, tf, tc, 672, 8, max_pairs, cap)
+    ).lower(*args).as_text()
+    assert "stablehlo.while" not in text
