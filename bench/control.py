@@ -1,7 +1,8 @@
-"""The control of a cell's comparison: the reference computed one
-precision down (bfloat16 for the configuration's float32) put in the
-program's place, at the cell's own size.  It has to come out not
-correct; its readings set the upper end of ``score_gap``'s limit.
+"""The control of a cell's comparison: the reference of the
+configuration's ranking computed one precision down (bfloat16 for the
+configuration's float32) put in the program's place, at the cell's own
+size.  It has to come out not correct; its readings set the upper end
+of ``score_gap``'s limit.
 
     python3 -m bench.control --workload <cell> --seeds 1,2,3
 
@@ -25,8 +26,9 @@ def read(config: dict, mix: dict, seed: int) -> dict:
     qs = traffic.queries(mix, c.df(), c.token_counts(), c.num_docs, n, seed)
     terms = {int(t) for q in qs for t in q}
     k = int(config["k"])
-    exact = reference.Reference(c, terms)
-    low = reference.Reference(c, terms, precision="bfloat16")
+    ranking = run.load_ranking(config["ranking"])
+    exact = ranking.Reference(c, terms)
+    low = ranking.Reference(c, terms, precision="bfloat16")
     got = reference.compare([(q, *low.answer(q, k).served(k)) for q in qs],
                             exact, k)
     checks, correct = reference.verdict(got, 0, config["limits"])

@@ -5,8 +5,10 @@ itself (``repro.serve.snapshot`` format 3): each sealed segment is
 built straight at the doc count the configuration plans, and the last
 documents sit in the delta, as streaming ingest leaves them.  No
 compaction merge runs.  Each segment's layout is what the program's
-default ``LayoutCostModel`` chooses for it, and the program computes
-its own norms (``refresh_norms``).
+default ``LayoutCostModel`` chooses for it.  The configuration's ranking
+(``bench/rankings/<ranking>.py``) adds its arrays and meta keys to the
+snapshot (``snapshot_part``) and sets the restored index up
+(``after_restore``).
 """
 from __future__ import annotations
 
@@ -25,8 +27,9 @@ def plan(config: dict) -> tuple[list[tuple[int, int]], int]:
     return list(zip(bases.tolist(), spans)), delta
 
 
-def snapshot_state(config: dict, corpus, seed: int) -> dict:
-    """The corpus as a snapshot of a live index, in the program's format."""
+def snapshot_state(config: dict, corpus, seed: int, ranking) -> dict:
+    """The corpus as a snapshot of a live index, in the program's format,
+    with the ranking's part."""
     from repro.core import layouts, size_model
 
     segments, delta = plan(config)
@@ -55,6 +58,7 @@ def snapshot_state(config: dict, corpus, seed: int) -> dict:
         state[f"seg{i}_tfs"] = corpus.tfs[lo:hi]
     d0 = n - delta
     rng = np.random.default_rng([0x72616E6B, int(seed)])
+    arrays, meta_keys = ranking.snapshot_part(corpus)
     meta = {
         "version": 3, "live_docs": n, "epoch": 0, "seal_layout": "hor",
         "delta": {"doc_cap": int(config["delta_doc_capacity"]),
@@ -65,6 +69,7 @@ def snapshot_state(config: dict, corpus, seed: int) -> dict:
         "stats": {},
         "layout_policy": policy.to_dict(),
         "segments": seg_meta,
+        **meta_keys,
     }
     state.update({
         "meta": np.frombuffer(json.dumps(meta).encode(), np.uint8),
@@ -76,16 +81,18 @@ def snapshot_state(config: dict, corpus, seed: int) -> dict:
         "delta_terms": corpus.terms[corpus.doc_ptr[d0]:],
         "delta_tfs": corpus.tfs[corpus.doc_ptr[d0]:],
         "delta_lens": np.diff(corpus.doc_ptr[d0:]),
+        **arrays,
     })
     return state
 
 
-def build_index(config: dict, corpus, seed: int):
-    """The live index, its norms computed, and its view pinned."""
+def build_index(config: dict, corpus, seed: int, ranking):
+    """The live index, set up for the ranking, and its view pinned."""
     from repro.serve import snapshot
 
-    si = snapshot.restore_segmented(snapshot_state(config, corpus, seed))
-    si.refresh_norms()
+    si = snapshot.restore_segmented(snapshot_state(config, corpus, seed,
+                                                   ranking))
+    ranking.after_restore(si)
     si.view()
     return si
 

@@ -1,18 +1,12 @@
-"""Plain reference of the served answer, and the comparison with it.
+"""What every ranking's reference shares: the answer it gives, the
+precisions it computes in, and the comparison of served answers with it.
 
-The semantics are the program's documented ranking: for a query of
-distinct terms ``t`` over the live collection of ``D`` documents,
-
-    idf(t)    = ln(1 + D / df(t))
-    score(d)  = sum_t tf(d, t) idf(t) / (|d| |q|)
-    |d|       = sqrt(sum over every term u of d of (tf(d, u) idf(u))^2)
-    |q|       = sqrt(sum_t idf(t)^2)
-
-over the documents that hold at least one query term, best ``k`` first.
-This module computes that from the benchmark's own corpus in float64,
-with numpy alone: it imports nothing of the program and takes nothing
-it has made.  ``precision="bfloat16"`` rounds every stored and computed
-quantity to bfloat16 instead: the control, which has to fail.
+The reference itself, with its formulas, belongs to the configuration's
+ranking (``bench/rankings/<ranking>.py``: a ``Reference(corpus,
+needed_terms, precision)`` whose ``answer(terms, k)`` gives an
+``Answer``).  ``ROUNDING`` maps each precision a reference takes to the
+rounding it applies to every stored and computed quantity: ``float64``,
+and ``bfloat16`` for the control, which has to fail.
 """
 from __future__ import annotations
 
@@ -31,6 +25,9 @@ def _exact(x):
     return np.asarray(x, np.float64)
 
 
+ROUNDING = {"float64": _exact, "bfloat16": _bf16}
+
+
 @dataclasses.dataclass
 class Answer:
     ids: np.ndarray       # i64[n_hit], best first
@@ -43,48 +40,6 @@ class Answer:
         ids, scores = np.full(k, -1), np.zeros(k)
         ids[:len(self.ids)], scores[:len(self.top)] = self.ids, self.top
         return ids, scores
-
-
-class Reference:
-    def __init__(self, corpus, needed_terms, precision: str = "float64"):
-        r = {"float64": _exact, "bfloat16": _bf16}[precision]
-        self.round = r
-        n = corpus.num_docs
-        df = corpus.df()
-        self.num_docs = n
-        self.idf = r(np.where(df > 0, np.log1p(n / np.maximum(df, 1)), 0.0))
-        doc = corpus.doc_of()
-        w = r(corpus.tfs * self.idf[corpus.terms])
-        self.norm = r(np.sqrt(np.bincount(doc, weights=w * w,
-                                          minlength=n)))
-        want = np.zeros(corpus.vocab, bool)
-        want[np.asarray(list(needed_terms), np.int64)] = True
-        keep = want[corpus.terms]
-        terms = corpus.terms[keep]
-        order = np.argsort(terms, kind="stable")
-        self._docs = doc[keep][order]
-        self._tfs = corpus.tfs[keep][order]
-        self._ptr = np.searchsorted(terms[order],
-                                    np.arange(corpus.vocab + 1))
-
-    def answer(self, terms, k: int) -> Answer:
-        r = self.round
-        terms = np.asarray(terms, np.int64)
-        spans = [slice(self._ptr[t], self._ptr[t + 1]) for t in terms]
-        docs = np.concatenate([self._docs[s] for s in spans])
-        ws = np.concatenate([r(self._tfs[s] * self.idf[t])
-                             for s, t in zip(spans, terms)])
-        acc = r(np.bincount(docs, weights=ws, minlength=self.num_docs))
-        qnorm = r(np.sqrt(np.sum(self.idf[terms] ** 2)))
-        cand = np.flatnonzero(acc > 0)
-        sc = r(acc[cand] / r(self.norm[cand] * qnorm))
-        score = np.zeros(self.num_docs)
-        score[cand] = sc
-        n_hit = min(k, len(cand))
-        part = (np.argpartition(-sc, n_hit - 1)[:n_hit]
-                if 0 < n_hit < len(cand) else np.arange(n_hit))
-        best = cand[part[np.lexsort((cand[part], -sc[part]))]]
-        return Answer(ids=best, top=score[best], score=score)
 
 
 def gap(ids, scores, want: Answer) -> float | None:
@@ -109,9 +64,10 @@ def gap(ids, scores, want: Answer) -> float | None:
     return float(wide / want.top[0])
 
 
-def compare(answers, reference: Reference, k: int) -> dict:
-    """``answers``: [(terms, ids, scores)].  Returns the widest gap
-    over the answers right in kind, and how many were wrong in kind."""
+def compare(answers, reference, k: int) -> dict:
+    """``answers``: [(terms, ids, scores)]; ``reference``: a ranking's
+    ``Reference``.  Returns the widest gap over the answers right in
+    kind, and how many were wrong in kind."""
     widest, bad = 0.0, 0
     for terms, ids, scores in answers:
         g = gap(ids, scores, reference.answer(terms, k))

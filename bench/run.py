@@ -9,7 +9,9 @@ run builds the corpus from ``--seed``, stands the live index up through
 the program's snapshot restore, warms the server's one batch shape, and
 then serves an open-loop stream for ``--seconds`` through
 ``QueryServer`` with its worker thread running.  After the window a
-sample of the answers is checked against ``bench/reference.py``.
+sample of the answers is checked against the plain reference of the
+configuration's ranking (``bench/rankings/<ranking>.py``), compared by
+``bench/reference.py``.
 
 With ``--trace 0`` the result's metrics are the cell's end-to-end
 metrics; with ``--trace 1`` the window runs under the profiler with
@@ -52,7 +54,8 @@ def load_benchmark(root: pathlib.Path = ROOT) -> dict:
 
 
 def resolve_cell(bench: dict, name: str, root: pathlib.Path = ROOT):
-    """(cell, configuration entry, configuration file, mix file)."""
+    """(cell, configuration entry, configuration file, mix file).
+    Refuses a configuration whose ``ranking`` names no ranking file."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise Refused(f"no workload {name!r} in BENCHMARK.json")
@@ -61,6 +64,12 @@ def resolve_cell(bench: dict, name: str, root: pathlib.Path = ROOT):
     from bench import traffic
 
     config = json.loads((root / entry["file"]).read_text())
+    ranking = config.get("ranking")
+    if not isinstance(ranking, str):
+        raise Refused(f"{entry['file']} names no ranking: its \"ranking\" "
+                      "is the name of a file bench/rankings/<name>.py")
+    if not (root / "bench" / "rankings" / f"{ranking}.py").is_file():
+        raise Refused(f"no ranking file bench/rankings/{ranking}.py")
     return cell, entry, config, traffic.load(cell["traffic"], root / "bench")
 
 
@@ -70,13 +79,25 @@ def metrics_for(bench: dict, cell: str, kind: str) -> list:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def load_reader(name: str, root: pathlib.Path = ROOT):
-    path = root / "bench" / "layers" / f"{name}.py"
+def _load(kind: str, name: str, root: pathlib.Path):
+    path = root / "bench" / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        f"bench.layers.{name.replace('.', '_').replace('-', '_')}", path)
+        f"bench.{kind}.{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str, root: pathlib.Path = ROOT):
+    return _load("layers", name, root).read
+
+
+def load_ranking(name: str, root: pathlib.Path = ROOT):
+    """The ranking module ``bench/rankings/<name>.py``: its plain
+    ``Reference(corpus, needed_terms, precision)``, the arrays and meta
+    keys it adds to the snapshot (``snapshot_part(corpus)``), and the
+    index's set-up after restore (``after_restore(index)``)."""
+    return _load("rankings", name, root)
 
 
 def device_info(chips: int, peaks: dict) -> dict:
@@ -164,6 +185,7 @@ class Deployment:
         # cache scans and locks the directory on every read
         jax.config.update("jax_compilation_cache_max_size", -1)
         self.config = config
+        self.ranking = load_ranking(config["ranking"])
         self.phase = {}
         t = time.perf_counter()
         self.corpus = corpus_mod.generate(config, seed)
@@ -171,7 +193,8 @@ class Deployment:
         t = time.perf_counter()
         gc.collect()
         before = deploy.device_bytes(), deploy.live_array_bytes()
-        self.index = deploy.build_index(config, self.corpus, seed)
+        self.index = deploy.build_index(config, self.corpus, seed,
+                                        self.ranking)
         for leaf in jax.live_arrays():
             leaf.block_until_ready()
         gc.collect()
@@ -298,8 +321,8 @@ def execute(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
     pick = traffic.check_sample(mix, len(due), rec.cached, seed)
     ok_pick = [i for i in pick if rec.ok[i]]
     t = time.perf_counter()
-    ref = reference.Reference(corpus, {int(x) for i in ok_pick
-                                       for x in queries[i]})
+    ref = dep.ranking.Reference(corpus, {int(x) for i in ok_pick
+                                         for x in queries[i]})
     found = reference.compare(
         [(queries[i], np.asarray(rec.responses[i].doc_ids),
           np.asarray(rec.responses[i].scores)) for i in ok_pick], ref, k)

@@ -4,7 +4,7 @@ itself reads 0."""
 import numpy as np
 import pytest
 
-from bench import control, corpus, reference, traffic
+from bench import control, corpus, reference, run, traffic
 from bench.tests import tiny
 
 
@@ -18,7 +18,8 @@ def test_bfloat16_control_fails_the_limit(name):
     c = corpus.generate(config, tiny.SEED)
     qs = traffic.queries(mix, c.df(), c.token_counts(), c.num_docs, 8,
                          tiny.SEED)
-    exact = reference.Reference(c, {int(t) for q in qs for t in q})
+    exact = run.load_ranking(config["ranking"]).Reference(
+        c, {int(t) for q in qs for t in q})
     k = int(config["k"])
     itself = [(q, *exact.answer(q, k).served(k)) for q in qs]
     assert reference.compare(itself, exact, k) == {"score_gap": 0.0,
