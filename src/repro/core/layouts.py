@@ -90,17 +90,21 @@ def _register(cls):
 
 @dataclasses.dataclass(frozen=True)
 class DocTable:
-    """Per-document metadata: the paper's ``document`` relation."""
+    """Per-document metadata: the paper's ``document`` relation.  An
+    index holds the row its ranking scores with (``core/ranking.py``):
+    ``norm`` under tf-idf cosine, ``length`` under BM25, never both."""
     _static_fields = ()
-    norm: Array   # f32[D]  vector norm under tf-idf (paper §3.6)
-    rank: Array   # f32[D]  PageRank-like static score
+    norm: Array | None   # f32[D]  vector norm under tf-idf (paper §3.6)
+    rank: Array          # f32[D]  PageRank-like static score
+    length: Array | None = None  # f32[D]  BM25's K(d) = k1 (1-b+b dl/avgdl)
 
     @property
     def num_docs(self) -> int:
-        return self.norm.shape[0]
+        return self.rank.shape[0]
 
     def nbytes(self) -> int:
-        return int(self.norm.nbytes + self.rank.nbytes)
+        return int(sum(x.nbytes for x in (self.norm, self.rank,
+                                          self.length) if x is not None))
 
 
 _register(DocTable)

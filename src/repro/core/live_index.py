@@ -54,13 +54,16 @@ Exact-ranking contract
 Scoring state that depends on the WHOLE corpus is maintained globally
 and exactly: ``df`` over live documents (incremented on add,
 decremented on delete using the per-doc forward postings), the live doc
-count behind idf, and tf-idf norms recomputed per mutation batch with
-the same float64 op sequence as the bulk builder.  Tombstones mask
-deleted docs by zeroing their norm — the existing deleted-doc path of
-every engine, applied inside the fused kernel's doc-metadata tail.  The
-result: at ANY point of an add/delete/compact schedule, top-k from the
-fused candidates engine is bit-identical (ties included) to the jnp
-oracle over ``bulk_build`` of the equivalent live corpus
+count behind idf, every document's token count and the live token
+total behind BM25's average length, and the ranking's per-document rows
+(``core/ranking.py``: tf-idf norms with the same float64 op sequence as
+the bulk builder, or BM25's length normalisation) recomputed per
+mutation batch.  Tombstones mask deleted docs by zeroing their row —
+the deleted-doc path of every engine, applied inside the fused kernel's
+doc-metadata tail.  The result: at ANY point of an add/delete/compact
+schedule, top-k from the fused candidates engine is bit-identical (ties
+included) to the jnp oracle over ``bulk_build`` of the equivalent live
+corpus
 (``export_live_corpus`` builds exactly that corpus for the parity
 tests; ranking parity needs ``rank_blend == 0`` or an oracle sharing
 this index's static-rank table, and the default full-list ``cap``).
@@ -105,6 +108,7 @@ from repro.core import compaction, layouts, size_model
 from repro.core.build import TokenizedCorpus
 from repro.core.layouts import DocTable, PostingsHost
 from repro.core.query import QueryResult, final_scores
+from repro.core.ranking import TFIDF_COSINE, Ranking
 from repro.distributed.topk import merge_topk_candidates_host
 from repro.kernels import autotune, ops
 from repro.obs.registry import EventLog
@@ -121,42 +125,26 @@ Array = jax.Array
 
 
 def query_weights(df: np.ndarray, live_docs: int):
-    """Global idf weights + query norms of dedup'd query rows, on the host.
-
-    df i32[..., T] LIVE global document frequencies per (dedup'd) slot.
-    Same formula as ``query.idf`` + the oracle's qnorm reduction, in
-    float32 numpy: every server (single host, every shard of a mesh)
-    takes its weights from here, so a query scores with the same bits
+    """tf-idf cosine's host query weights (``Ranking.query_weights``):
+    every server (single host, every shard of a mesh) takes its weights
+    from the ranking on the host, so a query scores with the same bits
     whatever batch shape or program it rides in.  Returns
-    (idf f32[..., T], qnorm f32[...])."""
-    df = np.asarray(df)
-    d_live = np.float32(live_docs)
-    safe = np.maximum(df, 1).astype(np.float32)
-    idf = np.where(df > 0, np.log1p(d_live / safe),
-                   np.float32(0)).astype(np.float32)
-    return idf, query_norms(idf)
+    (idf f32[..., T], query norms f32[...])."""
+    return TFIDF_COSINE.query_weights(df, live_docs)
 
 
-def query_norms(idf: np.ndarray) -> np.ndarray:
-    """f32[...] norms of idf rows f32[..., T], summed slot by slot on
-    the host (numpy's own reduction order depends on the shape)."""
-    idf = np.asarray(idf, np.float32)
-    sq = np.zeros(idf.shape[:-1], np.float32)
-    for t in range(idf.shape[-1]):
-        sq = sq + idf[..., t] * idf[..., t]
-    return np.sqrt(np.maximum(sq, np.float32(1e-12))).astype(np.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("k_tile", "tile", "rank_blend"))
-def _delta_candidates(terms: Array, tfs: Array, doc_of: Array, norm: Array,
-                      rank: Array, tids: Array, idf_w: Array, qnorm: Array,
+@functools.partial(jax.jit, static_argnames=("k_tile", "tile", "rank_blend",
+                                             "ranking"))
+def _delta_candidates(terms: Array, tfs: Array, doc_of: Array, row: Array,
+                      rank: Array, tids: Array, idf_w: Array, qscale: Array,
                       doc_base: Array, *, k_tile: int, tile: int = TILE,
-                      rank_blend: float = 0.0):
+                      rank_blend: float = 0.0,
+                      ranking: Ranking = TFIDF_COSINE):
     """Score the mutable delta (capacity-padded doc-major postings) and
     reduce to the same per-tile candidate lists the sealed-segment
     kernels emit.  All shapes are delta capacities — static for the
     index's lifetime."""
-    dcap = norm.shape[0]
+    dcap = row.shape[0]
     with jax.named_scope("delta_scan"):
         # per-posting query weight: each posting's unified term id
         # against the query's (dedup'd) term-id slots
@@ -165,15 +153,18 @@ def _delta_candidates(terms: Array, tfs: Array, doc_of: Array, norm: Array,
         w_p = jnp.sum(jnp.where(match, idf_w[:, None, :], 0.0), axis=2)
         valid = doc_of >= 0
         safe_d = jnp.where(valid, doc_of, dcap)
-        contrib = jnp.where(valid[None, :], tfs[None, :] * w_p, 0.0)
+        prow = (row[jnp.where(valid, doc_of, 0)] if ranking.saturates
+                else None)
+        contrib = jnp.where(valid[None, :],
+                            ranking.posting(tfs, prow)[None, :] * w_p, 0.0)
 
-        def row(c):
+        def row_scores(c):
             acc = jnp.zeros((dcap + 1,), jnp.float32).at[safe_d].add(
                 c, mode="drop")
             return acc[:dcap]
 
-        scores = jax.vmap(row)(contrib)
-        final = final_scores(scores, norm, rank, qnorm, rank_blend)
+        scores = jax.vmap(row_scores)(contrib)
+        final = final_scores(scores, row, rank, qscale, rank_blend, ranking)
         vals, ids = extract_tile_candidates(final, tile, k_tile)
         gids = jnp.where(ids >= 0, ids + doc_base, -1)
     return vals, gids
@@ -353,6 +344,16 @@ class Segment:
                                        num_terms=self.num_terms)
 
 
+def _with_docs(ix, docs: DocTable):
+    """The segment index ``ix`` with its DocTable replaced (a banded
+    segment's two bands share one DocTable, as at build)."""
+    if isinstance(ix, layouts.BandedCsrIndex):
+        return layouts.BandedCsrIndex(
+            packed=dataclasses.replace(ix.packed, docs=docs),
+            hor=dataclasses.replace(ix.hor, docs=docs))
+    return dataclasses.replace(ix, docs=docs)
+
+
 def _layout_mix(segments) -> dict:
     """Aggregate per-layout composition of a sealed stack — the
     observability payload behind ``SegmentedIndex.layout_mix`` /
@@ -414,10 +415,17 @@ class LiveView:
     live: np.ndarray           # bool[num_docs] (copy)
     live_docs: int
     num_docs: int
+    live_tokens: int = 0       # token count of the live docs
+    ranking: Ranking = TFIDF_COSINE
 
     @property
     def num_segments(self) -> int:
         return len(self.segments)
+
+    @property
+    def avgdl(self) -> float:
+        """Average live document length in tokens (BM25's avgdl)."""
+        return self.live_tokens / self.live_docs if self.live_docs else 0.0
 
     def layout_mix(self) -> dict:
         """Per-layout composition of the pinned stack (counts, docs,
@@ -434,8 +442,8 @@ class LiveView:
                           0).astype(np.int32)
         else:
             df = np.zeros(qh.shape, np.int32)
-        idf_w, qnorm = query_weights(df, self.live_docs)
-        return qh, tids, jnp.asarray(idf_w), jnp.asarray(qnorm)
+        w, qscale = self.ranking.query_weights(df, self.live_docs)
+        return qh, tids, jnp.asarray(w), jnp.asarray(qscale)
 
     def topk(self, query_hashes, k: int, *, cap: int | None = None,
              rank_blend: float = 0.0, engine: str = "pallas",
@@ -469,8 +477,15 @@ class LiveView:
         qh = np.asarray(query_hashes, np.uint32)
         if qh.ndim != 2:
             raise ValueError("query_hashes must be [B, T]")
+        ranking = self.ranking
+        if ranking.saturates and (mode == "dense" or any(
+                s.layout == "banded" for s in self.segments)):
+            raise NotImplementedError(
+                f"{ranking.name} is served by the candidates engine over "
+                "hor and packed segments; dense mode and banded segments "
+                "score tf-idf cosine only")
         with stage(trace, "dispatch", parent="score"):
-            qh, tids, idf_w, qnorm = self._prep(qh)
+            qh, tids, idf_w, qscale = self._prep(qh)
             qh_dev = jnp.asarray(qh)
             k_tile = default_k_tile(k)        # delta path: TILE-wide tiles
             vals, ids, overflows = [], [], []
@@ -503,30 +518,30 @@ class LiveView:
                            if seg.layout == "banded" else {}))
                 if engine == "jnp":
                     v, g, o = ops.jnp_segment_topk(
-                        seg.index, qh_dev, idf_w, qnorm, b, k_tile=k_tile,
-                        cap=c, rank_blend=rank_blend)
+                        seg.index, qh_dev, idf_w, qscale, b, k_tile=k_tile,
+                        cap=c, rank_blend=rank_blend, ranking=ranking)
                 elif seg.layout == "banded":
                     # one fused dense launch per band, partials summed in
                     # the engine; both "candidates" and "dense" modes route
                     # here (a per-band candidate top-k cannot merge — scores
                     # are additive over terms, not max-mergeable)
                     v, g, o = ops.fused_segment_banded_topk(
-                        seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt,
+                        seg.index, qh_dev, idf_w, qscale, b, k_tile=seg_kt,
                         cap_packed=cap_p, cap_hor=cap_h,
                         max_pairs_packed=mp_p, max_pairs_hor=mp_h,
                         rank_blend=rank_blend, tile=cfg.tile,
                         backend=backend, q_pad=cfg.q_pad)
                 elif mode == "dense":
                     v, g, o = ops.fused_segment_dense_topk(
-                        seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt,
+                        seg.index, qh_dev, idf_w, qscale, b, k_tile=seg_kt,
                         cap=c, max_pairs=mp, rank_blend=rank_blend,
                         tile=cfg.tile, backend=backend, q_pad=cfg.q_pad)
                 else:
                     v, g, o = ops.fused_segment_topk(
-                        seg.index, qh_dev, idf_w, qnorm, b, k_tile=seg_kt,
+                        seg.index, qh_dev, idf_w, qscale, b, k_tile=seg_kt,
                         cap=c, max_pairs=mp, rank_blend=rank_blend,
                         tile=cfg.tile, backend=backend, q_pad=cfg.q_pad,
-                        reducer=cfg.reducer)
+                        reducer=cfg.reducer, ranking=ranking)
                 # keep device arrays until every segment is dispatched —
                 # transferring here would serialize the per-segment launches
                 vals.append(v)
@@ -542,10 +557,10 @@ class LiveView:
                      if trace is not None else None)
             dev = self.delta_dev
             dv, dg = _delta_candidates(
-                dev["terms"], dev["tfs"], dev["doc_of"], dev["norm"],
-                dev["rank"], jnp.asarray(tids.astype(np.int32)), idf_w, qnorm,
-                jnp.asarray(np.int32(self.delta_doc_base)), k_tile=k_tile,
-                rank_blend=rank_blend)
+                dev["terms"], dev["tfs"], dev["doc_of"], dev["row"],
+                dev["rank"], jnp.asarray(tids.astype(np.int32)), idf_w,
+                qscale, jnp.asarray(np.int32(self.delta_doc_base)),
+                k_tile=k_tile, rank_blend=rank_blend, ranking=ranking)
             vals.append(dv)
             ids.append(dg)
             if dspan is not None:
@@ -572,10 +587,15 @@ class LiveView:
 
     def conjunctive(self, query_hashes, k: int, cap: int):
         """AND semantics over the pinned index for ONE query [T]; see
-        ``SegmentedIndex.conjunctive`` for the stats contract."""
+        ``SegmentedIndex.conjunctive`` for the stats contract.  tf-idf
+        cosine only."""
+        if self.ranking.saturates:
+            raise NotImplementedError(
+                f"conjunctive queries score tf-idf cosine only, not "
+                f"{self.ranking.name}")
         qh = _dedup_np(np.asarray(query_hashes, np.uint32).reshape(1, -1))
         needed = int((qh != 0).sum())
-        qh1, tids, idf_w, _qnorm = self._prep(qh)
+        qh1, tids, idf_w, _ = self._prep(qh)
         qh_dev = jnp.asarray(qh1[0])
         k_tile = default_k_tile(k)
         vals, ids, truncs = [], [], []
@@ -591,7 +611,7 @@ class LiveView:
         ops.record_truncated(truncated)
         dev = self.delta_dev
         dv, dg = _delta_conjunctive(
-            dev["terms"], dev["tfs"], dev["doc_of"], dev["norm"],
+            dev["terms"], dev["tfs"], dev["doc_of"], dev["row"],
             jnp.asarray(tids[0].astype(np.int32)), idf_w[0],
             jnp.asarray(np.int32(needed)),
             jnp.asarray(np.int32(self.delta_doc_base)), k_tile=k_tile)
@@ -659,7 +679,8 @@ class SegmentedIndex:
     tombstones, queried by the fused candidates engine per segment.
 
     See the module docstring for the lifecycle and the exact-ranking /
-    recompile-avoidance contracts.
+    recompile-avoidance contracts.  ``ranking`` (``core/ranking.py``)
+    is the index's for its lifetime: tf-idf cosine unless given.
     """
 
     def __init__(self, term_hashes: np.ndarray | None = None, *,
@@ -668,9 +689,11 @@ class SegmentedIndex:
                  policy: compaction.TieredPolicy | None = None,
                  rank_seed: int = 7, seal_layout: str = "hor",
                  layout_policy: size_model.LayoutCostModel | None = None,
-                 event_capacity: int = 256):
+                 event_capacity: int = 256,
+                 ranking: Ranking = TFIDF_COSINE):
         if seal_layout not in ("hor", "packed", "banded"):
             raise ValueError(f"unknown seal layout: {seal_layout!r}")
+        self._ranking = ranking
         self._hashes = (np.asarray(term_hashes, np.uint32).copy()
                         if term_hashes is not None
                         else np.zeros(0, np.uint32))
@@ -678,8 +701,10 @@ class SegmentedIndex:
         self._rebuild_lookup()
         self._live = np.zeros(0, bool)
         self._rank = np.zeros(0, np.float32)
-        self._norm = np.zeros(0, np.float32)
+        self._doc_row = np.zeros(0, np.float32)   # the ranking's doc row
+        self._dl = np.zeros(0, np.int64)          # tokens of every doc
         self._live_docs = 0
+        self._live_tokens = 0
         self._segments: list[Segment] = []
         post_cap = (int(delta_posting_capacity)
                     if delta_posting_capacity is not None
@@ -712,6 +737,15 @@ class SegmentedIndex:
     @property
     def live_doc_count(self) -> int:
         return self._live_docs
+
+    @property
+    def live_token_count(self) -> int:
+        """Tokens (summed tfs) of the live documents, exact."""
+        return self._live_tokens
+
+    @property
+    def ranking(self) -> Ranking:
+        return self._ranking
 
     @property
     def num_segments(self) -> int:
@@ -799,7 +833,8 @@ class SegmentedIndex:
             hashes=self._hashes, hash_sorted=self._hash_sorted,
             hash_order=self._hash_order, df=self._df.copy(),
             live=self._live.copy(), live_docs=self._live_docs,
-            num_docs=self.num_docs)
+            num_docs=self.num_docs, live_tokens=self._live_tokens,
+            ranking=self._ranking)
         return self._view
 
     # -- vocabulary ---------------------------------------------------------
@@ -857,20 +892,25 @@ class SegmentedIndex:
             order = np.lexsort((flat_terms, doc_idx))
             flat_terms = flat_terms[order]
             flat_tfs = flat_tfs[order]
+            dl = np.rint(np.bincount(doc_idx, weights=flat_tfs,
+                                     minlength=nd)).astype(np.int64)
         else:
             flat_terms = np.zeros(0, np.int64)
             flat_tfs = np.zeros(0, np.float32)
+            dl = np.zeros(nd, np.int64)
 
         self._live = np.concatenate([self._live, np.ones(nd, bool)])
         self._rank = np.concatenate(
             [self._rank,
              (self._rng.random(nd) * 1e-3).astype(np.float32)])
-        self._norm = np.concatenate(
-            [self._norm, np.zeros(nd, np.float32)])
+        self._doc_row = np.concatenate(
+            [self._doc_row, np.zeros(nd, np.float32)])
+        self._dl = np.concatenate([self._dl, dl])
         if total:
             self._df += np.bincount(flat_terms,
                                     minlength=len(self._hashes))
         self._live_docs += nd
+        self._live_tokens += int(dl.sum())
         self.stats.postings_appended += total
         self.stats.docs_added += nd
 
@@ -968,6 +1008,7 @@ class SegmentedIndex:
                 self._df[terms.astype(np.int64)] -= 1
         self._live[ids] = False
         self._live_docs -= int(ids.size)
+        self._live_tokens -= int(self._dl[ids].sum())
         self.stats.deletes += int(ids.size)
         self._refresh_norms()
         self._bump_epoch()
@@ -1075,7 +1116,7 @@ class SegmentedIndex:
         np.cumsum(df_seg, out=offsets[1:])
         norm_pad = np.zeros(d_pad, np.float32)
         rank_pad = np.zeros(d_pad, np.float32)
-        norm_pad[:span] = self._norm[base:base + span]
+        norm_pad[:span] = self._doc_row[base:base + span]
         rank_pad[:span] = self._rank[base:base + span]
         host = PostingsHost(
             term_hashes=self._hashes, df=df_seg.astype(np.int32),
@@ -1151,6 +1192,10 @@ class SegmentedIndex:
                 route_pairs_max=layouts.size_class(ix.route_pairs_max),
                 route_span_max=layouts.size_class(ix.route_span_max,
                                                   base=8))
+        if self._ranking.saturates:
+            # the builders lay a cosine DocTable out; this ranking's row
+            # is the length row, and no norm row is kept
+            ix = _with_docs(ix, self._ranking.doc_table(norm_pad, rank_pad))
         doc_offsets = np.zeros(span + 1, np.int64)
         np.cumsum(np.bincount(doc_of.astype(np.int64), minlength=span),
                   out=doc_offsets[1:])
@@ -1267,72 +1312,59 @@ class SegmentedIndex:
     # -- norms / doc metadata ----------------------------------------------
 
     def _refresh_norms(self) -> None:
-        """Recompute every live doc's tf-idf norm with the CURRENT live
-        df and doc count — the same float64 bincount (per-doc ascending-
-        term accumulation order) as the bulk builder, so norms are
-        bit-identical to a rebuild.  Dead docs get norm 0 (the tombstone
-        mask every engine honours); live empty docs get 1e-12."""
-        n_alloc = self.num_docs
-        w = len(self._df)
-        idf64 = (np.log1p(self._live_docs /
-                          np.maximum(self._df, 1).astype(np.float64))
-                 if w else np.zeros(0))
-        norm_sq = np.zeros(n_alloc, np.float64)
-        touched = 0
-        for seg in self._segments:
-            if seg.n_postings == 0:
-                continue
-            wv = seg.tfs * idf64[seg.terms.astype(np.int64)]
-            norm_sq += np.bincount(
-                seg.doc_of.astype(np.int64) + seg.doc_base,
-                weights=wv * wv, minlength=n_alloc)
-            touched += seg.n_postings
+        """Recompute every doc's ranking row (``Ranking.doc_rows``) with
+        the CURRENT live df, doc count and token count, and push the
+        rows to each segment's device DocTable.  Under cosine that is
+        every live doc's tf-idf norm, from the same float64 bincount
+        (per-doc ascending-term accumulation order) as the bulk builder,
+        so norms are bit-identical to a rebuild; live empty docs get
+        1e-12.  Dead docs get row 0 (the tombstone mask every engine
+        honours)."""
         dl = self._delta
-        if dl.n_postings:
-            wv = (dl.tfs[:dl.n_postings] *
-                  idf64[dl.terms[:dl.n_postings].astype(np.int64)])
-            norm_sq += np.bincount(
-                dl.doc_of[:dl.n_postings].astype(np.int64) + dl.doc_base,
-                weights=wv * wv, minlength=n_alloc)
-            touched += dl.n_postings
-        norm = np.sqrt(norm_sq).astype(np.float32)
-        norm[norm == 0] = 1e-12
-        norm[~self._live] = 0.0
-        self._norm = norm
-        self.stats.postings_norm_refreshed += touched
+        n_p = dl.n_postings
+
+        def postings():
+            # one source at a time: a global id column per segment
+            for seg in self._segments:
+                if seg.n_postings:
+                    yield (seg.doc_of.astype(np.int64) + seg.doc_base,
+                           seg.terms, seg.tfs)
+            if n_p:
+                yield (dl.doc_of[:n_p].astype(np.int64) + dl.doc_base,
+                       dl.terms[:n_p], dl.tfs[:n_p])
+
+        self._doc_row = self._ranking.doc_rows(
+            live=self._live, df=self._df, live_docs=self._live_docs,
+            dl=self._dl, live_tokens=self._live_tokens, postings=postings())
+        if not self._ranking.saturates:
+            self.stats.postings_norm_refreshed += n_p + sum(
+                s.n_postings for s in self._segments)
         for seg in self._segments:
             self._push_doc_meta(seg)
         self._delta_dirty = True
 
     def _push_doc_meta(self, seg: Segment) -> None:
         d_pad = seg.index.docs.num_docs
-        norm_pad = np.zeros(d_pad, np.float32)
-        norm_pad[:seg.doc_span] = self._norm[
+        row_pad = np.zeros(d_pad, np.float32)
+        row_pad[:seg.doc_span] = self._doc_row[
             seg.doc_base:seg.doc_base + seg.doc_span]
-        docs = DocTable(norm=jnp.asarray(norm_pad),
-                        rank=seg.index.docs.rank)
-        if isinstance(seg.index, layouts.BandedCsrIndex):
-            # one DocTable object, shared by both bands (as at build)
-            seg.index = layouts.BandedCsrIndex(
-                packed=dataclasses.replace(seg.index.packed, docs=docs),
-                hor=dataclasses.replace(seg.index.hor, docs=docs))
-        else:
-            seg.index = dataclasses.replace(seg.index, docs=docs)
+        seg.index = _with_docs(seg.index, self._ranking.doc_table(
+            row_pad, seg.index.docs.rank))
 
     def _delta_device(self) -> dict:
         if self._delta_dev is None or self._delta_dirty:
             dl = self._delta
-            norm = np.zeros(dl.doc_cap, np.float32)
+            row = np.zeros(dl.doc_cap, np.float32)
             rank = np.zeros(dl.doc_cap, np.float32)
             hi = min(dl.doc_base + dl.doc_cap, self.num_docs)
             n = max(hi - dl.doc_base, 0)
-            norm[:n] = self._norm[dl.doc_base:hi]
+            row[:n] = self._doc_row[dl.doc_base:hi]
             rank[:n] = self._rank[dl.doc_base:hi]
             self._delta_dev = {
                 "terms": jnp.asarray(dl.terms),
                 "tfs": jnp.asarray(dl.tfs),
                 "doc_of": jnp.asarray(dl.doc_of),
-                "norm": jnp.asarray(norm),
+                "row": jnp.asarray(row),
                 "rank": jnp.asarray(rank),
             }
             self._delta_dirty = False
@@ -1385,12 +1417,16 @@ class SegmentedIndex:
             return si
         si._live = np.ones(host.num_docs, bool)
         si._rank = host.rank.astype(np.float32).copy()
-        si._norm = np.zeros(host.num_docs, np.float32)
+        si._doc_row = np.zeros(host.num_docs, np.float32)
         si._df = host.df.astype(np.int64).copy()
         si._live_docs = host.num_docs
         term_of = np.repeat(np.arange(host.num_terms, dtype=np.int64),
                             np.diff(host.offsets))
         doc = host.doc_ids.astype(np.int64)
+        si._dl = np.rint(np.bincount(doc, weights=host.tfs,
+                                     minlength=host.num_docs)
+                         ).astype(np.int64)
+        si._live_tokens = int(si._dl.sum())
         order = np.lexsort((term_of, doc))           # doc-major canonical
         seg = si._build_segment(0, host.num_docs, doc[order],
                                 term_of[order],

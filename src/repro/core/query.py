@@ -22,6 +22,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.ranking import TFIDF_COSINE, Ranking
+
 Array = jax.Array
 
 
@@ -53,28 +55,19 @@ def dedup_query_hashes(query_hashes: Array) -> Array:
     return jnp.where(dup, 0, query_hashes)
 
 
-def final_scores(scores: Array, norm: Array, rank: Array, qnorm: Array,
-                 rank_blend: float) -> Array:
-    """Batched q_doc scoring tail: cosine + static-rank blend; deleted
-    (norm == 0) and zero-score docs -> -inf.
+def final_scores(scores: Array, row: Array, rank: Array, qscale: Array,
+                 rank_blend: float,
+                 ranking: Ranking = TFIDF_COSINE) -> Array:
+    """Batched q_doc scoring tail of ``ranking`` (``core/ranking.py``);
+    deleted (row 0) and zero-score docs -> -inf.
 
-    scores f32[B, D], qnorm f32[B].  The fused candidate kernels apply
-    the SAME op sequence per resident tile
+    scores f32[B, D], row/rank f32[D], qscale f32[B].  The fused
+    candidate kernels apply the SAME tail per resident tile
     (``fused_decode_score._final_from_acc``), so candidate values are
     bit-identical to this dense reference.
     """
-    return scoring_tail(scores, norm[None, :], rank[None, :],
-                        qnorm[:, None], rank_blend)
-
-
-def scoring_tail(scores: Array, norm: Array, rank: Array, qnorm: Array,
-                 rank_blend: float) -> Array:
-    """``final_scores`` on pre-broadcast operands: norm/rank ``[1, D]``
-    rows, qnorm a ``[B, 1]`` column — the form a kernel holds in VMEM."""
-    live = norm > 0
-    cosine = scores / (jnp.maximum(norm, 1e-12) * qnorm)
-    final = cosine + rank_blend * rank
-    return jnp.where(live & (scores > 0), final, -jnp.inf)
+    return ranking.tail(scores, row[None, :], rank[None, :],
+                        qscale[:, None], rank_blend)
 
 
 def accumulate_scores(doc_ids: Array, weights: Array, valid: Array,

@@ -844,6 +844,14 @@ class SegmentStackShards:
         return qh, w, qnorm
 
 
+def _refuse_saturating(ranking) -> None:
+    """The sharded engines inline tf-idf cosine's tail; BM25 is served
+    by the single-chip path only."""
+    if ranking.saturates:
+        raise ValueError(f"the sharded engines score tf-idf cosine only, "
+                         f"not {ranking.name}")
+
+
 def stack_segment_shards(live_index, n_shards: int) -> SegmentStackShards:
     """Distribute a SegmentedIndex's sealed stack across ``n_shards``.
     The delta must be sealed first — the serving tier replicates
@@ -858,8 +866,10 @@ def stack_segment_shards(live_index, n_shards: int) -> SegmentStackShards:
     (``"packed"``), or any per-seal mixture: segments stack into
     per-``(size_class, layout)`` groups, so a warm
     ``make_doc_sharded_segment_scorer`` jit cache sees zero new entries
-    when a rebuilt stack hits the same group signatures."""
+    when a rebuilt stack hits the same group signatures.  tf-idf cosine
+    only: a BM25 index is refused."""
     from repro.core.live_index import LiveView
+    _refuse_saturating(live_index.ranking)
     if isinstance(live_index, LiveView):
         if live_index.delta_n_docs:
             raise ValueError("pin a view with a sealed delta before "
@@ -1447,8 +1457,10 @@ def build_term_sharded_from_view(view, n_shards: int,
     tier's alternate topology: unlike the segment-stack path it
     re-builds (and re-compiles for new shapes) per epoch, which is the
     right trade only when the corpus is near-static between handoffs.
+    tf-idf cosine only: a BM25 view is refused.
     """
     from repro.core import build
+    _refuse_saturating(view.ranking)
     tc_live, live_ids = view.export_live_corpus()
     builder = {"packed": build_term_sharded_packed,
                "banded": build_term_sharded_banded}.get(

@@ -294,11 +294,11 @@ def time_config(index, query_hashes, idf_w, k: int, cap: int,
     under ``cfg`` (jit-compiled; warmup excluded)."""
     import jax
 
-    from repro.core.live_index import query_norms
+    from repro.core.ranking import query_norms
     from repro.kernels import ops
 
     k_tile = cfg.resolve_k_tile(k)
-    qnorm = jax.numpy.asarray(query_norms(idf_w))
+    qscale = jax.numpy.asarray(query_norms(idf_w))
     # the query paths' budget: every candidate is timed doing the FULL
     # pair set, not a silently truncated one
     max_pairs = ops.default_max_pairs(index, *query_hashes.shape, cap,
@@ -306,7 +306,7 @@ def time_config(index, query_hashes, idf_w, k: int, cap: int,
 
     def run():
         vals, ids, _ = ops.fused_segment_topk(
-            index, query_hashes, idf_w, qnorm, jax.numpy.int32(0),
+            index, query_hashes, idf_w, qscale, jax.numpy.int32(0),
             k_tile=k_tile, cap=cap, max_pairs=max_pairs,
             rank_blend=rank_blend,
             tile=cfg.tile, backend=backend, q_pad=cfg.q_pad,

@@ -11,10 +11,11 @@ pairs ``(block, tile)`` and, per pair,
      (HOR/BlockedIndex) or delta+bit-packed u32 words (PackedCsrIndex);
   2. for packed blocks, unpacks IN VMEM (per-lane variable shifts over
      a lane gather of the word row + a log-step lane prefix sum);
-  3. scatters ``qw * tf`` into a ``tile``-wide doc tile with a one-hot
-     matmul on the MXU and adds it to a ``[Q, tile]`` accumulator — a
-     hot block is read ONCE and serves every query in the batch that
-     touches it.
+  3. scatters ``qw * tf`` (under BM25 ``qw`` times the saturated tf,
+     with the document's length row read off the tile) into a
+     ``tile``-wide doc tile with a one-hot matmul on the MXU and adds
+     it to a ``[Q, tile]`` accumulator — a hot block is read ONCE and
+     serves every query in the batch that touches it.
 
 Routing pairs are deduplicated across the query batch (two queries
 sharing a term share the block read) and sorted by tile so each output
@@ -34,9 +35,10 @@ VMEM scratch; when the walk leaves a tile (tile-sorted pairs make its
 run contiguous) the accumulator is reduced IN VMEM to a per-tile
 candidate set:
 
-  * the doc-metadata tail (norm division, deleted-doc mask, static-rank
-    blend — bit-identical op sequence to the jnp oracle's scoring tail)
-    is applied to the resident tile, and
+  * the ranking's doc-metadata tail (``Ranking.tail``: deleted-doc
+    mask, cosine's norm division, static-rank blend — bit-identical op
+    sequence to the jnp oracle's scoring tail) is applied to the
+    resident tile, and
   * ``k_tile`` successive maxima are extracted (lowest-lane tie-break,
     matching ``jax.lax.top_k``) as (value, global doc id) pairs.
 
@@ -63,7 +65,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.query import scoring_tail
+from repro.core.ranking import TFIDF_COSINE, Ranking
 from repro.kernels.runtime import resolve_interpret
 
 Array = jax.Array
@@ -172,40 +174,54 @@ def _unpair_tf_row(pairs, half):
     return _f16_bits_to_f32((w >> (16 * half)) & 0xFFFF)
 
 
-def _tile_contribution(docs, tfs, qw, tile_base, lane_cap, tile: int):
+def _tile_contribution(docs, tfs, qw, tile_base, lane_cap, tile: int,
+                       ranking: Ranking = TFIDF_COSINE, row=None):
     """Shared scoring step: ``[Q, tile]`` contribution of one block.
 
     ``docs``/``tfs`` are ``[1, B]`` rows, ``qw`` the ``[Q, 1]`` query
-    weight column.  Each posting's ``qw * tf`` is one f32 product (as in
-    the oracle), and the one-hot ``[Q, B] x [tile, B]^T`` MXU product
-    only places it: postings of one block are distinct docs, so every
-    output lane sums one product and zeros, exactly, at HIGHEST
-    precision.  ``lane_cap`` truncates the block at posting granularity
-    so a per-term ``cap`` that cuts mid-block matches the oracle's
-    gather."""
+    weight column.  Each posting's weighted value is one f32 number (as
+    in the oracle): ``qw * tf``, or under a saturating ranking (BM25)
+    ``qw * ranking.posting(tf, K)`` with the posting's doc row ``K``
+    read off the tile's row ``row`` ``[1, tile]`` by the transposed
+    one-hot product.  The one-hot ``[Q, B] x [tile, B]^T`` MXU product
+    then only places the values: postings of one block are distinct
+    docs, so every output lane sums one value and zeros, exactly, at
+    HIGHEST precision, and the accumulator adds values already rounded
+    (no multiply for a backend to fuse into the add).  ``lane_cap``
+    truncates the block at posting granularity so a per-term ``cap``
+    that cuts mid-block matches the oracle's gather."""
     block = docs.shape[1]
     lane0 = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     local = docs - tile_base
     inb = (docs >= 0) & (local >= 0) & (local < tile) & (lane0 < lane_cap)
-    w = qw * jnp.where(inb, tfs, 0.0)                            # [Q, B]
     slot = jax.lax.broadcasted_iota(jnp.int32, (tile, block), 0)
     onehot = (slot == local).astype(jnp.float32)                 # [tile, B]
+    tf = jnp.where(inb, tfs, 0.0)                                # [1, B]
+    if ranking.saturates:
+        k_post = jax.lax.dot_general(
+            jnp.broadcast_to(row, (qw.shape[0], tile)), onehot,
+            (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)                  # [Q, B]
+        w = qw * ranking.posting(tf, k_post)                     # [Q, B]
+    else:
+        w = qw * tf                                              # [Q, B]
     return jax.lax.dot_general(
         w, onehot, (((1,), (1,)), ((), ())),
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                      # [Q, tile]
 
 
-def _final_from_acc(acc, norm, rank, qnorm, rank_blend: float):
-    """The oracle's q_doc scoring tail, applied to one resident tile
-    (``norm``/``rank`` ``[1, tile]`` rows, ``qnorm`` a ``[Q, 1]``
+def _final_from_acc(acc, row, rank, qscale, rank_blend: float,
+                    ranking: Ranking):
+    """The ranking's q_doc scoring tail, applied to one resident tile
+    (``row``/``rank`` ``[1, tile]`` rows, ``qscale`` a ``[Q, 1]``
     column).
 
-    Delegates to the ONE shared definition (``core.query.scoring_tail``)
-    so candidate values stay bit-identical to the dense reference — any
+    Delegates to the ONE shared definition (``Ranking.tail``) so
+    candidate values stay bit-identical to the dense reference — any
     change to the tail changes both sides at once.
     """
-    return scoring_tail(acc, norm, rank, qnorm, rank_blend)
+    return ranking.tail(acc, row, rank, qscale, rank_blend)
 
 
 def _tile_topk(final, base, k_tile: int, tile: int):
@@ -357,8 +373,13 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
     per-pair (bits, base, count)).  ``tfs`` is f32[NB, block] (HOR) or
     the u32[ceil(NB/2), block] tf-pair rows (packed).  ``tail`` is None for
     the dense engine (output f32[n_tiles+1, Q, tile]) or
-    ``(norm_t, rank_t, qnorm, k_tile, rank_blend, reducer)`` for the
-    candidate engine (outputs f32/i32[n_tiles+1, Q, lane_pad(k_tile)])."""
+    ``(row_t, rank_t, qscale, k_tile, rank_blend, reducer, ranking)``
+    for the candidate engine (outputs f32/i32[n_tiles+1, Q,
+    lane_pad(k_tile)]).  A saturating ranking (BM25) DMAs a tile's doc
+    row when the walk enters the tile, since every pair of the tile
+    needs it; cosine reads it only at the flush.  The kernel is named
+    ``pair_walk`` under cosine and ``pair_walk_<ranking>`` otherwise,
+    so a trace tells them apart."""
     np_pairs, q = pair_qw.shape
     n_tiles = max(-(-num_docs // tile), 1)
     npc = max(-(-np_pairs // CHUNK), 1) * CHUNK
@@ -369,6 +390,8 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
     n_scal = len(scalars)
     packed = decode is not None
     dense = tail is None
+    ranking = TFIDF_COSINE if dense else tail[6]
+    saturates = ranking.saturates
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     inputs = [*scalars, rows, tfs, qwt]
     in_specs = [hbm] * len(inputs)
@@ -381,12 +404,12 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
         out_shape = jax.ShapeDtypeStruct((n_tiles + 1, q, tile), jnp.float32)
         n_out = 1
     else:
-        norm_t, rank_t, qnorm, k_tile, rank_blend, reducer = tail
+        row_t, rank_t, qscale, k_tile, rank_blend, reducer, _ = tail
         kp = _lane_pad(k_tile)
         # [n_tiles + 1, 1, tile]: a tile's row is one slice of the
         # untiled leading dim, whatever tiling the compiler picks
-        inputs += [norm_t[:, None, :], rank_t[:, None, :],
-                   qnorm.reshape(q, 1).astype(jnp.float32)]
+        inputs += [row_t[:, None, :], rank_t[:, None, :],
+                   qscale.reshape(q, 1).astype(jnp.float32)]
         in_specs += [hbm, hbm, pl.BlockSpec((q, 1), lambda i, n: (0, 0))]
         out_shape = (
             jax.ShapeDtypeStruct((n_tiles + 1, q, kp), jnp.float32),
@@ -403,7 +426,7 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
         rows_hbm, tfs_hbm, qw_hbm = refs[n_scal:n_scal + 3]
         r = n_scal + 3
         if not dense:
-            norm_hbm, rank_hbm, qn_ref = refs[r:r + 3]
+            drow_hbm, rank_hbm, qs_ref = refs[r:r + 3]
             r += 3
         outs = refs[r:r + n_out]
         r += n_out
@@ -421,11 +444,12 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
             if dense:
                 copy(acc, outs[0].at[t])
                 return
-            norm_v, rank_v, val_v, id_v = extra
-            copy(norm_hbm.at[t], norm_v)
+            drow_v, rank_v, val_v, id_v = extra
+            if not saturates:
+                copy(drow_hbm.at[t], drow_v)
             copy(rank_hbm.at[t], rank_v)
-            final = _final_from_acc(acc[...], norm_v[...], rank_v[...],
-                                    qn_ref[...], rank_blend)
+            final = _final_from_acc(acc[...], drow_v[...], rank_v[...],
+                                    qs_ref[...], rank_blend, ranking)
             vals, ids = _tile_reduce(final, t * tile, k_tile, tile, reducer)
             val_v[...] = jnp.full((q, kp), -jnp.inf, jnp.float32)
             id_v[...] = jnp.full((q, kp), -1, jnp.int32)
@@ -444,6 +468,8 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
                 def _():
                     flush(cur)
                 acc[...] = jnp.zeros_like(acc)
+                if saturates:
+                    copy(drow_hbm.at[t], extra[0])
 
             copy(rows_hbm.at[pl.ds(b, 1)], row_v)
             if packed:
@@ -460,8 +486,9 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
             lane = jax.lax.broadcasted_iota(jnp.int32, qwg.shape, 1)
             qcol = jnp.sum(jnp.where(lane == j % LANES, qwg, 0.0), axis=1,
                            keepdims=True)                           # [Q, 1]
-            acc[...] += _tile_contribution(docs, tf, qcol, t * tile,
-                                           s_sm[2][j], tile)
+            acc[...] += _tile_contribution(
+                docs, tf, qcol, t * tile, s_sm[2][j], tile, ranking,
+                extra[0][...] if saturates else None)
             return t
 
         n = n_ref[0]
@@ -487,7 +514,8 @@ def _walk_pairs(rows: Array, tfs: Array, pair_block: Array,
         scratch_shapes=scratch)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret, name="pair_walk",
+        interpret=interpret,
+        name="pair_walk" if not saturates else f"pair_walk_{ranking.name}",
     )(_n_walk(pair_tile, pair_cap, n_tiles), *inputs)
 
 
@@ -554,12 +582,12 @@ def fused_score_packed_pallas(packed: Array, tf_pairs: Array,
     return _finish(out, pair_tile, n_tiles, tile, num_docs)
 
 
-def _doc_tiles(norm: Array, rank: Array, n_tiles: int, tile: int):
+def _doc_tiles(row: Array, rank: Array, n_tiles: int, tile: int):
     """Pad per-doc metadata to the tile grid (+ a zero trash tile for
-    padding pairs; norm 0 there marks every lane deleted)."""
-    pad = n_tiles * tile - norm.shape[0]
+    padding pairs; row 0 there marks every lane deleted)."""
+    pad = n_tiles * tile - row.shape[0]
     z = jnp.zeros((1, tile), jnp.float32)
-    nt = jnp.pad(norm.astype(jnp.float32), (0, pad)).reshape(n_tiles, tile)
+    nt = jnp.pad(row.astype(jnp.float32), (0, pad)).reshape(n_tiles, tile)
     rt = jnp.pad(rank.astype(jnp.float32), (0, pad)).reshape(n_tiles, tile)
     return jnp.concatenate([nt, z]), jnp.concatenate([rt, z])
 
@@ -592,39 +620,40 @@ def _check_reducer(reducer: str, interpret: bool) -> None:
 
 
 def _topk_walk(rows, tfs, pair_block, pair_tile, pair_qw, pair_cap, decode,
-               norm, rank, qnorm, num_docs, block, k_tile, rank_blend, tile,
-               reducer, interpret):
+               row, rank, qscale, num_docs, block, k_tile, rank_blend, tile,
+               reducer, interpret, ranking):
     interp = resolve_interpret(interpret)
     _check_k_tile(k_tile, tile)
     _check_reducer(reducer, interp)
     n_tiles = max(-(-num_docs // tile), 1)
-    norm_t, rank_t = _doc_tiles(norm, rank, n_tiles, tile)
+    row_t, rank_t = _doc_tiles(row, rank, n_tiles, tile)
     vals, ids = _walk_pairs(
         rows, tfs, pair_block, pair_tile, pair_qw,
         pair_cap, decode, num_docs, tile, block, interp,
-        tail=(norm_t, rank_t, qnorm, k_tile, rank_blend, reducer))
+        tail=(row_t, rank_t, qscale, k_tile, rank_blend, reducer, ranking))
     return _finish_candidates(vals, ids, pair_tile, n_tiles, k_tile)
 
 
 def fused_topk_blocked_pallas(block_docs: Array, block_tfs: Array,
                               pair_block: Array, pair_tile: Array,
                               pair_qw: Array, pair_cap: Array,
-                              norm: Array, rank: Array, qnorm: Array,
+                              row: Array, rank: Array, qscale: Array,
                               num_docs: int, k_tile: int,
                               rank_blend: float = 0.0, tile: int = TILE,
                               reducer: str = "successive",
-                              interpret: bool | None = None):
+                              interpret: bool | None = None,
+                              ranking: Ranking = TFIDF_COSINE):
     """HOR candidate path: same routing contract as the dense kernel,
-    plus per-doc metadata (norm f32[num_docs], rank f32[num_docs]) and
-    per-query norms (qnorm f32[Q], padding queries should carry 1.0).
-    Returns (values f32[Q, n_tiles*k_tile], ids i32[Q, n_tiles*k_tile])
-    tile-major candidate lists of FINAL scores — the dense [Q, num_docs]
-    array never leaves VMEM."""
+    plus per-doc metadata (the ranking's doc row f32[num_docs], rank
+    f32[num_docs]) and per-query scales (qscale f32[Q], padding queries
+    should carry 1.0).  Returns (values f32[Q, n_tiles*k_tile], ids
+    i32[Q, n_tiles*k_tile]) tile-major candidate lists of FINAL scores
+    — the dense [Q, num_docs] array never leaves VMEM."""
     return _topk_walk(block_docs.astype(jnp.int32),
                       block_tfs.astype(jnp.float32), pair_block,
-                      pair_tile, pair_qw, pair_cap, None, norm, rank, qnorm,
+                      pair_tile, pair_qw, pair_cap, None, row, rank, qscale,
                       num_docs, block_docs.shape[1], k_tile, rank_blend,
-                      tile, reducer, interpret)
+                      tile, reducer, interpret, ranking)
 
 
 def fused_topk_packed_pallas(packed: Array, tf_pairs: Array,
@@ -632,20 +661,21 @@ def fused_topk_packed_pallas(packed: Array, tf_pairs: Array,
                              pair_qw: Array, pair_cap: Array,
                              pair_bits: Array, pair_base: Array,
                              pair_count: Array,
-                             norm: Array, rank: Array, qnorm: Array,
+                             row: Array, rank: Array, qscale: Array,
                              num_docs: int, block: int, k_tile: int,
                              rank_blend: float = 0.0, tile: int = TILE,
                              reducer: str = "successive",
-                             interpret: bool | None = None):
+                             interpret: bool | None = None,
+                             ranking: Ranking = TFIDF_COSINE):
     """Packed candidate path: in-VMEM decode + per-tile top-k; only
     compressed posting words and candidates cross the kernel boundary.
     Rows as for ``fused_score_packed_pallas``."""
     _check_packed_rows(packed, tf_pairs, block)
     return _topk_walk(packed, tf_pairs, pair_block,
                       pair_tile, pair_qw, pair_cap,
-                      (pair_bits, pair_base, pair_count), norm, rank, qnorm,
+                      (pair_bits, pair_base, pair_count), row, rank, qscale,
                       num_docs, block, k_tile, rank_blend, tile, reducer,
-                      interpret)
+                      interpret, ranking)
 
 
 def extract_tile_candidates(final: Array, tile: int, k_tile: int):

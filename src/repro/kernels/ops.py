@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.core.layouts import (BandedCsrIndex, BlockedIndex,
                                 PackedCsrIndex, unpair_tfs)
 from repro.core.query import final_scores
+from repro.core.ranking import TFIDF_COSINE, Ranking
 from repro.kernels import ref
 from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -342,15 +343,16 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
                        k_tile: int | None = None,
                        backend: Backend = "pallas", q_pad: int = Q_PAD,
                        reducer: str = "successive",
-                       qnorm: Array | None = None):
+                       qscale: Array | None = None,
+                       ranking: Ranking = TFIDF_COSINE):
     """The candidate path: per-tile partial top-k INSIDE the fused
     engine, so the dense [B, num_docs] score array never reaches HBM.
 
     Same contract as ``fused_batched_scores`` up to the accumulator;
     each doc tile is then reduced (in VMEM, on its last grid step) to
     ``k_tile`` (value, global doc id) candidates of FINAL score — the
-    doc-metadata tail (norm, deleted-doc mask, rank blend) is applied
-    per-tile, not densely.  ``k_tile`` defaults to the exactness floor
+    doc-metadata tail (``ranking.tail``: the deleted-doc mask, cosine's
+    norm division, the rank blend) is applied per-tile, not densely.  ``k_tile`` defaults to the exactness floor
     ``min(k, tile)`` (rounded up to the lane quantum), which guarantees
     a pure ``merge_topk_candidates`` over the returned tile-major lists
     reproduces the dense oracle's top-k bit-identically.
@@ -361,28 +363,37 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
     ``reducer`` / ``q_pad`` are autotuner-selected
     kernel geometry (see ``kernels/autotune.py``); the defaults are the
     historical hardcoded values, so untuned callers are bit-identical
-    to the pre-autotuner engine.  ``qnorm`` f32[B] overrides the query
-    norms derived from ``idf_w`` (the live index passes its host ones).
+    to the pre-autotuner engine.  ``qscale`` f32[B] overrides the
+    per-query scales (the live index passes the ones its ranking
+    computed on the host); without it the cosine query norms of
+    ``idf_w`` are taken.  ``ranking`` (``core/ranking.py``) picks the
+    kernel's posting transform and tail; only the Pallas walk scores a
+    saturating one.
     """
     b, t = term_ids.shape
     num_docs = index.docs.num_docs
     if k_tile is None:
         k_tile = default_k_tile(k, tile)
     k_tile = min(k_tile, tile)
-    if qnorm is None:
+    if qscale is None:
         # per-query norm of the idf weight vector (duplicate slots carry
         # 0 after dedup) — same reduction the oracle's scoring tail does
-        qnorm = jnp.sqrt(jnp.maximum(jnp.sum(idf_w * idf_w, axis=1),
-                                     1e-12))
+        qscale = jnp.sqrt(jnp.maximum(jnp.sum(idf_w * idf_w, axis=1),
+                                      1e-12))
+    row = ranking.doc_row(index.docs)
 
     if backend == "xla":
+        if ranking.saturates:
+            raise NotImplementedError(
+                f"{ranking.name} is scored by the Pallas walk only; the "
+                "xla lowering places weighted tfs linearly")
         # plain-HLO lowering: dense scores (same block dedup), then the
         # jnp mirror of the kernels' per-tile reduction
         scores, overflow = fused_batched_scores(
             index, term_ids, idf_w, cap, max_pairs=max_pairs, tile=tile,
             backend="xla")
-        final = final_scores(scores, index.docs.norm, index.docs.rank,
-                             qnorm, rank_blend)
+        final = final_scores(scores, row, index.docs.rank, qscale,
+                             rank_blend, ranking)
         vals, ids = extract_tile_candidates(final, tile, k_tile)
         return vals, ids, overflow
 
@@ -404,26 +415,27 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
             max_pairs, cand_cap=cand_cap)
 
     # pad the query batch to the accumulator quantum (padding queries
-    # get qnorm 1.0 — their zero accumulator masks them to -inf anyway)
+    # get scale 1.0 — their zero accumulator masks them to -inf anyway)
     bp = -(-b // max(q_pad, 1)) * max(q_pad, 1)
-    qnorm_p = qnorm
+    qscale_p = qscale
     if bp != b:
         pqw = jnp.pad(pqw, ((0, 0), (0, bp - b)))
-        qnorm_p = jnp.pad(qnorm, (0, bp - b), constant_values=1.0)
+        qscale_p = jnp.pad(qscale, (0, bp - b), constant_values=1.0)
 
     if isinstance(index, PackedCsrIndex):
         vals, ids = fused_topk_packed_pallas(
             index.packed, index.tf_pairs, pb, pt, pqw, pcap,
             index.block_bits[pb], index.block_base[pb],
-            index.block_count[pb], index.docs.norm, index.docs.rank,
-            qnorm_p, num_docs, block, k_tile, rank_blend=rank_blend,
-            tile=tile, reducer=reducer, interpret=_interp(backend))
+            index.block_count[pb], row, index.docs.rank,
+            qscale_p, num_docs, block, k_tile, rank_blend=rank_blend,
+            tile=tile, reducer=reducer, interpret=_interp(backend),
+            ranking=ranking)
     else:
         vals, ids = fused_topk_blocked_pallas(
             index.block_docs, index.block_tfs, pb, pt, pqw, pcap,
-            index.docs.norm, index.docs.rank, qnorm_p, num_docs, k_tile,
+            row, index.docs.rank, qscale_p, num_docs, k_tile,
             rank_blend=rank_blend, tile=tile, reducer=reducer,
-            interpret=_interp(backend))
+            interpret=_interp(backend), ranking=ranking)
     return vals[:b], ids[:b], overflow
 
 
@@ -440,25 +452,28 @@ def fused_batched_topk(index: BlockedIndex | PackedCsrIndex,
 # (segment-local ids shifted by the traced ``doc_base`` scalar), merged
 # host-side by ``distributed.topk.merge_topk_candidates_host``.
 #
-# ``idf_w`` carries GLOBAL idf weights (live df over live docs, computed
-# by the live index) — a segment never scores with its local df, so the
-# multi-segment ranking matches a from-scratch rebuild exactly.  Slots
-# whose term is absent from THIS segment still contribute to the query
-# norm (it is a property of the query, not the segment) but gate no
-# posting blocks.
+# ``idf_w`` carries GLOBAL query weights (live df over live docs,
+# computed on the host by the index's ranking) — a segment never scores
+# with its local df, so the multi-segment ranking matches a from-scratch
+# rebuild exactly.  Slots whose term is absent from THIS segment still
+# count in the query's scale (it is a property of the query, not the
+# segment) but gate no posting blocks.  ``ranking`` is static: each
+# ranking compiles its own engine (BM25's walk is ``pair_walk_bm25``).
 
 
 @functools.partial(jax.jit, static_argnames=(
     "k_tile", "cap", "max_pairs", "rank_blend", "tile", "backend",
-    "q_pad", "reducer"))
+    "q_pad", "reducer", "ranking"))
 def fused_segment_topk(index: BlockedIndex | PackedCsrIndex,
-                       query_hashes: Array, idf_w: Array, qnorm: Array,
+                       query_hashes: Array, idf_w: Array, qscale: Array,
                        doc_base: Array, *, k_tile: int,
                        cap: int, max_pairs: int, rank_blend: float = 0.0,
                        tile: int = TILE, backend: Backend = "pallas",
-                       q_pad: int = Q_PAD, reducer: str = "successive"):
+                       q_pad: int = Q_PAD, reducer: str = "successive",
+                       ranking: Ranking = TFIDF_COSINE):
     """Candidate engine over one segment: fused decode-and-score kernel
-    with in-kernel per-tile top-k (tombstones ride in as norm == 0).
+    with in-kernel per-tile top-k (tombstones ride in as a doc row of
+    0).
 
     Accepts either sealed-segment layout — HOR blocks (``seal_layout=
     "hor"``) or delta+bit-packed blocks (``"packed"``); the pytree
@@ -473,7 +488,7 @@ def fused_segment_topk(index: BlockedIndex | PackedCsrIndex,
     vals, ids, overflow = fused_batched_topk(
         index, tids, idf_w, cap, k=k_tile, rank_blend=rank_blend,
         max_pairs=max_pairs, tile=tile, k_tile=k_tile, backend=backend,
-        q_pad=q_pad, reducer=reducer, qnorm=qnorm)
+        q_pad=q_pad, reducer=reducer, qscale=qscale, ranking=ranking)
     gids = jnp.where(ids >= 0, ids + doc_base, -1)
     return vals, gids, overflow
 
@@ -566,14 +581,16 @@ def fused_segment_banded_topk(index: BandedCsrIndex, query_hashes: Array,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "k_tile", "cap", "rank_blend", "tile"))
+    "k_tile", "cap", "rank_blend", "tile", "ranking"))
 def jnp_segment_topk(index, query_hashes: Array, idf_w: Array,
-                     qnorm: Array, doc_base: Array, *, k_tile: int, cap: int,
-                     rank_blend: float = 0.0, tile: int = TILE):
+                     qscale: Array, doc_base: Array, *, k_tile: int, cap: int,
+                     rank_blend: float = 0.0, tile: int = TILE,
+                     ranking: Ranking = TFIDF_COSINE):
     """Pure-jnp oracle engine over one segment (gather + scatter-add),
     reduced to the same per-tile candidate lists as the fused kernels."""
     from repro.core.query import accumulate_scores
     num_docs = index.docs.num_docs
+    row = ranking.doc_row(index.docs)
 
     def one(qh, w):
         present = qh != 0
@@ -584,11 +601,13 @@ def jnp_segment_topk(index, query_hashes: Array, idf_w: Array,
                                       jnp.iinfo(jnp.int32).max))
         tids, w = tids[order], w[order]
         d, tf, valid = index.gather_postings(tids, cap)
-        return accumulate_scores(d, tf * w[:, None], valid, num_docs)
+        drow = row[jnp.maximum(d, 0)] if ranking.saturates else None
+        return accumulate_scores(d, ranking.posting(tf, drow) * w[:, None],
+                                 valid, num_docs)
 
     scores = jax.vmap(one)(query_hashes, idf_w)
-    final = final_scores(scores, index.docs.norm, index.docs.rank, qnorm,
-                         rank_blend)
+    final = final_scores(scores, row, index.docs.rank, qscale,
+                         rank_blend, ranking)
     vals, ids = extract_tile_candidates(final, tile, k_tile)
     gids = jnp.where(ids >= 0, ids + doc_base, -1)
     return vals, gids, jnp.int32(0)

@@ -143,3 +143,61 @@ def ref_attention(q: Array, k: Array, v: Array, causal: bool = True,
     p = jax.nn.softmax(logits, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)
     return jnp.einsum("bhqk,bhkd->bhqd", p, vv)
+
+
+# ---------------------------------------------------------------------------
+# BM25: the plain reference of the ranking the live index serves
+# ---------------------------------------------------------------------------
+
+
+def ref_bm25_topk(doc_term_ids, doc_counts, queries, k: int, k1: float,
+                  b: float):
+    """Exact BM25 top-k over a plain corpus, with no kernel, batching or
+    segments: the reference the served path is compared with.
+
+    ``doc_term_ids`` / ``doc_counts``: one array of distinct term ids
+    and one of their counts per document (ids are the list positions).
+    ``queries``: term-id arrays (distinct; ids absent from the corpus
+    score nothing).  For each query term ``t`` and document ``d``, in
+    float32 numpy (no fused multiply-add, no matmul)::
+
+        idf(t)   = ln(1 + (N - df(t) + 0.5) / (df(t) + 0.5))
+        K(d)     = k1 (1 - b + b dl(d) / avgdl)
+        score(d) = sum_t idf(t) tf (k1 + 1) / (tf + K(d))
+
+    with ``N`` the document count, ``dl(d)`` the document's summed
+    counts and ``avgdl`` their mean.  A document's terms are added in
+    ascending term id, and the best ``k`` come first, ties to the lower
+    id.  Returns (ids i32[Q, k], scores f32[Q, k]), -1 / 0 past the
+    hits."""
+    import numpy as np
+
+    f32 = np.float32
+    n = len(doc_term_ids)
+    lens = np.array([len(t) for t in doc_term_ids], np.int64)
+    terms = (np.concatenate(doc_term_ids).astype(np.int64) if n
+             else np.zeros(0, np.int64))
+    tfs = (np.concatenate(doc_counts).astype(f32) if n
+           else np.zeros(0, f32))
+    docs = np.repeat(np.arange(n), lens)
+    df = np.bincount(terms, minlength=int(terms.max(initial=-1)) + 1)
+    dl = np.array([int(np.sum(c)) for c in doc_counts], np.int64)
+    avgdl = f32(dl.sum() / n if n else 1.0)
+    kd = f32(k1) * (f32(1.0 - b) + f32(b) * (dl.astype(f32) / avgdl))
+    out_ids = np.full((len(queries), k), -1, np.int32)
+    out_scores = np.zeros((len(queries), k), f32)
+    for i, q in enumerate(queries):
+        acc = np.zeros(n, f32)
+        for t in sorted(int(x) for x in q if 0 <= x < len(df)):
+            if df[t] == 0:
+                continue
+            idf = np.log1p((f32(n) - f32(df[t]) + f32(0.5))
+                           / (f32(df[t]) + f32(0.5)))
+            hit = terms == t
+            tf, d = tfs[hit], docs[hit]
+            acc[d] = acc[d] + tf * f32(k1 + 1.0) / (tf + kd[d]) * idf
+        cand = np.flatnonzero(acc > 0)
+        best = cand[np.lexsort((cand, -acc[cand]))][:k]
+        out_ids[i, :len(best)] = best
+        out_scores[i, :len(best)] = acc[best]
+    return out_ids, out_scores

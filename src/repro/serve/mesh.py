@@ -171,6 +171,10 @@ class MeshServer(QueryServer):
     def __init__(self, index, config: MeshConfig | None = None,
                  mesh=None):
         cfg = config or MeshConfig()
+        if index.ranking.saturates:
+            raise ValueError(
+                f"MeshServer scores tf-idf cosine only; this index ranks "
+                f"by {index.ranking.name} (serve it with QueryServer)")
         if cfg.topology not in ("doc_stack", "term_fused"):
             raise ValueError(f"unknown mesh topology {cfg.topology!r}")
         self.mesh = (mesh if mesh is not None

@@ -199,6 +199,8 @@ class QueryServer:
         # compiles while serving a batch: 0 in steady state, since
         # warmup() compiled every shape the batches use
         self._compiles = self.registry.counter("serve_compiles")
+        # batches scored under BM25 (warm-up excluded)
+        self._bm25_batches = self.registry.counter("serve_bm25_batches")
         _listen_for_compiles()
         self._register_index_gauges()
         self._queue: deque[Ticket] = deque()
@@ -212,6 +214,7 @@ class QueryServer:
             if self.config.event_capacity is not None:
                 index.events.resize(self.config.event_capacity)
             self._pinned: LiveView = index.view()
+        self._observe_pin(self._pinned)
         self._purged_epoch = self._pinned.epoch
         self.metrics.observe_layout_mix(self._pinned.layout_mix())
 
@@ -235,6 +238,13 @@ class QueryServer:
                 ("index_events_total", lambda: ix.events.total)):
             if self.registry.get(name) is None:
                 self.registry.register_callback(name, fn)
+
+    def _observe_pin(self, view: LiveView) -> None:
+        """Gauges of the pinned epoch's length statistics: its live
+        tokens and their average over live documents (BM25's avgdl),
+        set at each pin."""
+        self.registry.gauge("index_live_tokens").set(view.live_tokens)
+        self.registry.gauge("index_avgdl").set(view.avgdl)
 
     def metrics_snapshot(self, include_global: bool = True) -> dict:
         """The stable export (see ``repro.obs.registry``): this
@@ -317,6 +327,7 @@ class QueryServer:
                 self._pinned = self.index.view()
             finally:
                 self.index_lock.release()
+            self._observe_pin(self._pinned)
         return self._pinned
 
     @property
@@ -400,6 +411,8 @@ class QueryServer:
                            rank_blend=cfg.rank_blend, engine=cfg.engine,
                            mode=cfg.mode, backend=cfg.backend,
                            tune=cfg.tune, trace=btr)
+        if view.ranking.saturates:
+            self._bm25_batches.inc()
         with stage(btr, "fetch", parent="score"):
             ids = np.asarray(result.doc_ids)
             scores = np.asarray(result.scores)

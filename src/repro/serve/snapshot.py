@@ -27,13 +27,16 @@ import numpy as np
 from repro.core import compaction, size_model
 from repro.core.live_index import (LiveIndexStats, LiveView, SegmentedIndex,
                                    _Delta)
+from repro.core.ranking import Ranking
 
 # v2 adds the layout policy + per-segment chooser provenance
 # (size_class, num_terms, chooser_reason); v3 adds the per-segment band
 # descriptor (band_cut) so banded segments restore with the EXACT band
 # membership they sealed with.  v1/v2 snapshots still restore (no
 # policy / band_cut re-derived by the builder) — the arrays are
-# identical either way.
+# identical either way.  Any version may carry the index's ranking in
+# its meta (``"ranking"``); a snapshot without one restores as tf-idf
+# cosine.
 _FORMAT_VERSION = 3
 _READ_VERSIONS = (1, 2, 3)
 
@@ -52,8 +55,9 @@ def serialize_segmented(index: SegmentedIndex, lock=None) -> dict:
     Layout: a JSON manifest (uint8 bytes under ``"meta"``) for scalars
     and per-segment shapes, plus one array per global table and per
     segment postings column.  Everything needed to rebuild — vocabulary,
-    live df, live mask, ranks, norms, per-segment canonical triples,
-    the delta tail, compaction policy, and the rank rng state.
+    live df, live mask, ranks, the ranking and its doc rows (under
+    ``"norm"``), per-segment canonical triples, the delta tail,
+    compaction policy, and the rank rng state.
 
     The state is gathered in several passes, so like ``view()`` this
     must run serially with writers: pass the serving tier's write lock
@@ -71,6 +75,7 @@ def serialize_segmented(index: SegmentedIndex, lock=None) -> dict:
         "live_docs": int(index._live_docs),
         "epoch": int(index._epoch),
         "seal_layout": index._seal_layout,
+        "ranking": index.ranking.to_meta(),
         "delta": {"doc_cap": dl.doc_cap, "post_cap": dl.post_cap,
                   "doc_base": dl.doc_base, "n_docs": dl.n_docs},
         "policy": {"size_ratio": index._policy.size_ratio,
@@ -103,7 +108,7 @@ def serialize_segmented(index: SegmentedIndex, lock=None) -> dict:
         "df": index._df.copy(),
         "live": index._live.copy(),
         "rank": index._rank.copy(),
-        "norm": index._norm.copy(),
+        "norm": index._doc_row.copy(),
         "delta_terms": dl.terms[:n_p].copy(),
         "delta_tfs": dl.tfs[:n_p].copy(),
         "delta_lens": np.diff(dl.doc_offsets[:dl.n_docs + 1]),
@@ -123,12 +128,16 @@ def restore_segmented(state: dict) -> SegmentedIndex:
     canonical triples — the same path live sealing takes, so device
     structures come out identical up to vocabulary width (terms added
     after a segment sealed appear as posting-less vocab entries, which
-    gate nothing and change no result bit).
+    gate nothing and change no result bit).  The index gets the
+    ranking the meta records.  Document lengths are summed from the
+    stored postings; a BM25 index computes its doc rows from them (and
+    from the live statistics) rather than reading ``"norm"``.
     """
     meta = json.loads(bytes(np.asarray(state["meta"])).decode())
     if meta["version"] not in _READ_VERSIONS:
         raise ValueError(f"unknown snapshot version {meta['version']}")
     pol = meta.get("layout_policy")
+    ranking = Ranking.from_meta(meta.get("ranking"))
     si = SegmentedIndex(
         term_hashes=np.asarray(state["hashes"], np.uint32),
         delta_doc_capacity=meta["delta"]["doc_cap"],
@@ -136,14 +145,22 @@ def restore_segmented(state: dict) -> SegmentedIndex:
         policy=compaction.TieredPolicy(**meta["policy"]),
         seal_layout=meta["seal_layout"],
         layout_policy=(size_model.LayoutCostModel.from_dict(pol)
-                       if pol is not None else None))
+                       if pol is not None else None),
+        ranking=ranking)
     si._df = np.asarray(state["df"], np.int64).copy()
     si._live = np.asarray(state["live"], bool).copy()
     si._rank = np.asarray(state["rank"], np.float32).copy()
-    si._norm = np.asarray(state["norm"], np.float32).copy()
     si._live_docs = int(meta["live_docs"])
     si._rng.bit_generator.state = meta["rng_state"]
-    # norms are already restored, so segment builds pad the exact values
+    si._dl = _doc_lengths(state, meta, len(si._live))
+    si._live_tokens = int(si._dl[si._live].sum())
+    if ranking.saturates:
+        si._doc_row = ranking.doc_rows(
+            live=si._live, df=si._df, live_docs=si._live_docs, dl=si._dl,
+            live_tokens=si._live_tokens, postings=())
+    else:
+        si._doc_row = np.asarray(state["norm"], np.float32).copy()
+    # rows are already restored, so segment builds pad the exact values
     for i, sm in enumerate(meta["segments"]):
         # the stored layout restores as an EXPLICIT arg (top of the
         # ladder), so the roundtrip stays bitwise no matter what the
@@ -174,6 +191,26 @@ def restore_segmented(state: dict) -> SegmentedIndex:
                    segments=len(si._segments),
                    snapshot_version=int(meta["version"]))
     return si
+
+
+def _doc_lengths(state: dict, meta: dict, num_docs: int) -> np.ndarray:
+    """i64[num_docs] token counts (summed tfs) of every document, from
+    the stored postings: a sealed segment holds a deleted document's
+    postings until compaction drops them, so only live lengths are
+    exact, and only those are used."""
+    dl = np.zeros(num_docs, np.float64)
+    for i, sm in enumerate(meta["segments"]):
+        base, span = int(sm["doc_base"]), int(sm["doc_span"])
+        dl[base:base + span] += np.bincount(
+            np.asarray(state[f"seg{i}_doc_of"]), minlength=span,
+            weights=np.asarray(state[f"seg{i}_tfs"]))[:span]
+    lens = np.asarray(state["delta_lens"], np.int64)
+    if lens.size:
+        base = int(meta["delta"]["doc_base"])
+        dl[base:base + lens.size] += np.bincount(
+            np.repeat(np.arange(lens.size), lens), minlength=lens.size,
+            weights=np.asarray(state["delta_tfs"]))
+    return np.rint(dl).astype(np.int64)
 
 
 def save_segmented(index: SegmentedIndex, path, lock=None) -> None:

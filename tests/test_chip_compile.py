@@ -150,6 +150,34 @@ def test_hor_candidate_kernel_compiles(one_chip, compiled_kernels):
 
 
 @pytest.mark.parametrize("layout", ["hor", "packed"])
+def test_bm25_candidate_kernel_compiles(one_chip, compiled_kernels, layout):
+    """The walk specialised for BM25 (``pair_walk_bm25``): the tile's
+    length row DMA'd when the walk enters a tile, and the per-posting
+    saturation, at the served pair budget."""
+    from repro.core.ranking import bm25
+    sds = _sds(one_chip)
+    docs_rows = [sds((DOCS,), jnp.float32), sds((DOCS,), jnp.float32),
+                 sds((Q,), jnp.float32)]
+    if layout == "packed":
+        blocks, wpb, docs = PACKED_SHAPES[0]
+        args = [*_packed_rows(sds, blocks, wpb),
+                *_pair_args(sds, SERVED_PAIRS, decode=True), *docs_rows]
+        fn = functools.partial(fds.fused_topk_packed_pallas, num_docs=docs,
+                               block=BLOCK, k_tile=K_TILE, tile=TILE,
+                               ranking=bm25(0.9, 0.4))
+    else:
+        args = [sds((BLOCKS, BLOCK), jnp.int32),
+                sds((BLOCKS, BLOCK), jnp.float32),
+                *_pair_args(sds, SERVED_PAIRS), *docs_rows]
+        fn = functools.partial(fds.fused_topk_blocked_pallas, num_docs=DOCS,
+                               k_tile=K_TILE, tile=TILE,
+                               ranking=bm25(0.9, 0.4))
+    lowered = jax.jit(fn).lower(*args)
+    assert "pair_walk_bm25" in lowered.as_text()
+    assert lowered.compile().memory_analysis() is not None
+
+
+@pytest.mark.parametrize("layout", ["hor", "packed"])
 def test_dense_kernel_compiles(one_chip, compiled_kernels, layout):
     sds = _sds(one_chip)
     if layout == "packed":

@@ -701,3 +701,46 @@ def test_overflow_host_count_prints_and_counts_like_the_jitted_path(capsys):
         "raise max_pairs")
     ops.warn_on_overflow(0, "test_obs host zero")
     assert capsys.readouterr().out == "" and c.value == before + 10
+
+
+def test_avgdl_gauge_and_bm25_batch_counter():
+    """The pinned epoch's live tokens and avgdl are gauges set at each
+    pin: they equal the index's live tokens over live documents after
+    an ingest and after a delete.  Each scored BM25 batch counts once;
+    warm-up counts none."""
+    from repro.core.ranking import bm25
+    tc = corpus.generate(corpus.CorpusSpec(num_docs=160, vocab=200,
+                                           avg_distinct=12, seed=21))
+    si = SegmentedIndex(term_hashes=tc.term_hashes, delta_doc_capacity=64,
+                        delta_posting_capacity=2048,
+                        ranking=bm25(0.9, 0.4))
+    si.add_batch(_slice(tc, 0, 100))
+    server = QueryServer(si, ServerConfig(batch_size=4, n_terms_budget=4,
+                                          k=5, cache_capacity=0))
+    qh = corpus.sample_query_terms(np.bincount(np.concatenate(
+        tc.doc_term_ids), minlength=200), tc.term_hashes, 6, 3,
+        num_docs=tc.num_docs, seed=2)
+
+    def gauges():
+        snap = server.metrics_snapshot(include_global=False)
+        return (snap["index_live_tokens"]["value"],
+                snap["index_avgdl"]["value"],
+                snap["serve_bm25_batches"]["value"])
+
+    def tokens():
+        live = si.live_mask()
+        return sum(int(np.sum(tc.doc_counts[d]))
+                   for d in np.flatnonzero(live))
+
+    server.warmup()
+    assert gauges() == (tokens(), tokens() / 100, 0)
+    si.add_batch(_slice(tc, 100, 160))
+    for row in qh:
+        server.submit(row)
+    assert server.pump(max_batches=10) == len(qh)
+    assert gauges() == (tokens(), pytest.approx(tokens() / 160), 2)
+    si.delete([5, 120, 150])
+    server.submit(qh[0])
+    assert server.pump() == 1
+    assert si.live_token_count == tokens()
+    assert gauges() == (tokens(), pytest.approx(tokens() / 157), 3)

@@ -292,7 +292,7 @@ def test_snapshot_restore_bit_identical(seal_layout):
         assert other.num_segments == si.num_segments
         assert other.live_doc_count == si.live_doc_count
         np.testing.assert_array_equal(other._df, si._df)
-        np.testing.assert_array_equal(other._norm, si._norm)
+        np.testing.assert_array_equal(other._doc_row, si._doc_row)
         r1 = si.topk(qh, k=10)
         r2 = other.topk(qh, k=10)
         np.testing.assert_array_equal(np.asarray(r1.doc_ids),
